@@ -12,8 +12,8 @@ Per CNN preset (smallest -> largest) this measures, on one machine model:
     program), reported per-sample at batch 1 and batch 8 (compile time
     excluded; that's the cached cost);
   * ``compiled_pallas`` — the registry's ``pallas`` backend: the fused
-    per-core megakernel (`repro.core.megakernel`, <= num_cores
-    ``pallas_call``s per program, requant fused in epilogues). Real Mosaic
+    per-core megakernel (`repro.core.megakernel`, scratchpad-sized
+    segments, requant fused in epilogues). Real Mosaic
     kernels on TPU, interpret mode on CPU CI;
   * ``compiled_pallas_perop`` — the same backend with ``megakernel=False``
     (one ``pallas_call`` per op) — the megakernel's fusion win is
@@ -36,6 +36,7 @@ import argparse
 import json
 import time
 
+import jax
 import numpy as np
 
 import repro
@@ -69,11 +70,7 @@ def _time(fn, reps):
     t0 = time.perf_counter()
     for _ in range(reps):
         out = fn()
-    try:
-        import jax
-        jax.block_until_ready(out)
-    except (ImportError, TypeError):
-        pass
+    jax.block_until_ready(out)             # a failed sync raises
     return (time.perf_counter() - t0) / reps
 
 

@@ -8,6 +8,7 @@ from __future__ import annotations
 
 import time
 
+import jax
 import numpy as np
 
 from repro.hw import TPU_V5E
@@ -15,21 +16,13 @@ from repro.kernels import ops, ref
 
 
 def _time(fn, *args, reps=3):
-    out = fn(*args)                # compile
-    try:
-        out.block_until_ready()
-    except AttributeError:
-        pass
+    jax.block_until_ready(fn(*args))         # compile; a failed sync raises
     t0 = time.perf_counter()
     for _ in range(reps):
-        out = fn(*args)
         # sync INSIDE the timed loop: async dispatch would otherwise queue
         # all reps and only the last result's readiness would be awaited,
         # under-reporting jitted times
-        try:
-            out.block_until_ready()
-        except AttributeError:
-            pass
+        jax.block_until_ready(fn(*args))
     return (time.perf_counter() - t0) / reps
 
 

@@ -37,7 +37,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from ..core import compiled as _C
@@ -192,14 +191,14 @@ def _mesh_program(prog: CompiledProgram, batched: bool):
         single = _mesh_single_fn(prog, model)
         mesh = make_host_mesh(data=data, model=model)
         if batched:
-            fn = shard_map(jax.vmap(single), mesh=mesh,
-                           in_specs=(P("data"),), out_specs=P("data"),
-                           check_rep=False)
+            fn = jax.shard_map(jax.vmap(single), mesh=mesh,
+                               in_specs=(P("data"),), out_specs=P("data"),
+                               check_vma=False)
         else:
             # replicated in, replicated out: every device computes the
             # same value (psum over disjoint exact tile covers)
-            fn = shard_map(single, mesh=mesh, in_specs=(P(),),
-                           out_specs=P(), check_rep=False)
+            fn = jax.shard_map(single, mesh=mesh, in_specs=(P(),),
+                               out_specs=P(), check_vma=False)
         prog._pallas_cache[key] = jax.jit(fn)
     return prog._pallas_cache[key]
 

@@ -29,8 +29,9 @@ Built-in backends (see repro/core/compiled.py for their numerics):
   * ``jax``    — the whole program as one jitted (and, batched, vmapped)
     XLA function; the serving fast path.
   * ``pallas`` — the fused per-core megakernel over the Pallas kernels
-    (`repro.core.megakernel`): <= num_cores `pallas_call`s per program,
-    requant fused in epilogues, scratchpad-budgeted segments. Real Mosaic
+    (`repro.core.megakernel`): scratchpad-sized segments, one
+    `pallas_call` each and `num_cores` of them when the scratchpad allows,
+    requant fused in epilogues. Real Mosaic
     lowering on TPU, interpret mode elsewhere. ``megakernel=False`` in the
     options falls back to the per-op kernel path.
 
@@ -73,11 +74,12 @@ class BackendOptions:
                            requires the backend's `requires_device`.
       megakernel         — fused per-core megakernel on/off (None: on for
                            the pallas backend).
-      scratchpad_budget  — bytes; overrides the machine scratchpad capacity
-                           the megakernel planner and kernel tile
-                           derivation use (the tile-override knob).
-      max_kernels        — cap on emitted pallas_calls per program
-                           (None: the program's core count).
+      scratchpad_budget  — bytes; packs the megakernel segments against
+                           less than the machine's scratchpad capacity
+                           (a larger value is clamped to the capacity).
+      max_kernels        — target count of emitted pallas_calls per
+                           program, met only within the scratchpad
+                           capacity (None: the program's core count).
     """
 
     interpret: bool | None = None

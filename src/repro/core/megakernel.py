@@ -11,8 +11,9 @@ structure on the compiled program:
   1. **Segmentation** (`plan_segments`): walk `_pallas_plan`'s steps in
      program order and greedily pack them into contiguous *segments* whose
      summed working set — streamed operands counted twice on a dual-ported
-     scratchpad (the i/i+1 double-buffer pair) plus the int32 accumulator
-     and output tile — fits the machine's scratchpad capacity
+     scratchpad (the i/i+1 double-buffer pair), the int32 accumulator, the
+     output tile, and the padded copy plus tap window a conv or pool step
+     windows over — fits the machine's scratchpad capacity
      (`hw.scratchpad_bytes`). Each segment is one core's fused stretch of
      the program and is assigned a core round-robin, so the per-core WCET
      composition of the schedule survives the fusion (ACETONE-style
@@ -21,20 +22,29 @@ structure on the compiled program:
      replays the segment's steps scratchpad-resident — gemms via the exact
      int8 contraction (`kernels.gemm_int8.dot_i32_exact`: MXU int8 dots on
      TPU, exactness-preserving chunked-f32 dots under interpret mode),
-     convs via in-kernel im2col (`kernels.conv2d_im2col.im2col_patches`),
-     requantization fused into the epilogues exactly as the per-op plan
-     decided (`_PallasStep.mult`), and fallback kinds via the shared JAX
-     op emitters. A single gemm/conv whose working set alone exceeds the
-     scratchpad falls back to the existing *tiled* kernels
+     convs and pools as strided tap windows over a padded int32 VMEM copy
+     (`kernels.conv2d_im2col.conv_accumulate`), requantization fused into
+     the epilogues exactly as the per-op plan decided (`_PallasStep.mult`).
+     In-kernel values stay int32 (Mosaic lays out and windows 32-bit
+     values; int8 ones it refuses to reshape or compare) and narrow to int8
+     only for the MXU and at the output store. Weights and requant
+     multipliers enter as operands. A single gemm/conv whose working set
+     alone exceeds the scratchpad runs on the *tiled* kernels
      (`gemm_int8_pallas` / `conv2d_int8_pallas`), whose grid streaming is
      Pallas-double-buffered — still one `pallas_call`. Fallback-only steps
      that fit in no segment run at the XLA level between kernels (zero
      extra launches, same as the per-op backend).
-  3. **Call-count invariant**: the planner re-packs with a doubled budget
-     until the program emits at most `num_cores` kernels (`max_kernels`
-     override in `BackendOptions`) — the paper's "one program per core"
-     shape. `count_pallas_calls` verifies the invariant on the traced
-     function; the megakernel tests gate on it.
+  3. **Kernel-count target**: the paper's shape is one program per core, so
+     the planner aims at `num_cores` kernels (`max_kernels` override in
+     `BackendOptions`): when a pack at a reduced budget emits more, the
+     budget doubles toward the scratchpad capacity and packing reruns. The
+     capacity is a hard ceiling — a program that cannot fit `num_cores`
+     segments of the scratchpad emits more kernels, never a segment larger
+     than the scratchpad (the sanitizer's SPM002 re-checks this).
+     `count_pallas_calls` counts the kernels of the traced function.
+
+Every `pallas_call` states its VMEM limit from the bytes it holds in the
+chip's tiled layout (`kernels.vmem`).
 
 Bit-exactness: every emission path reuses the repo's single requant
 definition (`requant_epilogue`) and exact int8 contractions, so the
@@ -45,16 +55,20 @@ supported graph — the same acceptance bar as the per-op backend.
 from __future__ import annotations
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from . import compiled as C
-from .graph import conv_out_hw
-from ..kernels.conv2d_im2col import conv2d_int8_pallas, im2col_patches
+from ..kernels import vmem
+from ..kernels.conv2d_im2col import (conv2d_int8_pallas, conv2d_vmem_bytes,
+                                     conv_accumulate, fill_window,
+                                     tap_windows, window_scratch)
 from ..kernels.gemm_int8 import (dot_i32_exact, gemm_int8_pallas,
-                                 requant_epilogue)
+                                 gemm_vmem_bytes, requant_epilogue)
+from ..kernels.ref import _as_channel_mult, round_half_even_div
 
 _ITEM_BYTES = {"int8": 1, "uint8": 1, "int16": 2, "int32": 4,
                "f32": 4, "bf16": 2}
@@ -62,6 +76,8 @@ _ITEM_BYTES = {"int8": 1, "uint8": 1, "int16": 2, "int32": 4,
 # fallback capacity when the program carries no hardware model: the paper
 # machine's 1 MiB worker scratchpad
 _DEFAULT_BUDGET = 1 << 20
+
+_WINDOWED = ("conv2d", "maxpool", "avgpool")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -84,18 +100,43 @@ class Segment:
         return self.kind in ("fused", "tiled")
 
 
-def _buffer_bytes(prog: C.CompiledProgram, idx: int) -> int:
-    _, shape, dtype = prog.buffers[idx]
+def _size(shape) -> int:
     n = 1
     for d in shape:
         n *= int(d)
-    return n * _ITEM_BYTES[dtype]
+    return n
+
+
+def _buffer_bytes(prog: C.CompiledProgram, idx: int) -> int:
+    _, shape, dtype = prog.buffers[idx]
+    return _size(shape) * _ITEM_BYTES[dtype]
+
+
+def _window_geometry(prog: C.CompiledProgram, b) -> tuple:
+    """(padded input shape, pad, (oh, ow)) of a conv or pool batch: the
+    padded copy its taps window over, and the output extent."""
+    H, W, Cn = prog.buffers[b.in_idx[0]][1]
+    p = b.attrs.get("padding", 0)
+    oh, ow = prog.buffers[b.out_idx][1][:2]
+    return (H + 2 * p, W + 2 * p, Cn), p, (oh, ow)
+
+
+def _work_bytes(prog: C.CompiledProgram, step) -> int:
+    """In-kernel working set of a conv or pool step beyond its operands:
+    the padded int32 copy its taps window over plus one int32 tap window —
+    what the fused body holds in place of an im2col patch matrix."""
+    b = step.batch
+    if b.kind not in _WINDOWED:
+        return 0
+    padded, _, (oh, ow) = _window_geometry(prog, b)
+    return 4 * (_size(padded) + oh * ow * padded[2])
 
 
 def _step_bytes(prog: C.CompiledProgram, step, dual: bool) -> int:
     """Scratchpad residency of one step: streamed operands (inputs +
     weights, double-buffered when the scratchpad is dual-ported) + int32
-    accumulator for matmul kinds + the output tile."""
+    accumulator for matmul kinds + the output tile + the windowed working
+    set of conv and pool steps."""
     b = step.batch
     stream = sum(_buffer_bytes(prog, i) for i in b.in_idx)
     if b.w_idx is not None:
@@ -104,12 +145,9 @@ def _step_bytes(prog: C.CompiledProgram, step, dual: bool) -> int:
         stream *= 2
     acc = 0
     if b.kind in ("gemm", "conv2d"):
-        _, shape, _ = prog.buffers[b.out_idx]
-        n = 1
-        for d in shape:
-            n *= int(d)
-        acc = 4 * n
-    return stream + acc + _buffer_bytes(prog, step.out_idx)
+        acc = 4 * _size(prog.buffers[b.out_idx][1])
+    return (stream + acc + _buffer_bytes(prog, step.out_idx)
+            + _work_bytes(prog, step))
 
 
 def _pack(prog: C.CompiledProgram, plan, budget: int, dual: bool
@@ -154,25 +192,28 @@ def _pack(prog: C.CompiledProgram, plan, budget: int, dual: bool
 
 def plan_segments(prog: C.CompiledProgram, *, budget: int | None = None,
                   max_kernels: int | None = None) -> list[Segment]:
-    """Partition the pallas plan into <= `max_kernels` kernel-emitting
-    segments (default: the program's core count).
+    """Partition the pallas plan into kernel-emitting segments, none of
+    which holds more than the machine's scratchpad.
 
-    `budget` overrides the scratchpad capacity the packing uses
-    (`BackendOptions.scratchpad_budget`); when the pack exceeds the kernel
-    cap the budget doubles and packing reruns — larger segments, fewer
-    launches — until the per-core invariant holds.
+    `budget` (`BackendOptions.scratchpad_budget`) packs against less than
+    the capacity; a larger one is clamped to it. `max_kernels` (default:
+    the program's core count) is a target: while the pack emits more
+    kernels and the budget is below the capacity, the budget doubles
+    (capped at the capacity) and packing reruns — larger segments, fewer
+    launches. At the capacity the pack stands, however many kernels it
+    emits.
     """
     plan = C._pallas_plan(prog)
     hw = prog.hw
+    capacity = hw.scratchpad_bytes if hw is not None else _DEFAULT_BUDGET
     cap = max_kernels if max_kernels is not None else max(1, prog.num_cores)
-    b = budget if budget is not None else (
-        hw.scratchpad_bytes if hw is not None else _DEFAULT_BUDGET)
+    b = capacity if budget is None else min(budget, capacity)
     dual = hw.dual_ported if hw is not None else True
     while True:
         segments = _pack(prog, plan, b, dual)
-        if sum(s.emits_call for s in segments) <= cap:
+        if sum(s.emits_call for s in segments) <= cap or b >= capacity:
             break
-        b *= 2
+        b = min(2 * b, capacity)
     cores = max(1, prog.num_cores)
     out = []
     n_call = 0
@@ -188,9 +229,10 @@ def plan_segments(prog: C.CompiledProgram, *, budget: int | None = None,
 def segment_footprint(prog: C.CompiledProgram, seg: Segment,
                       dual: bool = True) -> int:
     """Scratchpad bytes a fused segment keeps resident: the sum of its
-    steps' streamed operands, accumulators, and output tiles — exactly
-    the quantity `_pack` budgets against. Public so the static analyzer
-    (repro.analysis) can check the packing instead of trusting it."""
+    steps' streamed operands, accumulators, output tiles and windowed
+    working sets — exactly the quantity `_pack` budgets against. Public so
+    the static analyzer (repro.analysis) can check the packing instead of
+    trusting it."""
     return sum(_step_bytes(prog, s, dual) for s in seg.steps)
 
 
@@ -203,32 +245,82 @@ def segment_io(prog: C.CompiledProgram, seg: Segment
 
 # -- emission -----------------------------------------------------------------
 
-def _emit_step(step, local: dict, wvals: dict, prog: C.CompiledProgram,
-               via_f32: bool):
-    """Execute one plan step on in-kernel values. local maps buffer idx ->
-    value; wvals maps weight buffer idx -> value."""
+def _mult_of(step):
+    """The requant multiplier a fused step applies, if any: its fused
+    epilogue's, or a standalone requant batch's own."""
+    if step.mult is not None:
+        return step.mult
+    return step.batch.mult if step.batch.kind == "requant" else None
+
+
+def _narrow(v: jax.Array, dtype: str) -> jax.Array:
+    """An int32 value cast through its buffer dtype (wrapping like the
+    oracle's astype) and widened back."""
+    if dtype == "int32":
+        return v
+    return v.astype(C._JNP_DT[dtype]).astype(jnp.int32)
+
+
+def _emit_step(step, local: dict, w_refs: dict, m_refs: dict,
+               s_refs: dict, prog: C.CompiledProgram, via_f32: bool) -> None:
+    """Execute one plan step on in-kernel int32 values. local maps buffer
+    idx -> value; w_refs maps weight buffer idx -> weight ref; m_refs and
+    s_refs map step out idx -> its (1, N) multiplier ref and its window
+    scratch."""
     b = step.batch
     a = b.attrs
-    if step.mode == "gemm":
-        x = local[b.in_idx[0]].reshape(a["M"], a["K"])
-        acc = dot_i32_exact(x, wvals[b.w_idx], via_f32=via_f32)
-        if step.mult is not None:
-            local[step.out_idx] = requant_epilogue(acc, jnp.asarray(step.mult))
+    out_dt = prog.buffers[step.out_idx][2]
+    mult = m_refs[step.out_idx][...] if step.out_idx in m_refs else None
+    if b.kind in ("gemm", "conv2d"):
+        x = local[b.in_idx[0]]
+        if b.kind == "gemm":
+            acc = dot_i32_exact(x.reshape(a["M"], a["K"]).astype(jnp.int8),
+                                w_refs[b.w_idx][...], via_f32=via_f32)
         else:
-            local[step.out_idx] = acc.astype(
-                C._JNP_DT[prog.buffers[step.out_idx][2]])
-    elif step.mode == "conv2d":
-        cols = im2col_patches(local[b.in_idx[0]], a["kh"], a["kw"],
-                              a["stride"], a["padding"])
-        acc = dot_i32_exact(cols, wvals[b.w_idx], via_f32=via_f32)
-        oh, ow = conv_out_hw(a)
-        if step.mult is not None:
-            out = requant_epilogue(acc, jnp.asarray(step.mult))
-        else:
-            out = acc.astype(C._JNP_DT[prog.buffers[step.out_idx][2]])
-        local[step.out_idx] = out.reshape(oh, ow, a["C_out"])
-    else:                            # "jax": the shared per-op emitters
-        local[b.out_idx] = C._jax_op(b, local, prog, wvals)
+            _, pad, (oh, ow) = _window_geometry(prog, b)
+            src = s_refs[step.out_idx]
+            fill_window(src, x, pad, 0)
+            acc = conv_accumulate(src, w_refs[b.w_idx], a["C_in"], a["kh"],
+                                  a["kw"], a["stride"], oh, ow,
+                                  via_f32=via_f32)
+            acc = acc.reshape(oh, ow, a["C_out"])
+        local[step.out_idx] = (_narrow(acc, out_dt) if mult is None else
+                               requant_epilogue(acc, mult, jnp.int32))
+        return
+    ins = [local[i] for i in b.in_idx]
+    if b.kind == "requant":
+        out = requant_epilogue(ins[0], mult, jnp.int32)
+    elif b.kind == "relu":
+        out = jnp.maximum(ins[0], 0)
+    elif b.kind == "add":
+        s = ins[0] + ins[1]
+        out = jnp.clip(s, -128, 127) if out_dt == "int8" else \
+            _narrow(s, out_dt)
+    elif b.kind in ("maxpool", "avgpool"):
+        padded, pad, (oh, ow) = _window_geometry(prog, b)
+        k = a["k"]
+        src = s_refs[step.out_idx]
+        in_dt = C._JNP_DT[prog.buffers[b.in_idx[0]][2]]
+        fill_window(src, ins[0], pad, int(jnp.iinfo(in_dt).min)
+                    if b.kind == "maxpool" else 0)
+        taps = [chunks for _, chunks in tap_windows(
+            src, padded[2], k, k, a["stride"], oh, ow)]
+        red = jnp.maximum if b.kind == "maxpool" else jnp.add
+        out = jnp.concatenate(
+            [functools.reduce(red, [t[j][2] for t in taps])
+             for j in range(len(taps[0]))], axis=-1)
+        if b.kind == "avgpool":
+            out = jnp.clip(round_half_even_div(out, k * k), -128, 127)
+    elif b.kind == "gap":
+        H, W = ins[0].shape[0], ins[0].shape[1]
+        m = round_half_even_div(ins[0].sum(axis=(0, 1)).reshape(1, -1),
+                                H * W)
+        out = jnp.clip(m, -128, 127)
+    elif b.kind == "concat":
+        out = jnp.concatenate(ins, axis=-1)
+    else:
+        raise C.CompileError(f"op kind {b.kind} not lowered")
+    local[step.out_idx] = out
 
 
 def _segment_io(prog: C.CompiledProgram, seg: Segment
@@ -258,28 +350,102 @@ def _segment_io(prog: C.CompiledProgram, seg: Segment
     return ins, wids, outs
 
 
+def _tiled_bytes(shape, dtype: str) -> int:
+    return vmem.tile_bytes(shape, _ITEM_BYTES[dtype])
+
+
+def _weight_layout(prog: C.CompiledProgram, w_idx: int, seg: Segment):
+    """Shape a fused kernel receives weight buffer `w_idx` in: (K, N) for
+    a gemm, tap-major (kh*kw, C, N) for a conv."""
+    shape = tuple(prog.buffers[w_idx][1])
+    for s in seg.steps:
+        a = s.batch.attrs
+        if s.batch.w_idx == w_idx and s.batch.kind == "conv2d":
+            return (a["kh"] * a["kw"], a["C_in"], shape[-1])
+    return shape
+
+
+def _fused_vmem_bytes(prog: C.CompiledProgram, seg: Segment) -> int:
+    """VMEM a fused segment's kernel holds in the chip's tiled layout: its
+    operand and output blocks (doubled: the batched program pipelines them
+    over the batch grid) plus every step's int32 value, accumulator and
+    windowed working set."""
+    ins, wids, outs = _segment_io(prog, seg)
+    blocks = sum(_tiled_bytes(prog.buffers[i][1], prog.buffers[i][2])
+                 for i in ins + outs)
+    blocks += sum(_tiled_bytes(_weight_layout(prog, i, seg), "int8")
+                  for i in wids)
+    values = 0
+    for s in seg.steps:
+        out_shape = prog.buffers[s.out_idx][1]
+        values += 2 * _tiled_bytes(out_shape, "int32")
+        if _mult_of(s) is not None:
+            blocks += _tiled_bytes((1, out_shape[-1]), "f32")
+        if s.batch.kind in _WINDOWED:
+            padded, _, (oh, ow) = _window_geometry(prog, s.batch)
+            values += (vmem.tile_bytes(window_scratch(*padded).shape, 4)
+                       + 2 * _tiled_bytes((oh * ow, padded[2]), "int32"))
+    return 2 * blocks + values
+
+
+def segment_vmem_bytes(prog: C.CompiledProgram, seg: Segment) -> int:
+    """VMEM bytes the kernel a segment emits holds (0 for "outside")."""
+    if seg.kind == "fused":
+        return _fused_vmem_bytes(prog, seg)
+    if seg.kind == "outside":
+        return 0
+    step = seg.steps[0]
+    a = step.batch.attrs
+    requant = step.mult is not None
+    if step.mode == "gemm":
+        bm, bn, bk = step.blocks
+        return gemm_vmem_bytes(a["M"], a["K"], a["N"], bm=bm, bn=bn, bk=bk,
+                               requant=requant)
+    rows_t, bn = step.blocks
+    return conv2d_vmem_bytes(a["H"], a["W"], a["C_in"], a["C_out"],
+                             kh=a["kh"], kw=a["kw"], stride=a["stride"],
+                             padding=a["padding"], rows_t=rows_t, bn=bn,
+                             requant=requant)
+
+
 def _run_fused(prog: C.CompiledProgram, seg: Segment, vals: list,
                weights: dict, interpret: bool) -> None:
     ins, wids, outs = _segment_io(prog, seg)
+    mults = [(s.out_idx, _mult_of(s)) for s in seg.steps
+             if _mult_of(s) is not None]
+    windowed = [s for s in seg.steps if s.batch.kind in _WINDOWED]
     steps = seg.steps
+    n_in, n_w, n_m, n_o = len(ins), len(wids), len(mults), len(outs)
 
     def kernel(*refs):
-        in_refs = refs[:len(ins)]
-        w_refs = refs[len(ins):len(ins) + len(wids)]
-        out_refs = refs[len(ins) + len(wids):]
-        local = {i: r[...] for i, r in zip(ins, in_refs)}
-        wvals = {i: r[...] for i, r in zip(wids, w_refs)}
+        w_refs = dict(zip(wids, refs[n_in:n_in + n_w]))
+        m_refs = {o: r for (o, _), r in
+                  zip(mults, refs[n_in + n_w:n_in + n_w + n_m])}
+        out_refs = refs[n_in + n_w + n_m:n_in + n_w + n_m + n_o]
+        s_refs = {s.out_idx: r for s, r in
+                  zip(windowed, refs[n_in + n_w + n_m + n_o:])}
+        local = {i: r[...].astype(jnp.int32)
+                 for i, r in zip(ins, refs[:n_in])}
         for step in steps:
-            _emit_step(step, local, wvals, prog, via_f32=interpret)
+            _emit_step(step, local, w_refs, m_refs, s_refs, prog,
+                       via_f32=interpret)
         for i, r in zip(outs, out_refs):
-            r[...] = local[i]
+            r[...] = local[i].astype(r.dtype)
 
     out_shape = [jax.ShapeDtypeStruct(tuple(prog.buffers[i][1]),
                                       C._JNP_DT[prog.buffers[i][2]])
                  for i in outs]
-    operands = [vals[i] for i in ins] + [weights[i] for i in wids]
-    res = pl.pallas_call(kernel, out_shape=out_shape,
-                         interpret=interpret)(*operands)
+    operands = ([vals[i] for i in ins]
+                + [weights[i].reshape(_weight_layout(prog, i, seg))
+                   for i in wids]
+                + [_as_channel_mult(m, prog.buffers[o][1][-1]).reshape(1, -1)
+                   for o, m in mults])
+    res = pl.pallas_call(
+        kernel, out_shape=out_shape,
+        scratch_shapes=[window_scratch(*_window_geometry(prog, s.batch)[0])
+                        for s in windowed],
+        compiler_params=vmem.compiler_params(_fused_vmem_bytes(prog, seg)),
+        interpret=interpret)(*operands)
     for i, r in zip(outs, res):
         vals[i] = r
 
@@ -308,19 +474,20 @@ def _run_tiled(prog: C.CompiledProgram, step, vals: list, weights: dict,
             interpret=interpret)
 
 
-def megakernel_single(prog: C.CompiledProgram, *, interpret: bool = False,
-                      budget: int | None = None,
-                      max_kernels: int | None = None):
-    """Single-sample traced function over the segment plan (cached per
-    (interpret, budget, max_kernels) on the program). Same calling
-    convention as `compiled.pallas_single`; bit-exact against it."""
-    key = ("mega_single", bool(interpret), budget, max_kernels)
+def megakernel_fn(prog: C.CompiledProgram, *, interpret: bool = False,
+                  budget: int | None = None,
+                  max_kernels: int | None = None):
+    """The megakernel program as ``fn(weights, inputs) -> outputs`` over
+    the segment plan (cached per (interpret, budget, max_kernels) on the
+    program). `weights` maps weight buffer idx -> array
+    (`device_weights`); taking them as arguments keeps them out of the
+    executable instead of baking them in as constants."""
+    key = ("mega_fn", bool(interpret), budget, max_kernels)
     if key not in prog._pallas_cache:
         segments = plan_segments(prog, budget=budget,
                                  max_kernels=max_kernels)
-        weights = {i: jnp.asarray(w) for i, w in prog.weights.items()}
 
-        def single(inputs: dict):
+        def fn(weights: dict, inputs: dict):
             vals: list = [None] * len(prog.buffers)
             for name, i in prog.input_idx.items():
                 vals[i] = inputs[name]
@@ -334,8 +501,27 @@ def megakernel_single(prog: C.CompiledProgram, *, interpret: bool = False,
                     vals[b.out_idx] = C._jax_op(b, vals, prog, weights)
             return {name: vals[i] for name, i in prog.output_idx.items()}
 
-        prog._pallas_cache[key] = single
+        prog._pallas_cache[key] = fn
     return prog._pallas_cache[key]
+
+
+def device_weights(prog: C.CompiledProgram) -> dict:
+    """The program's weights as device arrays, put once per program."""
+    if "weights" not in prog._pallas_cache:
+        prog._pallas_cache["weights"] = {
+            i: jnp.asarray(w) for i, w in prog.weights.items()}
+    return prog._pallas_cache["weights"]
+
+
+def megakernel_single(prog: C.CompiledProgram, *, interpret: bool = False,
+                      budget: int | None = None,
+                      max_kernels: int | None = None):
+    """Single-sample traced function ``single(inputs)`` over the segment
+    plan, weights bound. Same calling convention as
+    `compiled.pallas_single`; bit-exact against it."""
+    fn = megakernel_fn(prog, interpret=interpret, budget=budget,
+                       max_kernels=max_kernels)
+    return functools.partial(fn, device_weights(prog))
 
 
 def jit_megakernel_single(prog: C.CompiledProgram, *,
@@ -345,9 +531,10 @@ def jit_megakernel_single(prog: C.CompiledProgram, *,
     interpret = C.resolve_interpret(interpret)
     key = ("mega_jit_single", bool(interpret), budget, max_kernels)
     if key not in prog._pallas_cache:
-        prog._pallas_cache[key] = jax.jit(megakernel_single(
-            prog, interpret=interpret, budget=budget,
-            max_kernels=max_kernels))
+        prog._pallas_cache[key] = functools.partial(
+            jax.jit(megakernel_fn(prog, interpret=interpret, budget=budget,
+                                  max_kernels=max_kernels)),
+            device_weights(prog))
     return prog._pallas_cache[key]
 
 
@@ -356,13 +543,15 @@ def megakernel_batched(prog: C.CompiledProgram, *,
                        budget: int | None = None,
                        max_kernels: int | None = None):
     """The megakernel program jitted and vmapped over a leading batch axis
-    (the `pallas` backend's batched serving step)."""
+    of the inputs (the `pallas` backend's batched serving step)."""
     interpret = C.resolve_interpret(interpret)
     key = ("mega_batched", bool(interpret), budget, max_kernels)
     if key not in prog._pallas_cache:
-        prog._pallas_cache[key] = jax.jit(jax.vmap(megakernel_single(
-            prog, interpret=interpret, budget=budget,
-            max_kernels=max_kernels)))
+        prog._pallas_cache[key] = functools.partial(
+            jax.jit(jax.vmap(megakernel_fn(
+                prog, interpret=interpret, budget=budget,
+                max_kernels=max_kernels), in_axes=(None, 0))),
+            device_weights(prog))
     return prog._pallas_cache[key]
 
 
@@ -400,6 +589,6 @@ def _count_pallas_eqns(jaxpr) -> int:
 
 def count_pallas_calls(fn, sample_inputs: dict) -> int:
     """Number of pallas_call equations in `fn`'s jaxpr (recursing into
-    sub-jaxprs) — the <= num_cores invariant check the tests gate on."""
+    sub-jaxprs) — the kernel count the plan promises."""
     jaxpr = jax.make_jaxpr(fn)(sample_inputs)
     return _count_pallas_eqns(jaxpr.jaxpr)

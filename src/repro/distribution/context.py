@@ -31,11 +31,5 @@ def current_mesh():
     m = getattr(_state, "mesh", None)
     if m is not None:
         return m
-    # only on newer jax; older versions have no trace-time abstract mesh,
-    # so the explicit with_mesh_context above is the only source there
-    get_abstract = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get_abstract is not None:
-        am = get_abstract()
-        if am is not None and not getattr(am, "empty", True):
-            return am
-    return None
+    am = jax.sharding.get_abstract_mesh()
+    return None if am.empty else am

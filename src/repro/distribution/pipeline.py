@@ -20,7 +20,6 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 
 def pipeline_apply(mesh, layer_fn: Callable, stage_params, x_micro,
@@ -80,8 +79,8 @@ def pipeline_apply(mesh, layer_fn: Callable, stage_params, x_micro,
 
     in_specs = (jax.tree.map(lambda _: P(axis), stage_params),
                 P())                                  # xs replicated
-    fn = shard_map(per_device, mesh=mesh, in_specs=in_specs,
-                   out_specs=P(), check_rep=False)
+    fn = jax.shard_map(per_device, mesh=mesh, in_specs=in_specs,
+                       out_specs=P(), check_vma=False)
     return fn(stage_params, x_micro)
 
 

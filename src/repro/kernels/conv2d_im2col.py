@@ -3,13 +3,23 @@
 The paper's rule — "the actual duplication of memory is only carried out in
 the scratchpad" — adapted one step further for TPU: the duplication never
 materializes at all. The kernel keeps the raw NHWC input band in VMEM and
-accumulates kh*kw shifted (strided-slice) GEMMs against the corresponding
-weight rows, so HBM traffic is the raw band and VMEM holds only the raw
+accumulates kh*kw shifted (strided) GEMMs against the corresponding
+weight slabs, so HBM traffic is the raw band and VMEM holds only the raw
 band + weight tile + int32 accumulator.
 
 Grid: (output-row bands, output-channel tiles). Each band (with its halo) is
 streamed per grid step; Pallas double-buffers the band transfer against the
 previous step's compute (the paper's dual-ported scratchpad).
+
+What Mosaic (v5e) accepts shapes the tap reads: a strided *value* slice is
+refused, and strided loads exist only for 32-bit refs whose last dim fits
+one 128-lane row. So the band is widened to int32 once into a chunk-major
+VMEM scratch (`fill_window`) and every tap is a strided ref load per
+128-channel chunk (`tap_windows`). Window reshapes happen on the int32
+values; each window narrows to int8 right before its MXU dot. The megakernel's fused
+segments window their scratchpad-resident values through the same
+helpers (`conv_accumulate`), so both paths share one tap order and one
+exact int8 contraction.
 """
 
 from __future__ import annotations
@@ -19,62 +29,124 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from .gemm_int8 import requant_epilogue
+from . import vmem
+from .gemm_int8 import _block, dot_i32_exact, requant_epilogue
 from .ref import _as_channel_mult
 
 
-def im2col_patches(x: jax.Array, kh: int, kw: int, stride: int = 1,
-                   padding: int = 0) -> jax.Array:
-    """(H, W, C) -> (oh*ow, kh*kw*C) patch matrix, value-level.
+LANES = 128
 
-    kh*kw shifted strided slices concatenated along the channel axis —
-    column order (di*kw + dj)*C + c, matching the (kh*kw*C, N) weight
-    layout of the conv kernels. Operates on values, so it works inside a
-    Pallas kernel body: the megakernel uses it to im2col scratchpad-
-    resident bands ("the actual duplication of memory is only carried out
-    in the scratchpad"), feeding one fused GEMM per conv instead of kh*kw
-    accumulation steps.
-    """
+
+def lane_chunks(c: int) -> list[tuple[int, int]]:
+    """[c0, c1) channel ranges of at most one 128-lane vreg width: Mosaic
+    strided-loads only refs whose last dim fits one lane row, so windowed
+    copies keep channels chunk-major."""
+    return [(c0, min(c, c0 + LANES)) for c0 in range(0, c, LANES)]
+
+
+def window_scratch(hp: int, wp: int, c: int):
+    """The chunk-major padded int32 VMEM copy (n_chunks, hp, wp, lanes) a
+    conv or pool windows over."""
+    return pltpu.VMEM((len(lane_chunks(c)), hp, wp, min(c, LANES)),
+                      jnp.int32)
+
+
+def fill_window(src, x, pad: int, fill: int | None = None) -> None:
+    """Write the int32 (H, W, C) value or int8 ref `x` into the scratch
+    `src` at offset (pad, pad), chunk by chunk, the border set to `fill`
+    (None: `x` covers the whole scratch)."""
+    if fill is not None:
+        src[...] = jnp.full(src.shape, fill, jnp.int32)
+    x = x[...].astype(jnp.int32)     # whole loads: no unaligned ref slices
     H, W, C = x.shape
-    oh = (H + 2 * padding - kh) // stride + 1
-    ow = (W + 2 * padding - kw) // stride + 1
-    xp = jnp.pad(x, ((padding, padding), (padding, padding), (0, 0)))
-    cols = [jax.lax.slice(
-        xp, (di, dj, 0),
-        (di + (oh - 1) * stride + 1, dj + (ow - 1) * stride + 1, C),
-        (stride, stride, 1)).reshape(oh * ow, C)
-        for di in range(kh) for dj in range(kw)]
-    return jnp.concatenate(cols, axis=1)
+    for k, (c0, c1) in enumerate(lane_chunks(C)):
+        src[k, pad:pad + H, pad:pad + W, :c1 - c0] = x[:, :, c0:c1]
+
+
+def tap_windows(src, c: int, kh: int, kw: int, stride: int, oh: int,
+                ow: int):
+    """Yield ((di, dj), chunks) for every kernel tap, where chunks lists
+    (c0, c1, window): the (oh, ow, c1 - c0) int32 window of channel chunk
+    [c0, c1) that tap (di, dj) reads from the scratch `src`, as one
+    strided ref load."""
+    for di in range(kh):
+        for dj in range(kw):
+            yield (di, dj), [
+                (c0, c1, src[k, pl.ds(di, oh, stride=stride),
+                             pl.ds(dj, ow, stride=stride), :c1 - c0])
+                for k, (c0, c1) in enumerate(lane_chunks(c))]
+
+
+def conv_accumulate(src, w, c: int, kh: int, kw: int, stride: int, oh: int,
+                    ow: int, via_f32: bool = False) -> jax.Array:
+    """(oh*ow, N) exact int32 accumulator of a conv over the scratch
+    `src`; `w` is the (kh*kw, C, N) int8 weight ref, tap-major — the
+    (kh*kw*C, N) conv weight layout with the tap axis split off."""
+    acc = None
+    for (di, dj), chunks in tap_windows(src, c, kh, kw, stride, oh, ow):
+        for c0, c1, win in chunks:
+            x = win.reshape(oh * ow, c1 - c0).astype(jnp.int8)
+            part = dot_i32_exact(x, w[di * kw + dj, c0:c1, :],
+                                 via_f32=via_f32)
+            acc = part if acc is None else acc + part
+    return acc
 
 
 def _make_kernel(kh: int, kw: int, stride: int, rows_t: int, ow: int,
-                 requant: bool = False):
+                 requant: bool, via_f32: bool):
     def kernel(x_ref, w_ref, *refs):
-        # x_ref: (1, in_rows_t, Wp, C) int8 raw band (halo included)
-        # w_ref: (kh*kw*C, bn) int8
+        # x_ref: (1, in_rows, Wp, C) int8 raw band (halo included)
+        # w_ref: (kh*kw, C, bn) int8
         # [m_ref: (1, bn) f32 requant multiplier, if fused]
-        # o_ref: (rows_t*ow, bn) int32 (int8 if fused requant)
-        o_ref = refs[-1]
-        x = x_ref[0]
-        C = x.shape[2]
-        acc = jnp.zeros((rows_t * ow, o_ref.shape[1]), jnp.int32)
-        for di in range(kh):
-            for dj in range(kw):
-                patch = jax.lax.slice(
-                    x, (di, dj, 0),
-                    (di + (rows_t - 1) * stride + 1,
-                     dj + (ow - 1) * stride + 1, C),
-                    (stride, stride, 1)).reshape(rows_t * ow, C)
-                wslab = w_ref[(di * kw + dj) * C:(di * kw + dj + 1) * C, :]
-                acc = acc + jax.lax.dot_general(
-                    patch, wslab, (((1,), (0,)), ((), ())),
-                    preferred_element_type=jnp.int32)
+        # o_ref: (rows_t, ow, bn) int32 (int8 if fused requant)
+        # xs:    chunk-major int32 copy of the band the taps window over
+        o_ref, xs = refs[-2], refs[-1]
+        fill_window(xs, x_ref.at[0], 0)
+        acc = conv_accumulate(xs, w_ref, x_ref.shape[-1], kh, kw, stride,
+                              rows_t, ow, via_f32=via_f32)
+        acc = acc.reshape(rows_t, ow, acc.shape[-1])
         if requant:
             o_ref[...] = requant_epilogue(acc, refs[0][...])
         else:
             o_ref[...] = acc
     return kernel
+
+
+def _conv_geometry(H: int, W: int, kh: int, kw: int, stride: int,
+                   padding: int, rows_t: int) -> tuple:
+    """(oh, ow, rows_t, oh_p, in_rows, wp) of the banded conv: output
+    dims, the band height clamped to oh, the padded output height, the
+    input rows a band reads (halo included) and the padded input width
+    (every tap in range, rounded to whole 8-row sublane tiles)."""
+    oh = (H + 2 * padding - kh) // stride + 1
+    ow = (W + 2 * padding - kw) // stride + 1
+    rows_t = min(rows_t, oh)
+    oh_p = -(-oh // rows_t) * rows_t
+    in_rows = (rows_t - 1) * stride + kh
+    wp = -(-max(W + 2 * padding, (ow - 1) * stride + kw) // 8) * 8
+    return oh, ow, rows_t, oh_p, in_rows, wp
+
+
+def conv2d_vmem_bytes(H: int, W: int, C: int, N: int, *, kh: int, kw: int,
+                      stride: int, padding: int, rows_t: int, bn: int,
+                      requant: bool) -> int:
+    """VMEM the tiled conv kernel holds: double-buffered band, weight,
+    multiplier and output blocks, the int32 band scratch, and the
+    accumulator, one tap window and its partial product."""
+    _, ow, rows_t, _, in_rows, wp = _conv_geometry(H, W, kh, kw, stride,
+                                                   padding, rows_t)
+    bn = _block(bn, N, 128)
+    m = rows_t * ow
+    blocks = (vmem.tile_bytes((in_rows, wp, C), 1)
+              + vmem.tile_bytes((kh * kw, C, bn), 1)
+              + vmem.tile_bytes((1, bn), 4)
+              + vmem.tile_bytes((rows_t, ow, bn), 1 if requant else 4))
+    values = (vmem.tile_bytes(window_scratch(in_rows, wp, C).shape, 4)
+              + 2 * vmem.tile_bytes((m, bn), 4)
+              + vmem.tile_bytes((m, C), 4) + vmem.tile_bytes((m, C), 1))
+    return 2 * blocks + values
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -91,26 +163,21 @@ def conv2d_int8_pallas(x: jax.Array, w: jax.Array,
     is folded to int8 in the kernel epilogue (`requant_epilogue` — the same
     round-half-even contract as the GEMM kernel and `kernels.ref`), so the
     int32 tensor never leaves VMEM. Block shapes (rows_t, bn) can be derived
-    from a scratchpad budget with `repro.hw.derive_conv_blocks`.
+    from a scratchpad budget with `repro.hw.derive_conv_blocks`; `bn` is
+    rounded to whole 128-lane tiles (or the whole channel axis).
     """
     H, W, C = x.shape
     KKC, N = w.shape
     assert KKC == kh * kw * C
-    oh = (H + 2 * padding - kh) // stride + 1
-    ow = (W + 2 * padding - kw) // stride + 1
-
-    rows_t = min(rows_t, oh)
-    bn_ = min(bn, N)
-    oh_p = -(-oh // rows_t) * rows_t
+    oh, ow, rows_t, oh_p, in_rows_t, wp_ = _conv_geometry(
+        H, W, kh, kw, stride, padding, rows_t)
+    bn_ = _block(bn, N, 128)
     Np = -(-N // bn_) * bn_
     # pad input so every band's halo slice is in range
     need_rows = (oh_p - 1) * stride + kh
-    need_cols = (ow - 1) * stride + kw
     xp = jnp.pad(x, ((padding, max(0, need_rows - H - padding)),
-                     (padding, max(0, need_cols - W - padding)),
-                     (0, 0)))
-    wp = jnp.pad(w, ((0, 0), (0, Np - N)))
-    in_rows_t = (rows_t - 1) * stride + kh
+                     (padding, wp_ - W - padding), (0, 0)))
+    wp = jnp.pad(w.reshape(kh * kw, C, N), ((0, 0), (0, 0), (0, Np - N)))
     # bands overlap by the halo; BlockSpec blocks cannot overlap, so the
     # wrapper materializes per-band views (XLA fuses the gather with the
     # HBM->VMEM stream; on the paper machine this is the raw-band DMA)
@@ -120,11 +187,12 @@ def conv2d_int8_pallas(x: jax.Array, w: jax.Array,
             xp, (s, 0, 0), (in_rows_t, xp.shape[1], C)))(starts)
 
     fused = requant_mult is not None
-    kernel = _make_kernel(kh, kw, stride, rows_t, ow, requant=fused)
+    kernel = _make_kernel(kh, kw, stride, rows_t, ow, requant=fused,
+                          via_f32=interpret)
     in_specs = [
         pl.BlockSpec((1, in_rows_t, xp.shape[1], C),
                      lambda i, j: (i, 0, 0, 0)),
-        pl.BlockSpec((kh * kw * C, bn_), lambda i, j: (0, j)),
+        pl.BlockSpec((kh * kw, C, bn_), lambda i, j: (0, 0, j)),
     ]
     operands = [bands, wp]
     if fused:
@@ -135,9 +203,13 @@ def conv2d_int8_pallas(x: jax.Array, w: jax.Array,
         kernel,
         grid=(oh_p // rows_t, Np // bn_),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((rows_t * ow, bn_), lambda i, j: (i, j)),
+        out_specs=pl.BlockSpec((rows_t, ow, bn_), lambda i, j: (i, 0, j)),
         out_shape=jax.ShapeDtypeStruct(
-            (oh_p * ow, Np), jnp.int8 if fused else jnp.int32),
+            (oh_p, ow, Np), jnp.int8 if fused else jnp.int32),
+        scratch_shapes=[window_scratch(in_rows_t, xp.shape[1], C)],
+        compiler_params=vmem.compiler_params(conv2d_vmem_bytes(
+            H, W, C, N, kh=kh, kw=kw, stride=stride, padding=padding,
+            rows_t=rows_t, bn=bn, requant=fused)),
         interpret=interpret,
     )(*operands)
-    return out[:oh * ow, :N].reshape(oh, ow, N)
+    return out[:oh, :, :N]

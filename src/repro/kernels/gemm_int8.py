@@ -14,9 +14,10 @@ MXU (128x128 systolic, int8 path at 2x bf16 rate, VMEM scratchpad):
   * the epilogue folds the int32 tile to int8 via the same fixed-point
     requant math as `repro.core.quantize.requantize` (bit-exact).
 
-Block shapes default to MXU-aligned (128, 128, 128); VMEM footprint =
-bm*bk + bk*bn (int8) + bm*bn*4 (acc) + out tile, well under the ~128 MiB
-VMEM with room for Pallas' double buffering.
+Block shapes default to MXU-aligned (128, 128, 128) and are rounded to
+what Mosaic tiles (`_block`); the call states its scoped-VMEM limit from
+the double-buffered x/w/out blocks plus the int32 accumulator
+(`kernels.vmem`).
 """
 
 from __future__ import annotations
@@ -28,6 +29,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from . import vmem
 from .ref import _as_channel_mult
 
 
@@ -86,7 +88,8 @@ def dot_i32_exact(x: jax.Array, w: jax.Array, *,
     return acc
 
 
-def requant_epilogue(acc: jax.Array, mult: jax.Array) -> jax.Array:
+def requant_epilogue(acc: jax.Array, mult: jax.Array,
+                     dtype=jnp.int8) -> jax.Array:
     """int32 accumulator tile -> int8, the repo's single requant definition.
 
     float32 multiply + round-half-even + saturate. `jnp.round` rounds halves
@@ -94,10 +97,12 @@ def requant_epilogue(acc: jax.Array, mult: jax.Array) -> jax.Array:
     path, `quantize.requantize`, the executor's `_requant_np` (np.round is
     also half-even), and the integer-exact `kernels.ref.round_half_even_div`
     semantics on exact-half quotients. Shared by the GEMM and conv kernels
-    so the fused epilogue can never drift from the oracle.
+    so the fused epilogue can never drift from the oracle. `dtype=int32`
+    returns the same int8-ranged values widened, for kernel bodies that
+    keep their values in 32-bit layouts.
     """
     y = jnp.round(acc.astype(jnp.float32) * mult)
-    return jnp.clip(y, -128, 127).astype(jnp.int8)
+    return jnp.clip(y, -128, 127).astype(dtype)
 
 
 def _gemm_requant_kernel(x_ref, w_ref, m_ref, o_ref, acc_ref):
@@ -116,6 +121,33 @@ def _gemm_requant_kernel(x_ref, w_ref, m_ref, o_ref, acc_ref):
         o_ref[...] = requant_epilogue(acc_ref[...], m_ref[...])
 
 
+def _block(b: int, dim: int, align: int) -> int:
+    """A block extent Mosaic accepts along an axis of size `dim`: the
+    whole axis, or `b` rounded up to the layout tile `align`."""
+    if b >= dim:
+        return dim
+    return min(dim, -(-b // align) * align)
+
+
+def gemm_blocks(M: int, K: int, N: int, bm: int, bn: int, bk: int
+                ) -> tuple[int, int, int]:
+    """The (bm, bn, bk) the GEMM kernel runs for requested blocks: each
+    rounded to the int8 layout tile (32 rows, 128 lanes) or the whole
+    axis."""
+    return _block(bm, M, 32), _block(bn, N, 128), _block(bk, K, 128)
+
+
+def gemm_vmem_bytes(M: int, K: int, N: int, *, bm: int, bn: int, bk: int,
+                    requant: bool) -> int:
+    """VMEM the GEMM kernel holds: double-buffered x, w, multiplier and
+    output blocks, the int32 accumulator scratch and one partial product."""
+    bm, bn, bk = gemm_blocks(M, K, N, bm, bn, bk)
+    blocks = (vmem.tile_bytes((bm, bk), 1) + vmem.tile_bytes((bk, bn), 1)
+              + vmem.tile_bytes((1, bn), 4)
+              + vmem.tile_bytes((bm, bn), 1 if requant else 4))
+    return 2 * blocks + 2 * vmem.tile_bytes((bm, bn), 4)
+
+
 @functools.partial(jax.jit, static_argnames=("bm", "bn", "bk", "interpret"))
 def gemm_int8_pallas(x: jax.Array, w: jax.Array,
                      requant_mult: jax.Array | None = None,
@@ -128,18 +160,21 @@ def gemm_int8_pallas(x: jax.Array, w: jax.Array,
     per-channel (N,) vector (both broadcast, as in `quantize.requantize`).
     Block shapes can be derived from a scratchpad budget with
     `repro.hw.derive_gemm_blocks` (the compiled executor's pallas backend
-    does exactly that).
+    does exactly that); each is rounded to the int8 layout tile (32 rows,
+    128 lanes) or clamped to the whole axis.
     """
     M, K = x.shape
     K2, N = w.shape
     assert K == K2, (x.shape, w.shape)
     if requant_mult is not None:
         requant_mult = _as_channel_mult(requant_mult, N)
-    bm_, bn_, bk_ = min(bm, M), min(bn, N), min(bk, K)
+    bm_, bn_, bk_ = gemm_blocks(M, K, N, bm, bn, bk)
     Mp, Np, Kp = -(-M // bm_) * bm_, -(-N // bn_) * bn_, -(-K // bk_) * bk_
     xp = jnp.pad(x, ((0, Mp - M), (0, Kp - K)))
     wp = jnp.pad(w, ((0, Kp - K), (0, Np - N)))
     grid = (Mp // bm_, Np // bn_, Kp // bk_)
+    params = vmem.compiler_params(gemm_vmem_bytes(
+        M, K, N, bm=bm, bn=bn, bk=bk, requant=requant_mult is not None))
 
     if requant_mult is None:
         out = pl.pallas_call(
@@ -150,6 +185,7 @@ def gemm_int8_pallas(x: jax.Array, w: jax.Array,
             out_specs=pl.BlockSpec((bm_, bn_), lambda i, j, k: (i, j)),
             out_shape=jax.ShapeDtypeStruct((Mp, Np), jnp.int32),
             scratch_shapes=[pltpu.VMEM((bm_, bn_), jnp.int32)],
+            compiler_params=params,
             interpret=interpret,
         )(xp, wp)
     else:
@@ -163,6 +199,7 @@ def gemm_int8_pallas(x: jax.Array, w: jax.Array,
             out_specs=pl.BlockSpec((bm_, bn_), lambda i, j, k: (i, j)),
             out_shape=jax.ShapeDtypeStruct((Mp, Np), jnp.int8),
             scratch_shapes=[pltpu.VMEM((bm_, bn_), jnp.int32)],
+            compiler_params=params,
             interpret=interpret,
         )(xp, wp, mp.reshape(1, Np))
     return out[:M, :N]

@@ -11,25 +11,17 @@ from __future__ import annotations
 import jax
 
 
-def axis_types_kw(n_axes: int) -> dict:
-    """`axis_types=` kwarg for `jax.make_mesh`, if this jax has it.
-
-    `jax.sharding.AxisType` only exists on newer jax; on older versions
-    every mesh axis is implicitly Auto, so omitting the kwarg is
-    equivalent. Centralized here so meshes (and mesh-building tests)
-    construct identically across the supported jax range.
-    """
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n_axes}
+def auto_axes(n_axes: int) -> tuple:
+    """`axis_types` for a mesh whose axes are all Auto (sharding decided
+    by the compiler), the way every mesh in this repository is built."""
+    return (jax.sharding.AxisType.Auto,) * n_axes
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 single pod (256 chips) or 2x16x16 two-pod (512 chips)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **axis_types_kw(len(axes)))
+    return jax.make_mesh(shape, axes, auto_axes(len(axes)))
 
 
 def make_host_mesh(data: int = 1, model: int = 1, pod: int = 0):
@@ -58,4 +50,4 @@ def make_host_mesh(data: int = 1, model: int = 1, pod: int = 0):
         shape, axes = (pod, data, model), ("pod", "data", "model")
     else:
         shape, axes = (data, model), ("data", "model")
-    return jax.make_mesh(shape, axes, **axis_types_kw(len(axes)))
+    return jax.make_mesh(shape, axes, auto_axes(len(axes)))

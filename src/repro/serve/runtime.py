@@ -470,11 +470,23 @@ class Server:
 
         Networks whose op kinds have no compiled lowering (LM decode
         graphs) are admitted for analysis and served through `step_fn`
-        (one request per job: ``step_fn(payload) -> output``).
+        (one request per job: ``step_fn(payload) -> output``). A Graph
+        with such op kinds and no `step_fn` raises `ServeError` — it would
+        have no executor; a ModelConfig may be admitted analysis-only and
+        given one later with `attach`.
 
         `criticality` orders overload shedding: higher levels shed later
         (see `OverloadPolicy`).
         """
+        if isinstance(net, Graph) and step_fn is None:
+            from ..core.compiled import SUPPORTED_KINDS
+            bad = sorted({op.kind for op in net.ops
+                          if op.kind not in SUPPORTED_KINDS})
+            if bad:
+                raise ServeError(
+                    f"network {name!r}: op kinds {bad} have no executable "
+                    f"lowering on backend {self.backend!r}; pass step_fn= "
+                    f"to serve it")
         snapshot = (dict(self._nets), self.report, self.compiled,
                     self._cursor, self.hyperperiods_completed,
                     self.clock_base_s)

@@ -12,7 +12,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.launch.mesh import axis_types_kw
+from repro.launch.mesh import auto_axes
 from repro.configs import ARCH_IDS, get_config, input_specs
 from repro.distribution.sharding import (cache_shardings, param_pspec,
                                          zero1_shardings)
@@ -21,7 +21,7 @@ from repro.models import init_params
 
 def _mesh_1x1():
     return jax.make_mesh((1, 1), ("data", "model"),
-                         **axis_types_kw(2))
+                         axis_types=auto_axes(2))
 
 
 @pytest.mark.parametrize("arch", ARCH_IDS)
@@ -81,8 +81,8 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, numpy as np
 from repro.distribution.pipeline import pipeline_apply, split_stages
-from repro.launch.mesh import axis_types_kw
-mesh = jax.make_mesh((4,), ("pipe",), **axis_types_kw(1))
+from repro.launch.mesh import auto_axes
+mesh = jax.make_mesh((4,), ("pipe",), axis_types=auto_axes(1))
 L, D, M, mb = 8, 16, 6, 4
 Ws = jax.random.normal(jax.random.PRNGKey(0), (L, D, D)) * 0.1
 layer_fn = lambda w, x: jnp.tanh(x @ w)
@@ -123,9 +123,9 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, json
 from repro.configs import get_config
 from repro.launch.dryrun import lower_cell
-from repro.launch.mesh import axis_types_kw
+from repro.launch.mesh import auto_axes
 mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"),
-                     **axis_types_kw(3))
+                     axis_types=auto_axes(3))
 for arch in ("smollm-135m", "mixtral-8x22b", "rwkv6-1.6b"):
     cfg = get_config(arch, reduced=True)
     lowered, compiled, chips = lower_cell(cfg, "train_4k", mesh,

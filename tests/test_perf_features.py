@@ -7,7 +7,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.launch.mesh import axis_types_kw
+from repro.launch.mesh import auto_axes
 from repro.configs import get_config
 from repro.models import (ModelConfig, decode_step, init_cache, init_params,
                           prefill_step)
@@ -90,7 +90,7 @@ def test_fsdp_shardings_shard_over_data():
     assert cfg.fsdp
     from repro.distribution.sharding import param_shardings
     mesh = jax.make_mesh((1, 1), ("data", "model"),
-                         **axis_types_kw(2))
+                         axis_types=auto_axes(2))
     key = jax.random.PRNGKey(0)
     specs = jax.eval_shape(lambda k: init_params(cfg, k), key)
     sh = param_shardings(cfg, mesh, specs)
@@ -122,7 +122,7 @@ def test_elastic_remesh_roundtrip(tmp_path):
     from repro.train.fault import elastic_remesh
     from jax.sharding import NamedSharding, PartitionSpec as P
     mesh1 = jax.make_mesh((1,), ("data",),
-                          **axis_types_kw(1))
+                          axis_types=auto_axes(1))
     tree = {"w": jnp.arange(64, dtype=jnp.float32).reshape(8, 8)}
     mgr = CheckpointManager(str(tmp_path), async_save=False)
     mgr.save(1, tree)

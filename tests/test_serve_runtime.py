@@ -90,6 +90,18 @@ def test_admission_error_rollback():
     assert srv.networks == nets_before and srv.report.schedulable
 
 
+def test_register_refuses_unexecutable_graph_without_step_fn():
+    """A Graph with op kinds the backend cannot lower gets no silent
+    analysis-only registration: without a step_fn it has no executor."""
+    from repro.core.lmgraph import lm_decode_graph
+    srv = _mixed_server()
+    nets_before = list(srv.networks)
+    with pytest.raises(ServeError, match="no executable lowering"):
+        srv.register("lm_graph", lm_decode_graph(_lm_cfg(), 1, 64),
+                     period_s=1 / 25)
+    assert srv.networks == nets_before
+
+
 # -- queues ------------------------------------------------------------------
 
 def test_queue_reject_policy_backpressure():

@@ -15,7 +15,6 @@
   bench_cluster   4-replica ClusterServer vs one Server at capacity load,
                   modeled-time throughput behind the WCET-aware router;
                   emits BENCH_cluster.json
-  roofline        §Roofline table from the multi-pod dry-run artifacts
 
 ``--smoke`` runs a fast subset (taskset sweep + executor backends + serve
 runtime) suitable for CI; ``--only name[,name...]`` restricts the run to
@@ -65,7 +64,7 @@ def main(argv: list[str] | None = None) -> None:
             ("cluster", lambda: bench_cluster.run(csv_rows, smoke=True)),
         ]
     else:
-        from . import bench_wcet, bench_schedule, bench_kernels, roofline
+        from . import bench_wcet, bench_schedule, bench_kernels
         sections = [
             ("wcet", lambda: (bench_wcet.run(csv_rows),
                               bench_wcet.run_mapping_ablation(csv_rows))),
@@ -75,7 +74,6 @@ def main(argv: list[str] | None = None) -> None:
             ("kernels", lambda: bench_kernels.run(csv_rows)),
             ("serve", lambda: bench_serve.run(csv_rows)),
             ("cluster", lambda: bench_cluster.run(csv_rows)),
-            ("roofline", lambda: roofline.run(csv_rows)),
         ]
     if only is not None:
         unknown = only - {name for name, _ in sections}

@@ -50,6 +50,7 @@ from typing import Callable
 
 import numpy as np
 
+from .. import tracing
 from ..core import compiled as _C
 from ..core import megakernel as _MK
 
@@ -302,11 +303,20 @@ def _jax_batched(prog: _C.CompiledProgram,
 
 
 def _numpy_io(fn) -> Runner:
+    """numpy in, numpy out around a jitted program, in three spans: the
+    copy of the inputs to the device (`repro.runner.h2d`), the call, which
+    returns once the program is enqueued (`repro.runner.launch`), and the
+    copy of the outputs back, which waits for the device to finish
+    (`repro.runner.fetch`)."""
     import jax.numpy as jnp
 
     def run(inputs: dict) -> dict:
-        out = fn({k: jnp.asarray(v) for k, v in inputs.items()})
-        return {k: np.asarray(v) for k, v in out.items()}
+        with tracing.span("repro.runner.h2d"):
+            args = {k: jnp.asarray(v) for k, v in inputs.items()}
+        with tracing.span("repro.runner.launch"):
+            out = fn(args)
+        with tracing.span("repro.runner.fetch"):
+            return {k: np.asarray(v) for k, v in out.items()}
     return run
 
 

@@ -56,6 +56,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -408,8 +409,20 @@ def segment_vmem_bytes(prog: C.CompiledProgram, seg: Segment) -> int:
                              requant=requant)
 
 
+def segment_name(index: int, seg: Segment) -> str:
+    """The stable name of segment `index` of a plan: its index and its first
+    graph op. Every segment runs under it as a `jax.named_scope`, and a
+    fused segment's kernel carries it as its `pallas_call` name into the
+    compiled program and the profiler's trace. A tiled segment's kernel is
+    named after its shape instead (`conv2d_kernel_name`,
+    `gemm_kernel_name`): segments of one shape share one traced and
+    lowered kernel, which a name per segment would multiply."""
+    op = re.sub(r"[^0-9A-Za-z_]", "_", seg.steps[0].batch.name)
+    return f"seg{index:03d}_{op}"
+
+
 def _run_fused(prog: C.CompiledProgram, seg: Segment, vals: list,
-               weights: dict, interpret: bool) -> None:
+               weights: dict, interpret: bool, name: str) -> None:
     ins, wids, outs = _segment_io(prog, seg)
     mults = [(s.out_idx, _mult_of(s)) for s in seg.steps
              if _mult_of(s) is not None]
@@ -445,7 +458,7 @@ def _run_fused(prog: C.CompiledProgram, seg: Segment, vals: list,
         scratch_shapes=[window_scratch(*_window_geometry(prog, s.batch)[0])
                         for s in windowed],
         compiler_params=vmem.compiler_params(_fused_vmem_bytes(prog, seg)),
-        interpret=interpret)(*operands)
+        interpret=interpret, name=name)(*operands)
     for i, r in zip(outs, res):
         vals[i] = r
 
@@ -491,14 +504,17 @@ def megakernel_fn(prog: C.CompiledProgram, *, interpret: bool = False,
             vals: list = [None] * len(prog.buffers)
             for name, i in prog.input_idx.items():
                 vals[i] = inputs[name]
-            for seg in segments:
-                if seg.kind == "fused":
-                    _run_fused(prog, seg, vals, weights, interpret)
-                elif seg.kind == "tiled":
-                    _run_tiled(prog, seg.steps[0], vals, weights, interpret)
-                else:                # "outside": XLA-level fallback op
-                    b = seg.steps[0].batch
-                    vals[b.out_idx] = C._jax_op(b, vals, prog, weights)
+            for index, seg in enumerate(segments):
+                name = segment_name(index, seg)
+                with jax.named_scope(name):
+                    if seg.kind == "fused":
+                        _run_fused(prog, seg, vals, weights, interpret, name)
+                    elif seg.kind == "tiled":
+                        _run_tiled(prog, seg.steps[0], vals, weights,
+                                   interpret)
+                    else:            # "outside": XLA-level fallback op
+                        b = seg.steps[0].batch
+                        vals[b.out_idx] = C._jax_op(b, vals, prog, weights)
             return {name: vals[i] for name, i in prog.output_idx.items()}
 
         prog._pallas_cache[key] = fn
@@ -576,19 +592,24 @@ def _sub_jaxprs(v):
             yield inner
 
 
-def _count_pallas_eqns(jaxpr) -> int:
-    n = 0
+def _pallas_eqn_names(jaxpr) -> list[str]:
+    names = []
     for eqn in jaxpr.eqns:
         if eqn.primitive.name == "pallas_call":
-            n += 1
+            names.append(str(eqn.params["name"]))
         for v in eqn.params.values():
             for sub in _sub_jaxprs(v):
-                n += _count_pallas_eqns(sub)
-    return n
+                names += _pallas_eqn_names(sub)
+    return names
+
+
+def pallas_call_names(fn, sample_inputs: dict) -> list[str]:
+    """The names of the pallas_call equations in `fn`'s jaxpr (recursing
+    into sub-jaxprs), in program order."""
+    return _pallas_eqn_names(jax.make_jaxpr(fn)(sample_inputs).jaxpr)
 
 
 def count_pallas_calls(fn, sample_inputs: dict) -> int:
-    """Number of pallas_call equations in `fn`'s jaxpr (recursing into
-    sub-jaxprs) — the kernel count the plan promises."""
-    jaxpr = jax.make_jaxpr(fn)(sample_inputs)
-    return _count_pallas_eqns(jaxpr.jaxpr)
+    """Number of pallas_call equations in `fn`'s jaxpr — the kernel count
+    the plan promises."""
+    return len(pallas_call_names(fn, sample_inputs))

@@ -149,6 +149,14 @@ def conv2d_vmem_bytes(H: int, W: int, C: int, N: int, *, kh: int, kw: int,
     return 2 * blocks + values
 
 
+def conv2d_kernel_name(H: int, W: int, C: int, N: int, *, kh: int, kw: int,
+                       stride: int, padding: int) -> str:
+    """The kernel's name in the compiled program and the profiler's trace:
+    its geometry, so a trace ties the kernel's time to the layers of that
+    shape, and convolutions of one shape share one kernel."""
+    return f"conv{kh}x{kw}s{stride}p{padding}_{H}x{W}x{C}_{N}"
+
+
 @functools.partial(jax.jit, static_argnames=(
     "kh", "kw", "stride", "padding", "rows_t", "bn", "interpret"))
 def conv2d_int8_pallas(x: jax.Array, w: jax.Array,
@@ -211,5 +219,7 @@ def conv2d_int8_pallas(x: jax.Array, w: jax.Array,
             H, W, C, N, kh=kh, kw=kw, stride=stride, padding=padding,
             rows_t=rows_t, bn=bn, requant=fused)),
         interpret=interpret,
+        name=conv2d_kernel_name(H, W, C, N, kh=kh, kw=kw, stride=stride,
+                                padding=padding),
     )(*operands)
     return out[:oh, :, :N]

@@ -148,6 +148,12 @@ def gemm_vmem_bytes(M: int, K: int, N: int, *, bm: int, bn: int, bk: int,
     return 2 * blocks + 2 * vmem.tile_bytes((bm, bn), 4)
 
 
+def gemm_kernel_name(M: int, K: int, N: int) -> str:
+    """The kernel's name in the compiled program and the profiler's trace:
+    its shape, so GEMMs of one shape share one kernel and one name."""
+    return f"gemm_{M}x{K}x{N}"
+
+
 @functools.partial(jax.jit, static_argnames=("bm", "bn", "bk", "interpret"))
 def gemm_int8_pallas(x: jax.Array, w: jax.Array,
                      requant_mult: jax.Array | None = None,
@@ -186,7 +192,7 @@ def gemm_int8_pallas(x: jax.Array, w: jax.Array,
             out_shape=jax.ShapeDtypeStruct((Mp, Np), jnp.int32),
             scratch_shapes=[pltpu.VMEM((bm_, bn_), jnp.int32)],
             compiler_params=params,
-            interpret=interpret,
+            interpret=interpret, name=gemm_kernel_name(M, K, N),
         )(xp, wp)
     else:
         mp = jnp.pad(requant_mult.astype(jnp.float32), (0, Np - N))
@@ -200,6 +206,6 @@ def gemm_int8_pallas(x: jax.Array, w: jax.Array,
             out_shape=jax.ShapeDtypeStruct((Mp, Np), jnp.int8),
             scratch_shapes=[pltpu.VMEM((bm_, bn_), jnp.int32)],
             compiler_params=params,
-            interpret=interpret,
+            interpret=interpret, name=gemm_kernel_name(M, K, N),
         )(xp, wp, mp.reshape(1, Np))
     return out[:M, :N]

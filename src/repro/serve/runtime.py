@@ -77,6 +77,7 @@ from typing import Callable
 
 import numpy as np
 
+from .. import tracing
 from ..core.graph import Graph
 from ..core.lmgraph import lm_decode_graph
 from ..core.taskset import Job, NetworkSpec
@@ -147,6 +148,8 @@ class Ticket:
     status: str = "queued"
     error: str | None = None
     _result: TicketResult | None = dataclasses.field(default=None, repr=False)
+    # submit time (perf_counter_ns), stamped only while tracing is on
+    _submit_ns: int | None = dataclasses.field(default=None, repr=False)
 
     @property
     def done(self) -> bool:
@@ -359,7 +362,9 @@ class Server:
                                        slack_factor=slack_factor)
         self.metrics = {"jobs": 0, "idle_jobs": 0, "tickets": 0,
                         "dropped": 0, "degraded": 0, "retries": 0,
-                        "sheds": 0, "restores": 0, "mode_switches": 0}
+                        "sheds": 0, "restores": 0, "mode_switches": 0,
+                        "runner_calls": 0, "slots_filled": 0,
+                        "slots_padded": 0}
         self._nets: dict[str, _Network] = {}
         self.report: TasksetReport | None = None
         self.compiled = None                 # CompiledTaskset after analyze()
@@ -642,13 +647,16 @@ class Server:
                 f"Server.register, pass step_fn=, or call attach()")
         t = Ticket(tid=next(self._tids), network=name, payload=payload,
                    deadline_s=deadline_s)
-        if st.shed or (st.breaker is not None
-                       and st.breaker.state == "open"):
-            self._resolve_terminal(t, "degraded")
-            return t
-        evicted = st.queue.push(t)
-        if evicted is not None:
-            self._resolve_terminal(evicted, "dropped")
+        with tracing.span("repro.server.submit", t.tid) as sp:
+            if sp is not None:
+                t._submit_ns = sp.start
+            if st.shed or (st.breaker is not None
+                           and st.breaker.state == "open"):
+                self._resolve_terminal(t, "degraded")
+                return t
+            evicted = st.queue.push(t)
+            if evicted is not None:
+                self._resolve_terminal(evicted, "dropped")
         return t
 
     def queue_depths(self) -> dict[str, int]:
@@ -697,15 +705,16 @@ class Server:
         housekeeping runs: a staged mode switch applies and the overload
         control loop sheds/restores — both are forbidden mid-hyperperiod
         because they change the schedule the in-flight bounds assume."""
-        if self.report is None:
-            self.analyze()
-        if self._cursor == 0:
-            self._boundary()
-        jobs = self.compiled.jobs
-        job = jobs[self._cursor]
-        release_abs = (self.clock_base_s + self.hyperperiods_completed
-                       * self.compiled.hyperperiod_s + job.release)
-        self._execute_job(job, release_abs)
+        with tracing.span("repro.server.step", self.metrics["jobs"]):
+            if self.report is None:
+                self.analyze()
+            if self._cursor == 0:
+                self._boundary()
+            jobs = self.compiled.jobs
+            job = jobs[self._cursor]
+            release_abs = (self.clock_base_s + self.hyperperiods_completed
+                           * self.compiled.hyperperiod_s + job.release)
+            self._execute_job(job, release_abs)
         self._cursor += 1
         if self._cursor >= len(jobs):
             self._cursor = 0
@@ -732,6 +741,7 @@ class Server:
     def _execute_job(self, job: Job, release_abs: float) -> None:
         st = self._nets[job.network]
         bound = self.report.bound(job.network)
+        seq = self.metrics["jobs"]           # the job's sequence number
         self.metrics["jobs"] += 1
         if st.breaker is not None and not st.autorun:
             action = st.breaker.on_release()
@@ -749,32 +759,42 @@ class Server:
         if st.autorun and st.step_fn is not None:
             # MultiModelEngine mode: every job free-runs its no-arg fn
             # (autorun networks never hold tickets — submit refuses them)
-            out, dt = self._serve_call(st, [], st.step_fn)
+            out, dt = self._serve_call(st, [], st.step_fn, seq)
             if out is _GIVE_UP:
                 return
             self.monitor.check(job.network, dt, bound)
         elif st.runner is not None and len(st.queue) > 0:
-            tickets = st.queue.pop_upto(st.slots)
-            with self._failing(tickets):
-                # malformed payloads are caller errors, not executor
-                # faults: they fail the tickets and raise without
-                # consuming the retry budget
-                batch = self._stack(st, [t.payload for t in tickets])
+            with tracing.span("repro.server.batch", seq) as sp:
+                tickets = st.queue.pop_upto(st.slots)
+                with self._failing(tickets):
+                    # malformed payloads are caller errors, not executor
+                    # faults: they fail the tickets and raise without
+                    # consuming the retry budget
+                    batch = self._stack(st, [t.payload for t in tickets])
+            if sp is not None:       # each ticket's wait, submit to batching
+                for t in tickets:
+                    if t._submit_ns is not None:
+                        tracing.record("repro.server.queue", t._submit_ns,
+                                       sp.start, t.tid)
+            self.metrics["runner_calls"] += 1
+            self.metrics["slots_filled"] += len(tickets)
+            self.metrics["slots_padded"] += st.slots - len(tickets)
             out, dt = self._serve_call(st, tickets,
-                                       lambda: st.runner(batch))
+                                       lambda: st.runner(batch), seq)
             if out is _GIVE_UP:
                 return
-            self.monitor.check(job.network, dt, bound)
-            for i, t in enumerate(tickets):
-                self._finish(t, {k: v[i] for k, v in out.items()},
-                             dt, bound, release_abs)
+            with tracing.span("repro.server.account", seq):
+                self.monitor.check(job.network, dt, bound)
+                for i, t in enumerate(tickets):
+                    self._finish(t, {k: v[i] for k, v in out.items()},
+                                 dt, bound, release_abs)
         elif st.cengine is not None:
-            self._step_continuous(st, job, release_abs, bound)
+            self._step_continuous(st, job, release_abs, bound, seq)
         elif st.step_fn is not None and len(st.queue) > 0:
             tickets = st.queue.pop_upto(1)
             (t,) = tickets
             out, dt = self._serve_call(st, tickets,
-                                       lambda: st.step_fn(t.payload))
+                                       lambda: st.step_fn(t.payload), seq)
             if out is _GIVE_UP:
                 return
             self.monitor.check(job.network, dt, bound)
@@ -783,7 +803,7 @@ class Server:
             self.metrics["idle_jobs"] += 1
 
     def _step_continuous(self, st: _Network, job: Job, release_abs: float,
-                         bound: float) -> None:
+                         bound: float, seq: int) -> None:
         """One hyperperiod job of a continuous decode network: admit up to
         the engine's per-step prefill budget from the ticket queue, run one
         slot-batched decode step (the engine checks it against the WCET
@@ -807,7 +827,7 @@ class Server:
         # a failed decode step keeps its in-flight tickets queued in the
         # engine for the NEXT job (the stream is resumable), so no tickets
         # degrade here — the breaker/retry accounting still applies
-        info, _ = self._serve_call(st, [], ce.step)
+        info, _ = self._serve_call(st, [], ce.step, seq)
         if info is _GIVE_UP:
             return
         for req in info.finished:
@@ -915,8 +935,9 @@ class Server:
                 st.watchdog = StragglerWatchdog(margin=res.watchdog_margin)
 
     def _serve_call(self, st: _Network, tickets: list[Ticket],
-                    thunk: Callable):
-        """One executor call for a job. Returns (output, dt_s).
+                    thunk: Callable, seq: int):
+        """One executor call for job `seq`, in a `repro.server.call` span.
+        Returns (output, dt_s).
 
         Without resilience this is the legacy contract: a raising
         executor marks the popped tickets "failed" and the exception
@@ -926,11 +947,13 @@ class Server:
         resolves its tickets degraded and returns `(_GIVE_UP, 0.0)`
         instead of raising — serving continues."""
         if self.resilience is None:
-            with self._failing(tickets):
+            with self._failing(tickets), tracing.span("repro.server.call",
+                                                      seq):
                 t0 = time.perf_counter()
                 out = thunk()
                 return out, time.perf_counter() - t0
-        out, dt, error = self._call_resilient(st, thunk)
+        with tracing.span("repro.server.call", seq):
+            out, dt, error = self._call_resilient(st, thunk)
         if error is None:
             return out, dt
         for t in tickets:
@@ -1246,6 +1269,10 @@ class Server:
                      f"queued={self.queue_depths()}, "
                      f"hyperperiods={self.hyperperiods_completed}")
         m = self.metrics
+        if m["runner_calls"]:
+            lines.append(f"  runner_calls={m['runner_calls']} "
+                         f"(slots filled {m['slots_filled']}, "
+                         f"padded {m['slots_padded']})")
         if any(m[k] for k in ("dropped", "degraded", "retries", "sheds",
                               "restores", "mode_switches")) or self.mode_name:
             lines.append(

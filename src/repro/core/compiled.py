@@ -28,7 +28,8 @@ Backends over the lowered program:
     integer-exact round-half-even division (`kernels.ref.round_half_even_div`)
     so no x64 is needed.
   * ``run_pallas``  — gemm/conv tile batches lowered onto the package's
-    Pallas kernels (`kernels.gemm_int8`, `kernels.conv2d_im2col`), with a
+    Pallas kernels (`kernels.gemm_int8`, `kernels.conv2d_im2col`; a
+    pointwise conv is a GEMM and takes the GEMM kernel), with a
     gemm/conv -> requant chain fused into the kernel epilogue whenever the
     int32 accumulator has no other consumer. BlockSpec tiling is derived
     from the program's hardware model scratchpad capacity
@@ -46,7 +47,8 @@ from __future__ import annotations
 
 import dataclasses
 import hashlib
-from collections import OrderedDict
+from collections import Counter, OrderedDict
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -535,12 +537,23 @@ def resolve_interpret(interpret: bool | None = None) -> bool:
     return bool(interpret)
 
 
+class GemmShape(NamedTuple):
+    """The contraction a "gemm" plan step runs: x (M, K) @ w (K, N). A
+    pointwise conv's (H, W, K) input is subsampled by `stride` first."""
+
+    M: int
+    K: int
+    N: int
+    stride: int = 1
+
+
 @dataclasses.dataclass(frozen=True)
 class _PallasStep:
     """One op of the pallas-backend program plan.
 
     mode: "gemm" / "conv2d" (Pallas kernel), "jax" (fallback), or "skip"
-    (a requant batch fused into the preceding kernel's epilogue).
+    (a requant batch fused into the preceding kernel's epilogue). A "gemm"
+    step's batch is a gemm or a pointwise conv; `gemm` gives its shape.
     """
 
     mode: str
@@ -548,6 +561,7 @@ class _PallasStep:
     out_idx: int                 # where the result lands (fused: requant out)
     mult: np.ndarray | None      # fused requant multiplier, else None
     blocks: tuple                # (bm, bn, bk) gemm | (rows_t, bn) conv
+    gemm: GemmShape | None = None  # the contraction of a "gemm" step
 
 
 def _fusable_requant(prog: CompiledProgram, b: OpBatch) -> OpBatch | None:
@@ -572,10 +586,31 @@ def _fusable_requant(prog: CompiledProgram, b: OpBatch) -> OpBatch | None:
     return None
 
 
+def _gemm_shape(b: OpBatch) -> GemmShape | None:
+    """The GEMM a gemm batch, or a pointwise (1×1, unpadded) conv batch,
+    computes; None for every other batch."""
+    a = b.attrs
+    if b.kind == "gemm":
+        return GemmShape(a["M"], a["K"], a["N"])
+    if b.kind == "conv2d" and a["kh"] == a["kw"] == 1 and a["padding"] == 0:
+        oh, ow = conv_out_hw(a)
+        return GemmShape(oh * ow, a["C_in"], a["C_out"], a["stride"])
+    return None
+
+
 def _pallas_plan(prog: CompiledProgram) -> list[_PallasStep]:
     """Decide, once per program, how each fused tile batch lowers onto the
     Pallas kernels: kernel vs fallback, epilogue fusion, and SPM-derived
-    block shapes."""
+    block shapes.
+
+    A pointwise conv (1×1 kernel, no padding) is a GEMM: its output pixel
+    (i, j) reads input pixel (i·s, j·s) alone, so the input subsampled by
+    the stride and flattened to (oh·ow, C_in) contracts against the
+    (C_in, C_out) weight. It is planned as a "gemm" step and runs on the
+    GEMM kernel, which feeds int8 blocks straight to the MXU, instead of
+    the windowed conv kernel's halo-less pad, band copies, int32 widening
+    and tap loads. Same exact int32 contraction, same requant epilogue.
+    Every other conv keeps the windowed kernel."""
     plan: list[_PallasStep] = []
     skipped: set[int] = set()
     for b in prog.batches:
@@ -589,18 +624,55 @@ def _pallas_plan(prog: CompiledProgram) -> list[_PallasStep]:
         out_idx = rq.out_idx if rq is not None else b.out_idx
         mult = rq.mult if rq is not None else None
         out_bytes = 1 if rq is not None else 4
-        a = b.attrs
-        if b.kind == "gemm":
-            blocks = (derive_gemm_blocks(prog.hw, a["M"], a["K"], a["N"],
+        gemm = _gemm_shape(b)
+        if gemm is not None:
+            blocks = (derive_gemm_blocks(prog.hw, gemm.M, gemm.K, gemm.N,
                                          out_bytes)
                       if prog.hw is not None else (128, 128, 128))
         else:
-            blocks = (derive_conv_blocks(prog.hw, a, out_bytes)
+            blocks = (derive_conv_blocks(prog.hw, b.attrs, out_bytes)
                       if prog.hw is not None else (8, 128))
         if rq is not None:
             skipped.add(rq.op_idx)
-        plan.append(_PallasStep(b.kind, b, out_idx, mult, blocks))
+        plan.append(_PallasStep("gemm" if gemm is not None else "conv2d",
+                                b, out_idx, mult, blocks, gemm))
     return plan
+
+
+def plan_counts(prog: CompiledProgram) -> dict[str, int]:
+    """Steps of the pallas plan by mode, plus `pointwise_gemm`: the convs
+    planned as GEMMs."""
+    plan = _pallas_plan(prog)
+    counts = dict(Counter(s.mode for s in plan))
+    counts["pointwise_gemm"] = sum(
+        s.mode == "gemm" and s.batch.kind == "conv2d" for s in plan)
+    return counts
+
+
+def run_kernel_step(prog: CompiledProgram, step: _PallasStep, vals: list,
+                    weights: dict, interpret: bool) -> None:
+    """Run one "gemm" or "conv2d" plan step on its tiled Pallas kernel
+    (grid-streamed, double-buffered) and store its result in `vals`."""
+    b = step.batch
+    mult = None if step.mult is None else jnp.asarray(step.mult)
+    x = vals[b.in_idx[0]]
+    if step.mode == "gemm":
+        M, K, _, s = step.gemm
+        bm, bn, bk = step.blocks
+        if s > 1:
+            x = x[::s, ::s, :]
+        out = gemm_int8_pallas(x.reshape(M, K), weights[b.w_idx], mult,
+                               bm=bm, bn=bn, bk=bk, interpret=interpret)
+        if mult is None:
+            out = out.astype(_JNP_DT[prog.buffers[step.out_idx][2]])
+        vals[step.out_idx] = out.reshape(prog.buffers[step.out_idx][1])
+    else:
+        a = b.attrs
+        rows_t, bn = step.blocks
+        vals[step.out_idx] = conv2d_int8_pallas(
+            x, weights[b.w_idx], mult, kh=a["kh"], kw=a["kw"],
+            stride=a["stride"], padding=a["padding"], rows_t=rows_t, bn=bn,
+            interpret=interpret)
 
 
 def pallas_single(prog: CompiledProgram, interpret: bool = False):
@@ -617,32 +689,13 @@ def pallas_single(prog: CompiledProgram, interpret: bool = False):
             for name, i in prog.input_idx.items():
                 vals[i] = inputs[name]
             for step in plan:
-                b = step.batch
                 if step.mode == "skip":
                     continue                 # fused into the previous kernel
-                if step.mode == "gemm":
-                    a = b.attrs
-                    bm, bn, bk = step.blocks
-                    x = vals[b.in_idx[0]].reshape(a["M"], a["K"])
-                    out = gemm_int8_pallas(
-                        x, weights[b.w_idx],
-                        None if step.mult is None else jnp.asarray(step.mult),
-                        bm=bm, bn=bn, bk=bk, interpret=interpret)
-                    if step.mult is None:
-                        out = out.astype(
-                            _JNP_DT[prog.buffers[step.out_idx][2]])
-                    vals[step.out_idx] = out
-                elif step.mode == "conv2d":
-                    a = b.attrs
-                    rows_t, bn = step.blocks
-                    vals[step.out_idx] = conv2d_int8_pallas(
-                        vals[b.in_idx[0]], weights[b.w_idx],
-                        None if step.mult is None else jnp.asarray(step.mult),
-                        kh=a["kh"], kw=a["kw"], stride=a["stride"],
-                        padding=a["padding"], rows_t=rows_t, bn=bn,
-                        interpret=interpret)
+                if step.mode == "jax":
+                    vals[step.out_idx] = _jax_op(step.batch, vals, prog,
+                                                 weights)
                 else:
-                    vals[b.out_idx] = _jax_op(b, vals, prog, weights)
+                    run_kernel_step(prog, step, vals, weights, interpret)
             return {name: vals[i] for name, i in prog.output_idx.items()}
 
         prog._pallas_cache[key] = single

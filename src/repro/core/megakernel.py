@@ -31,9 +31,15 @@ structure on the compiled program:
      multipliers enter as operands. A single gemm/conv whose working set
      alone exceeds the scratchpad runs on the *tiled* kernels
      (`gemm_int8_pallas` / `conv2d_int8_pallas`), whose grid streaming is
-     Pallas-double-buffered — still one `pallas_call`. Fallback-only steps
-     that fit in no segment run at the XLA level between kernels (zero
-     extra launches, same as the per-op backend).
+     Pallas-double-buffered — still one `pallas_call`. A pointwise (1×1,
+     unpadded) conv is a GEMM, so the plan makes it a "gemm" step
+     (`compiled._pallas_plan`) and a tiled one runs on the GEMM kernel:
+     its input, subsampled by the stride and flattened to (oh·ow, C_in),
+     reaches the MXU in int8 blocks, without the windowed kernel's
+     padding, band copies, int32 widening and tap loads. (A fused body
+     still windows it.) Fallback-only steps that fit in no segment run at
+     the XLA level between kernels (zero extra launches, same as the
+     per-op backend).
   3. **Kernel-count target**: the paper's shape is one program per core, so
      the planner aims at `num_cores` kernels (`max_kernels` override in
      `BackendOptions`): when a pack at a reduced budget emits more, the
@@ -64,11 +70,11 @@ from jax.experimental import pallas as pl
 
 from . import compiled as C
 from ..kernels import vmem
-from ..kernels.conv2d_im2col import (conv2d_int8_pallas, conv2d_vmem_bytes,
-                                     conv_accumulate, fill_window,
-                                     tap_windows, window_scratch)
-from ..kernels.gemm_int8 import (dot_i32_exact, gemm_int8_pallas,
-                                 gemm_vmem_bytes, requant_epilogue)
+from ..kernels.conv2d_im2col import (conv2d_vmem_bytes, conv_accumulate,
+                                     fill_window, tap_windows,
+                                     window_scratch)
+from ..kernels.gemm_int8 import (dot_i32_exact, gemm_vmem_bytes,
+                                 requant_epilogue)
 from ..kernels.ref import _as_channel_mult, round_half_even_div
 
 _ITEM_BYTES = {"int8": 1, "uint8": 1, "int16": 2, "int32": 4,
@@ -400,8 +406,8 @@ def segment_vmem_bytes(prog: C.CompiledProgram, seg: Segment) -> int:
     requant = step.mult is not None
     if step.mode == "gemm":
         bm, bn, bk = step.blocks
-        return gemm_vmem_bytes(a["M"], a["K"], a["N"], bm=bm, bn=bn, bk=bk,
-                               requant=requant)
+        M, K, N, _ = step.gemm
+        return gemm_vmem_bytes(M, K, N, bm=bm, bn=bn, bk=bk, requant=requant)
     rows_t, bn = step.blocks
     return conv2d_vmem_bytes(a["H"], a["W"], a["C_in"], a["C_out"],
                              kh=a["kh"], kw=a["kw"], stride=a["stride"],
@@ -463,30 +469,6 @@ def _run_fused(prog: C.CompiledProgram, seg: Segment, vals: list,
         vals[i] = r
 
 
-def _run_tiled(prog: C.CompiledProgram, step, vals: list, weights: dict,
-               interpret: bool) -> None:
-    """One oversized step on the grid-scheduled tiled kernel (double-
-    buffered streaming; same emission as the per-op backend)."""
-    b = step.batch
-    a = b.attrs
-    mult = None if step.mult is None else jnp.asarray(step.mult)
-    if step.mode == "gemm":
-        bm, bn, bk = step.blocks
-        x = vals[b.in_idx[0]].reshape(a["M"], a["K"])
-        out = gemm_int8_pallas(x, weights[b.w_idx], mult,
-                               bm=bm, bn=bn, bk=bk, interpret=interpret)
-        if step.mult is None:
-            out = out.astype(C._JNP_DT[prog.buffers[step.out_idx][2]])
-        vals[step.out_idx] = out
-    else:
-        rows_t, bn = step.blocks
-        vals[step.out_idx] = conv2d_int8_pallas(
-            vals[b.in_idx[0]], weights[b.w_idx], mult,
-            kh=a["kh"], kw=a["kw"], stride=a["stride"],
-            padding=a["padding"], rows_t=rows_t, bn=bn,
-            interpret=interpret)
-
-
 def megakernel_fn(prog: C.CompiledProgram, *, interpret: bool = False,
                   budget: int | None = None,
                   max_kernels: int | None = None):
@@ -510,8 +492,8 @@ def megakernel_fn(prog: C.CompiledProgram, *, interpret: bool = False,
                     if seg.kind == "fused":
                         _run_fused(prog, seg, vals, weights, interpret, name)
                     elif seg.kind == "tiled":
-                        _run_tiled(prog, seg.steps[0], vals, weights,
-                                   interpret)
+                        C.run_kernel_step(prog, seg.steps[0], vals,
+                                          weights, interpret)
                     else:            # "outside": XLA-level fallback op
                         b = seg.steps[0].batch
                         vals[b.out_idx] = C._jax_op(b, vals, prog, weights)

@@ -81,7 +81,7 @@ def test_resnet50_megakernel_program_compiles_for_v5e(resnet50_prog,
                for i, w in prog.weights.items()}
     x = {"input": _spec((4, 224, 224, 3), jnp.int8, one_chip)}
     compiled = jax.jit(fn).lower(weights, x).compile()
-    assert _n_kernels(compiled) == sum(s.emits_call for s in segments)
+    assert _n_kernels(compiled) == sum(s.emits_call for s in segments) == 54
     assert compiled.memory_analysis() is not None
 
 
@@ -110,6 +110,38 @@ def test_resnet50_conv_kernel_compiles_for_v5e(name, one_chip):
         _spec((k * k * C, N), jnp.int8, one_chip),
         _spec((N,), jnp.float32, one_chip)).compile()
     assert _n_kernels(compiled) == 1
+
+
+@pytest.mark.parametrize("name", ["s0_c3_1x1", "s1_ds_1x1_s2"])
+def test_resnet50_pointwise_conv_gemm_route_compiles_for_v5e(name, one_chip):
+    """A pointwise conv as the served program runs it: planned as a GEMM
+    on the 16-core paper machine, a tiled segment subsampling by the
+    stride, flattening to (oh·ow, C_in) (M = 3025 or 784, neither a
+    multiple of the block), the GEMM kernel with its fused requant,
+    batched over 4 frames."""
+    from repro.core.graph import Graph, conv2d, requant
+    from repro.kernels.gemm_int8 import gemm_kernel_name
+    shape, stride, c_out = {"s0_c3_1x1": ((55, 55, 64), 1, 256),
+                            "s1_ds_1x1_s2": ((55, 55, 256), 2, 512)}[name]
+    g = Graph(name)
+    g.add_tensor("input", shape, "int8", is_input=True)
+    g.mark_output(requant(g, "pw.rq", conv2d(g, "pw", "input", c_out, 1,
+                                             stride=stride)))
+    g.validate()
+    rep, sched, subtasks, mapping = analyze(g, HW, num_cores=16)
+    prog = lower_program(g, init_params(g, seed=0), subtasks, mapping,
+                         sched, hw=HW)
+    (seg,) = MK.plan_segments(prog)
+    assert seg.kind == "tiled" and seg.steps[0].mode == "gemm"
+    M = ((shape[0] - 1) // stride + 1) ** 2
+    assert seg.steps[0].gemm == (M, shape[2], c_out, stride)
+    fn = jax.vmap(MK.megakernel_fn(prog, interpret=False), in_axes=(None, 0))
+    weights = {i: _spec(w.shape, w.dtype, one_chip)
+               for i, w in prog.weights.items()}
+    x = {"input": _spec((4,) + shape, jnp.int8, one_chip)}
+    compiled = jax.jit(fn).lower(weights, x).compile()
+    assert _n_kernels(compiled) == 1
+    assert gemm_kernel_name(M, shape[2], c_out) in compiled.as_text()
 
 
 def test_resnet50_fc_gemm_fused_requant_compiles_for_v5e(one_chip):

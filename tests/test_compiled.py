@@ -241,6 +241,54 @@ def test_pallas_plan_fuses_requant_chains():
             assert len(s.blocks) == 2
         if s.mode == "gemm":
             assert len(s.blocks) == 3
+    # a pointwise conv is planned as a gemm step carrying the conv's batch,
+    # its requant fused the same way; every other conv stays windowed
+    _, _, _, prog, _ = _compiled("resnet50")
+    plan = C._pallas_plan(prog)
+    modes = {s.batch.name: s.mode for s in plan}
+    assert modes["s0.b0.c1"] == "gemm" and modes["s0.b0.c1.rq"] == "skip"
+    assert modes["s1.b0.ds"] == "gemm" and modes["s1.b0.ds.rq"] == "skip"
+    assert modes["s0.b0.c2"] == "conv2d" and modes["stem"] == "conv2d"
+    for s in plan:
+        a = s.batch.attrs
+        if s.batch.kind == "conv2d":
+            pointwise = a["kh"] == a["kw"] == 1 and a["padding"] == 0
+            assert s.mode == ("gemm" if pointwise else "conv2d")
+            assert s.mult is not None
+        if s.mode == "gemm" and s.batch.kind == "conv2d":
+            oh, ow, n = prog.buffers[s.batch.out_idx][1]
+            assert s.gemm == (oh * ow, a["C_in"], a["C_out"], a["stride"])
+            assert n == a["C_out"] and len(s.blocks) == 3
+        elif s.mode == "gemm":
+            assert s.gemm == (a["M"], a["K"], a["N"], 1)
+        else:
+            assert s.gemm is None
+
+
+def test_plan_counts_full_width_resnet50():
+    """Full-width ResNet-50 (224², the benchmark's graph) on the 16-core
+    paper machine: its 36 pointwise convs (c1 and c3 of 16 bottlenecks,
+    4 projections) are planned as GEMMs, the 17 3×3 and 7×7 convs stay
+    windowed, and the segmentation is untouched: 54 tiled (53 convs and
+    the fc), 67 at the XLA level."""
+    from repro.core import megakernel as MK
+    g = cnn.resnet50()
+    hw = scaled_paper_machine(16)
+    rep, sched, subtasks, mapping = analyze(g, hw, num_cores=16)
+    prog = lower_program(g, init_params(g, seed=0), subtasks, mapping,
+                         sched, hw=hw)
+    counts = C.plan_counts(prog)
+    assert counts["pointwise_gemm"] == 36
+    assert counts["gemm"] == 37 and counts["conv2d"] == 17
+    windowed = [s.batch.attrs for s in C._pallas_plan(prog)
+                if s.mode == "conv2d"]
+    assert sorted({a["kh"] for a in windowed}) == [3, 7]
+    segments = MK.plan_segments(prog)
+    kinds = [s.kind for s in segments]
+    assert kinds.count("tiled") == 54 and kinds.count("outside") == 67
+    assert kinds.count("fused") == 0
+    assert sum(s.steps[0].mode == "gemm" for s in segments
+               if s.kind == "tiled") == 37
 
 
 def test_pallas_no_fusion_when_acc_is_graph_output():

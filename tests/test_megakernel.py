@@ -168,6 +168,58 @@ def test_small_scratchpad_emits_more_kernels_never_larger_segments():
         assert np.array_equal(ref[t], out[t])
 
 
+def _pointwise_program(hw_size, stride, c_in, c_out, requant):
+    """One 1×1 conv over an odd-sized (hw_size²) input, its accumulator
+    requantized (fused into the kernel) or itself the graph output."""
+    from repro.core.graph import Graph, conv2d, requant as rq
+    g = Graph("pointwise")
+    g.add_tensor("input", (hw_size, hw_size, c_in), "int8", is_input=True)
+    y = conv2d(g, "pw", "input", c_out, 1, stride=stride)
+    g.mark_output(rq(g, "pw.rq", y) if requant else y)
+    g.validate()
+    hw = scaled_paper_machine(2)
+    rep, sched, subtasks, mapping = analyze(g, hw, num_cores=2)
+    params = init_params(g, seed=11)
+    return g, lower_program(g, params, subtasks, mapping, sched, hw=hw)
+
+
+@pytest.mark.parametrize("megakernel", [False, True])
+@pytest.mark.parametrize("requant", [True, False])
+@pytest.mark.parametrize("hw_size,stride,c_in,c_out", [
+    (55, 1, 64, 256), (55, 2, 64, 64), (7, 1, 256, 64), (7, 2, 128, 256)])
+def test_pointwise_conv_runs_on_gemm_kernel_bit_exact(
+        hw_size, stride, c_in, c_out, requant, megakernel):
+    """A 1×1 conv runs on the GEMM kernel, per-op and as the megakernel's
+    tiled segment (a budget below its working set), bit for bit equal to
+    `run_numpy`: exact int32 contraction, same requant epilogue, stride by
+    subsampling."""
+    import jax.numpy as jnp
+    from repro.core import compiled as C
+    from repro.kernels.gemm_int8 import gemm_kernel_name
+    g, prog = _pointwise_program(hw_size, stride, c_in, c_out, requant)
+    (step,) = [s for s in C._pallas_plan(prog) if s.mode != "skip"]
+    oh = (hw_size - 1) // stride + 1
+    assert step.mode == "gemm"
+    assert step.gemm == (oh * oh, c_in, c_out, stride)
+    assert (step.mult is not None) == requant
+    x = np.random.default_rng(hw_size + stride).integers(
+        -128, 128, size=(hw_size, hw_size, c_in)).astype(np.int8)
+    if megakernel:
+        (seg,) = MK.plan_segments(prog, budget=1024)
+        assert seg.kind == "tiled"
+        fn = MK.megakernel_single(prog, interpret=True, budget=1024)
+    else:
+        fn = C.pallas_single(prog, interpret=True)
+    assert MK.pallas_call_names(fn, {"input": jnp.asarray(x)}) == [
+        gemm_kernel_name(oh * oh, c_in, c_out)]
+    ref = C.run_numpy(prog, {"input": x})
+    out = fn({"input": jnp.asarray(x)})
+    (t,) = g.outputs
+    assert out[t].shape == ref[t].shape == (oh, oh, c_out)
+    assert out[t].dtype == ref[t].dtype
+    assert np.array_equal(ref[t], np.asarray(out[t]))
+
+
 def test_segment_cores_round_robin():
     segments = [s for s in MK.plan_segments(_compiled("resnet50")[3])
                 if s.emits_call]

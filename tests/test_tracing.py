@@ -209,8 +209,9 @@ def test_a_collection_inside_a_step_is_a_child_of_the_step(rec):
 
 def test_every_megakernel_pallas_call_has_a_stable_name():
     """A fused kernel carries its segment's name (unique in the program); a
-    tiled kernel its shape, shared by the segments of one shape. A 64 KiB
-    scratchpad splits the reduced ResNet-50 into fused, tiled and
+    tiled kernel its shape, shared by the segments of one shape: a GEMM's
+    (M, K, N), pointwise convs included, or a windowed conv's geometry. A
+    64 KiB scratchpad splits the reduced ResNet-50 into fused, tiled and
     XLA-level segments."""
     from repro.core import analyze, init_params, lower_program
     from repro.kernels.conv2d_im2col import conv2d_kernel_name
@@ -219,10 +220,11 @@ def test_every_megakernel_pallas_call_has_a_stable_name():
     def expected(prog, index, seg):
         if seg.kind == "fused":
             return MK.segment_name(index, seg)
-        b = seg.steps[0].batch
+        step = seg.steps[0]
+        b = step.batch
         a = b.attrs
-        if b.kind == "gemm":
-            return gemm_kernel_name(a["M"], a["K"], a["N"])
+        if step.mode == "gemm":
+            return gemm_kernel_name(step.gemm.M, step.gemm.K, step.gemm.N)
         H, W, C = prog.buffers[b.in_idx[0]][1]
         return conv2d_kernel_name(H, W, C, prog.buffers[b.out_idx][1][-1],
                                   kh=a["kh"], kw=a["kw"],
@@ -247,4 +249,6 @@ def test_every_megakernel_pallas_call_has_a_stable_name():
     assert {s.kind for s in segments} == {"fused", "tiled", "outside"}
     assert names[0] == "seg000_stem"
     assert "conv3x3s2p1_4x4x64_64" in names
+    assert "gemm_4x128x256" in names           # the stride-2 projection
+    assert not [n for n in names if n.startswith("conv1x1")]
     assert all(n.replace("_", "").isalnum() for n in names)

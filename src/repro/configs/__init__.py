@@ -41,7 +41,7 @@ def get_cnn_graph(name: str, **kw):
     if name == "resnet50":
         return cnn.resnet50(**kw)
     if name == "yolov5s":
-        return cnn.yolov5s_backbone(**kw)
+        return cnn.yolov5s(**kw)
     if name == "small_cnn":
         return cnn.small_cnn(**kw)
     raise KeyError(name)

@@ -89,30 +89,29 @@ def resnet50(h: int = 224, w: int = 224, num_classes: int = 1000,
     return g
 
 
-def _c3(g: Graph, name: str, x: str, c_out: int, n: int) -> str:
-    """YOLOv5 C3 module (CSP bottleneck with 3 convs)."""
+def _c3(g: Graph, name: str, x: str, c_out: int, n: int,
+        relu_after_add: bool = False) -> str:
+    """YOLOv5 C3 module (CSP bottleneck with 3 convs). Its bottlenecks are
+    the published ones (activation on the 3x3 conv, nothing after the
+    shortcut add) unless `relu_after_add`, which moves the activation
+    after the add."""
     c_h = max(8, c_out // 2)
     y1 = _conv_block(g, f"{name}.cv1", x, c_h, 1)
     for i in range(n):
         z = _conv_block(g, f"{name}.m{i}.cv1", y1, c_h, 1)
-        z = _conv_block(g, f"{name}.m{i}.cv2", z, c_h, 3, relu=False)
+        z = _conv_block(g, f"{name}.m{i}.cv2", z, c_h, 3,
+                        relu=not relu_after_add)
         y1 = eltwise(g, f"{name}.m{i}.add", "add", [z, y1])
-        y1 = eltwise(g, f"{name}.m{i}.relu", "relu", [y1])
+        if relu_after_add:
+            y1 = eltwise(g, f"{name}.m{i}.relu", "relu", [y1])
     y2 = _conv_block(g, f"{name}.cv2", x, c_h, 1)
     y = concat(g, f"{name}.cat", [y1, y2])
     return _conv_block(g, f"{name}.cv3", y, c_out, 1)
 
 
-def yolov5s_backbone(h: int = 640, w: int = 640,
-                     width: float = 1.0) -> Graph:
-    """YOLOv5-small backbone + SPPF (width 0.5, depth 0.33 of YOLOv5l).
-
-    The detection head's upsample/route layers are out of scope of the
-    paper's GEMM-centric deployment discussion; the backbone + SPPF carries
-    >85% of the network FLOPs and all layer types the compiler handles
-    (conv/bottleneck/CSP/pool/concat). Noted in DESIGN.md.
-    """
-    g = Graph(f"yolov5s_{h}x{w}")
+def _yolov5s(name: str, h: int, w: int, width: float,
+             published: bool) -> Graph:
+    g = Graph(name)
     x = "input"
     g.add_tensor(x, (h, w, 3), "int8", is_input=True)
 
@@ -120,20 +119,46 @@ def yolov5s_backbone(h: int = 640, w: int = 640,
         return max(8, int(c * width))
 
     y = _conv_block(g, "stem", x, ch(32), 6, stride=2, padding=2)
-    y = _conv_block(g, "d1", y, ch(64), 3, stride=2)
-    y = _c3(g, "c3_1", y, ch(64), 1)
-    y = _conv_block(g, "d2", y, ch(128), 3, stride=2)
-    y = _c3(g, "c3_2", y, ch(128), 2)
-    y = _conv_block(g, "d3", y, ch(256), 3, stride=2)
-    y = _c3(g, "c3_3", y, ch(256), 3)
-    y = _conv_block(g, "d4", y, ch(512), 3, stride=2)
-    y = _c3(g, "c3_4", y, ch(512), 1)
+    for i, (c, n) in enumerate(((64, 1), (128, 2), (256, 3), (512, 1))):
+        y = _conv_block(g, f"d{i + 1}", y, ch(c), 3, stride=2)
+        y = _c3(g, f"c3_{i + 1}", y, ch(c), n, relu_after_add=not published)
     # SPPF (padded stride-1 maxpools keep spatial dims)
+    if published:
+        y = _conv_block(g, "sppf.cv1", y, ch(512) // 2, 1)
     p1 = pool2d(g, "sppf.p1", "maxpool", y, 5, 1, padding=2)
     p2 = pool2d(g, "sppf.p2", "maxpool", p1, 5, 1, padding=2)
     p3 = pool2d(g, "sppf.p3", "maxpool", p2, 5, 1, padding=2)
     y = concat(g, "sppf.cat", [y, p1, p2, p3])
-    y = _conv_block(g, "sppf.cv", y, ch(512), 1)
+    y = _conv_block(g, "sppf.cv2" if published else "sppf.cv", y, ch(512), 1)
     g.mark_output(y)
     g.validate()
     return g
+
+
+def yolov5s(h: int = 640, w: int = 640, width: float = 1.0) -> Graph:
+    """YOLOv5-small backbone + SPPF as published (Ultralytics YOLOv5 v6+
+    `models/yolov5s.yaml`: width 0.5, depth 0.33 of YOLOv5l), int8.
+
+    At `width=1.0` the widths are the published ones (32, 64, 128, 256,
+    512), the C3 repeats (1, 2, 3, 1), the bottleneck activates its 3x3
+    conv and adds nothing after the shortcut add, and SPPF reduces 512 to
+    256 channels, pools three times 5x5 and fuses the 1024-channel concat
+    back to 512. The part kept is 10.35 GOP a frame at 640x640, about 63%
+    of the published 16.5 GFLOPs. Departures, each forced: ReLU in place
+    of SiLU (the graph IR has no SiLU); batch norm folded into the requant
+    multiplier (int8 inference); the PANet neck and the Detect head left
+    out (the graph IR has no upsample).
+    """
+    return _yolov5s(f"yolov5s_v6_{h}x{w}", h, w, width, published=True)
+
+
+def yolov5s_backbone(h: int = 640, w: int = 640,
+                     width: float = 1.0) -> Graph:
+    """The YOLOv5-small backbone + SPPF of `yolov5s` with two more
+    departures from the published network, which the first benchmark
+    configuration `chipbench/configs/yolov5s_640.json` and its reference
+    `chipbench/configs/yolov5.py` pin: in a bottleneck the 3x3 conv has no
+    activation and a ReLU follows the shortcut add; SPPF max-pools the
+    512-channel input itself and fuses a 2048-channel concat.
+    """
+    return _yolov5s(f"yolov5s_{h}x{w}", h, w, width, published=False)

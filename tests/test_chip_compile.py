@@ -1,5 +1,5 @@
 """Compiles of the main path's Pallas kernels for a TPU v5e, at ResNet-50
-224x224 widths, without a chip.
+224x224 and YOLOv5s 640x640 widths, without a chip.
 
 The TPU compiler is installed alongside JAX and compiles for a chip that is
 described (`topologies.get_topology_desc`) rather than attached, so Mosaic
@@ -54,6 +54,25 @@ def _n_kernels(compiled) -> int:
     return compiled.as_text().count("custom_call_target=\"tpu_custom_call\"")
 
 
+def _compile_served(prog, frame_shape, n_kernels, one_chip):
+    """The served program batched over 4 frames, as the Server runs it,
+    compiled for v5e; no fused segment holds more than the scratchpad,
+    and each kernel-emitting segment is one Mosaic kernel (`n_kernels`)."""
+    segments = MK.plan_segments(prog)
+    cap = prog.hw.scratchpad_bytes
+    assert all(MK.segment_footprint(prog, s) <= cap
+               for s in segments if s.kind == "fused")
+    fn = jax.vmap(MK.megakernel_fn(prog, interpret=False),
+                  in_axes=(None, 0))
+    weights = {i: _spec(w.shape, w.dtype, one_chip)
+               for i, w in prog.weights.items()}
+    x = {"input": _spec((4,) + frame_shape, jnp.int8, one_chip)}
+    compiled = jax.jit(fn).lower(weights, x).compile()
+    assert _n_kernels(compiled) == sum(s.emits_call for s in segments) \
+        == n_kernels
+    return compiled
+
+
 @pytest.fixture(scope="module")
 def resnet50_prog():
     """Full-width ResNet-50 (224x224, width 1.0, 1000 classes) compiled for
@@ -70,19 +89,25 @@ def test_resnet50_megakernel_program_compiles_for_v5e(resnet50_prog,
     steps between them, batched as the Server runs it — compiles for v5e,
     with one Mosaic kernel per kernel-emitting segment, none of which holds
     more than the scratchpad."""
-    prog = resnet50_prog
-    segments = MK.plan_segments(prog)
-    cap = prog.hw.scratchpad_bytes
-    assert all(MK.segment_footprint(prog, s) <= cap
-               for s in segments if s.kind == "fused")
-    fn = jax.vmap(MK.megakernel_fn(prog, interpret=False),
-                  in_axes=(None, 0))
-    weights = {i: _spec(w.shape, w.dtype, one_chip)
-               for i, w in prog.weights.items()}
-    x = {"input": _spec((4, 224, 224, 3), jnp.int8, one_chip)}
-    compiled = jax.jit(fn).lower(weights, x).compile()
-    assert _n_kernels(compiled) == sum(s.emits_call for s in segments) == 54
+    compiled = _compile_served(resnet50_prog, (224, 224, 3), 54, one_chip)
     assert compiled.memory_analysis() is not None
+
+
+def test_yolov5s_megakernel_program_compiles_for_v5e(one_chip):
+    """Full-width YOLOv5s backbone + SPPF as published (640x640, the
+    benchmark's graph) as the Server runs it, batched over 4 frames,
+    compiles for v5e: the 6x6/2 stem over 3 input channels, the 3x3/2
+    downsamples at 320² and 160², pointwise GEMMs of up to 25600 rows and
+    SPPF's K = 1024 fuse, one Mosaic kernel per kernel-emitting segment,
+    no fused segment above the scratchpad, and the concats and 5x5 pools
+    at the XLA level."""
+    g = cnn.yolov5s()
+    prog = repro.compile(g, HW, backend="pallas",
+                         params=init_params(g, seed=0), num_cores=16).program
+    compiled = _compile_served(prog, (640, 640, 3), 33, one_chip)
+    mem = compiled.memory_analysis()
+    assert mem.output_size_in_bytes == 4 * 20 * 20 * 512
+    assert mem.temp_size_in_bytes < 1 << 30
 
 
 def _conv_case(name):

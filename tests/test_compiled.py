@@ -291,6 +291,33 @@ def test_plan_counts_full_width_resnet50():
                if s.kind == "tiled") == 37
 
 
+def test_plan_counts_full_width_yolov5s():
+    """Full-width YOLOv5s backbone + SPPF as published (640², the
+    benchmark's graph) on the 16-core paper machine: the 21 pointwise
+    convs (C3 branches and fuses, SPPF's 512→256 and 1024→512) are planned
+    as GEMMs, the 6×6 stem and the 11 3×3 convs stay windowed; concats,
+    SPPF's pools, shortcut adds and ReLUs are 48 XLA-level steps; 33 tiled
+    segments, none fused."""
+    from repro.core import megakernel as MK
+    g = cnn.yolov5s()
+    hw = scaled_paper_machine(16)
+    rep, sched, subtasks, mapping = analyze(g, hw, num_cores=16)
+    prog = lower_program(g, init_params(g, seed=0), subtasks, mapping,
+                         sched, hw=hw)
+    counts = C.plan_counts(prog)
+    assert counts["conv2d"] == 12 and counts["pointwise_gemm"] == 21
+    assert counts["gemm"] == 21 and counts["jax"] == 48
+    windowed = [s.batch.attrs for s in C._pallas_plan(prog)
+                if s.mode == "conv2d"]
+    assert sorted({a["kh"] for a in windowed}) == [3, 6]
+    gemms = sorted({s.gemm for s in C._pallas_plan(prog) if s.mode == "gemm"})
+    assert {m for m, *_ in gemms} == {25600, 6400, 1600, 400}
+    assert max(k for _, k, *_ in gemms) == 1024
+    kinds = [s.kind for s in MK.plan_segments(prog)]
+    assert kinds.count("tiled") == 33 and kinds.count("outside") == 48
+    assert kinds.count("fused") == 0
+
+
 def test_pallas_no_fusion_when_acc_is_graph_output():
     """An int32 accumulator that is itself a graph output must NOT be
     requant-fused away — and the backend stays bit-exact."""

@@ -289,6 +289,40 @@ def test_step_serves_in_static_batch_slots():
         assert np.array_equal(a[k], b[k])
 
 
+def test_yolov5s_served_on_pallas_equals_the_oracle(monkeypatch):
+    """The published YOLOv5s backbone + SPPF (64x64, width 0.25) served as
+    the chip benchmark serves it, on the pallas megakernel (interpret mode
+    here) with 4 slots: 5 frames take two calls, the second with 3 padded
+    slots. Every output equals the numpy oracle bit for bit; with the
+    recorder off no span is built."""
+    from repro import tracing
+    from repro.core import init_params, reference_forward
+
+    def no_span(*a, **k):
+        raise AssertionError("a span was built with the recorder off")
+
+    tracing.disable()
+    monkeypatch.setattr(tracing, "_Span", no_span)
+    monkeypatch.setattr(tracing.Recorder, "record", no_span)
+    g = cnn.yolov5s(h=64, w=64, width=0.25)
+    params = init_params(g, seed=5)
+    srv = Server(scaled_paper_machine(16), backend="pallas", num_cores=16)
+    srv.register("yolo", g, period_s=0.2, params=params, slots=4)
+    frames = [_frame(k, 64, 64) for k in range(5)]
+    tickets = [srv.submit("yolo", f) for f in frames]
+    while not all(t.terminal for t in tickets):
+        srv.step()
+    (out,) = g.outputs
+    for f, t in zip(frames, tickets):
+        want = reference_forward(g, params, {"input": f})[out]
+        got = t.result().output[out]
+        assert got.dtype == want.dtype and np.array_equal(got, want)
+    m = srv.metrics
+    assert (m["runner_calls"], m["slots_filled"], m["slots_padded"]) \
+        == (2, 5, 3)
+    assert tracing.drain() == [] and tracing.dropped_spans() == 0
+
+
 # -- save / load ----------------------------------------------------------------
 
 @pytest.mark.parametrize("backend", ["numpy", "jax"])

@@ -307,16 +307,36 @@ def _numpy_io(fn) -> Runner:
     copy of the inputs to the device (`repro.runner.h2d`), the call, which
     returns once the program is enqueued (`repro.runner.launch`), and the
     copy of the outputs back, which waits for the device to finish
-    (`repro.runner.fetch`)."""
+    (`repro.runner.fetch`).
+
+    Two more entry points let a caller overlap the input copy of one call
+    with the program of another. `run.stage(inputs)` issues the copy alone
+    and returns the device arrays without waiting on them; `run` takes
+    them in place of numpy arrays and skips the copy. `run.launch(inputs)`
+    enqueues the program and returns the fetch, a no-argument callable,
+    so the caller can work between the two."""
+    import jax
     import jax.numpy as jnp
 
-    def run(inputs: dict) -> dict:
+    def stage(inputs: dict) -> dict:
         with tracing.span("repro.runner.h2d"):
-            args = {k: jnp.asarray(v) for k, v in inputs.items()}
+            return {k: jnp.asarray(v) for k, v in inputs.items()}
+
+    def launch(inputs: dict) -> Callable[[], dict]:
+        if not all(isinstance(v, jax.Array) for v in inputs.values()):
+            inputs = stage(inputs)
         with tracing.span("repro.runner.launch"):
-            out = fn(args)
-        with tracing.span("repro.runner.fetch"):
-            return {k: np.asarray(v) for k, v in out.items()}
+            out = fn(inputs)
+
+        def fetch() -> dict:
+            with tracing.span("repro.runner.fetch"):
+                return {k: np.asarray(v) for k, v in out.items()}
+        return fetch
+
+    def run(inputs: dict) -> dict:
+        return launch(inputs)()
+
+    run.stage, run.launch = stage, launch
     return run
 
 

@@ -217,6 +217,10 @@ class RequestQueue:
             out.append(self._q.popleft())
         return out
 
+    def head(self, k: int) -> list[Ticket]:
+        """The first k tickets (fewer if fewer are queued), left queued."""
+        return list(itertools.islice(self._q, k))
+
 
 # -- overload + resilience policies -------------------------------------------
 
@@ -299,6 +303,9 @@ class _Network:
     breaker: object = None               # faults.CircuitBreaker (resilience)
     watchdog: object = None              # StragglerWatchdog (resilience)
     jobs_done: int = 0                   # executed jobs (watchdog step index)
+    # (tickets, device inputs): the head of the queue, copied to the
+    # device during the previous job (`Server._stage_next`)
+    staged_batch: tuple | None = None
 
 
 def _as_graph(net, name: str, *, batch: int, cache_len: int,
@@ -363,8 +370,8 @@ class Server:
         self.metrics = {"jobs": 0, "idle_jobs": 0, "tickets": 0,
                         "dropped": 0, "degraded": 0, "retries": 0,
                         "sheds": 0, "restores": 0, "mode_switches": 0,
-                        "runner_calls": 0, "slots_filled": 0,
-                        "slots_padded": 0}
+                        "runner_calls": 0, "staged_calls": 0,
+                        "slots_filled": 0, "slots_padded": 0}
         self._nets: dict[str, _Network] = {}
         self.report: TasksetReport | None = None
         self.compiled = None                 # CompiledTaskset after analyze()
@@ -766,11 +773,13 @@ class Server:
         elif st.runner is not None and len(st.queue) > 0:
             with tracing.span("repro.server.batch", seq) as sp:
                 tickets = st.queue.pop_upto(st.slots)
-                with self._failing(tickets):
-                    # malformed payloads are caller errors, not executor
-                    # faults: they fail the tickets and raise without
-                    # consuming the retry budget
-                    batch = self._stack(st, [t.payload for t in tickets])
+                batch = self._take_staged(st, tickets)
+                if batch is None:
+                    with self._failing(tickets):
+                        # malformed payloads are caller errors, not
+                        # executor faults: they fail the tickets and raise
+                        # without consuming the retry budget
+                        batch = self._stack(st, [t.payload for t in tickets])
             if sp is not None:       # each ticket's wait, submit to batching
                 for t in tickets:
                     if t._submit_ns is not None:
@@ -780,7 +789,7 @@ class Server:
             self.metrics["slots_filled"] += len(tickets)
             self.metrics["slots_padded"] += st.slots - len(tickets)
             out, dt = self._serve_call(st, tickets,
-                                       lambda: st.runner(batch), seq)
+                                       self._runner_call(st, batch, seq), seq)
             if out is _GIVE_UP:
                 return
             with tracing.span("repro.server.account", seq):
@@ -842,6 +851,59 @@ class Server:
                 verdict=req.verdict, release_s=release_abs)
             t.status = "done"
             self.metrics["tickets"] += 1
+
+    def _take_staged(self, st: _Network, tickets: list[Ticket]):
+        """The device inputs staged for `tickets` during the previous job,
+        or None: nothing was staged, or the queue's head changed since (a
+        shed, a drop, a breaker's skip or a mode switch took the staged
+        tickets)."""
+        staged, st.staged_batch = st.staged_batch, None
+        if staged is None:
+            return None
+        staged_tickets, batch = staged
+        if [t.tid for t in staged_tickets] != [t.tid for t in tickets]:
+            return None
+        self.metrics["staged_calls"] += 1
+        return batch
+
+    def _runner_call(self, st: _Network, batch: dict, seq: int) -> Callable:
+        """The executor call of a runner job. A runner that can stage (the
+        pallas runner: `stage`, and `launch`, which returns the fetch)
+        launches the program, stages the next job's batch while it runs
+        (`_stage_next`), then fetches; any other runner is called whole."""
+        if getattr(st.runner, "stage", None) is None:
+            return lambda: st.runner(batch)
+
+        def call():
+            fetch = st.runner.launch(batch)
+            self._stage_next(st, seq)
+            return fetch()
+        return call
+
+    def _stage_next(self, st: _Network, seq: int) -> None:
+        """Copy the batch the network's next job will serve to the device
+        while this job's program runs, when the next job of the static
+        program is this network's and its queue holds a full batch: that
+        job pops exactly these tickets (FIFO), so no short batch is
+        committed early. The tickets stay queued, and so count in
+        `queue_depths()` and resolve as queued ones do, until that job
+        pops them (`_take_staged`). Its time counts in this job's
+        `latency_s`, the whole executor call."""
+        jobs = self.compiled.jobs
+        if st.staged_batch is not None or len(st.queue) < st.slots or \
+                jobs[(self._cursor + 1) % len(jobs)].network != st.spec.name:
+            return
+        with tracing.span("repro.server.stage", seq):
+            tickets = st.queue.head(st.slots)
+            try:
+                batch = st.runner.stage(
+                    self._stack(st, [t.payload for t in tickets]))
+            except Exception:
+                # nothing is staged: the next job batches these tickets
+                # itself and fails them there, as without staging
+                batch = None
+            if batch is not None:
+                st.staged_batch = (tickets, batch)
 
     @contextlib.contextmanager
     def _failing(self, tickets: list[Ticket]):
@@ -1048,6 +1110,7 @@ class Server:
         for t in list(st.inflight.values()):
             self._resolve_terminal(t, "degraded")
         st.inflight.clear()
+        st.staged_batch = None           # no job of a shed network frees it
         st.shed = True
         self._reanalyze_active()
 
@@ -1272,7 +1335,8 @@ class Server:
         if m["runner_calls"]:
             lines.append(f"  runner_calls={m['runner_calls']} "
                          f"(slots filled {m['slots_filled']}, "
-                         f"padded {m['slots_padded']})")
+                         f"padded {m['slots_padded']}), "
+                         f"staged_calls={m['staged_calls']}")
         if any(m[k] for k in ("dropped", "degraded", "retries", "sheds",
                               "restores", "mode_switches")) or self.mode_name:
             lines.append(

@@ -163,6 +163,8 @@ def test_server_and_runner_spans_nest_per_job(rec):
     runner = [spans[i] for i in _children(spans, call)]
     assert all(spans[call].start_ns <= s.start_ns <= s.end_ns
                <= spans[call].end_ns for s in runner)
+    assert [s.name for s in sorted(runner, key=lambda s: s.start_ns)] == [
+        "repro.runner.h2d", "repro.runner.launch", "repro.runner.fetch"]
     queue = [spans[i] for i in kids if spans[i].name == "repro.server.queue"]
     assert sorted(s.ref for s in queue) == sorted(t.tid for t in tickets)
     batch = next(spans[i] for i in kids
@@ -174,6 +176,43 @@ def test_server_and_runner_spans_nest_per_job(rec):
     # the call span holds the interval the ticket's latency_s measures
     call_s = (spans[call].end_ns - spans[call].start_ns) / 1e9
     assert 0 < tickets[0].result().latency_s <= call_s
+    assert rec.dropped_spans() == 0
+
+
+def test_a_staged_call_copies_its_input_in_the_step_before(rec):
+    srv = _cnn_server("pallas")
+    _serve_one_job(srv, [_frame()])          # compile outside the recording
+    rec.enable(capacity=256)
+    tickets = _serve_one_job(srv, [_frame(k) for k in range(4)])
+    spans = rec.drain()
+    assert srv.metrics["staged_calls"] == 1
+    first, second = [i for i, s in enumerate(spans)
+                     if s.name == "repro.server.step"]
+    calls = [next(i for i in _children(spans, step)
+                  if spans[i].name == "repro.server.call")
+             for step in (first, second)]
+    # the first call copies its own input, launches, copies the second
+    # call's input while its program runs, then fetches
+    kids = sorted(_children(spans, calls[0]), key=lambda i: spans[i].start_ns)
+    assert [spans[i].name for i in kids] == [
+        "repro.runner.h2d", "repro.runner.launch", "repro.server.stage",
+        "repro.runner.fetch"]
+    assert spans[kids[2]].ref == spans[first].ref
+    assert _names(spans, _children(spans, kids[2])) == ["repro.runner.h2d"]
+    # the staged call launches and fetches, and copies nothing
+    kids = sorted(_children(spans, calls[1]), key=lambda i: spans[i].start_ns)
+    assert [spans[i].name for i in kids] == [
+        "repro.runner.launch", "repro.runner.fetch"]
+    assert "repro.runner.h2d" not in {
+        s.name for s in spans if s.start_ns >= spans[second].start_ns}
+    # latency_s is the whole call, the copy staged inside it included
+    for call, t in zip(calls, (tickets[0], tickets[2])):
+        call_s = (spans[call].end_ns - spans[call].start_ns) / 1e9
+        assert 0 < t.result().latency_s <= call_s
+    stage_s = sum(spans[i].end_ns - spans[i].start_ns
+                  for i in _children(spans, calls[0])
+                  if spans[i].name == "repro.server.stage") / 1e9
+    assert 0 < stage_s < tickets[0].result().latency_s
     assert rec.dropped_spans() == 0
 
 

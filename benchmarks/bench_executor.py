@@ -14,10 +14,7 @@ Per CNN preset (smallest -> largest) this measures, on one machine model:
   * ``compiled_pallas`` — the registry's ``pallas`` backend: the fused
     per-core megakernel (`repro.core.megakernel`, scratchpad-sized
     segments, requant fused in epilogues). Real Mosaic
-    kernels on TPU, interpret mode on CPU CI;
-  * ``compiled_pallas_perop`` — the same backend with ``megakernel=False``
-    (one ``pallas_call`` per op) — the megakernel's fusion win is
-    ``compiled_pallas_perop / compiled_pallas``.
+    kernels on TPU, interpret mode on CPU CI.
 
 All compiled paths go through one `repro.compile` Deployment per preset
 and its backend-registry runners — the same artifact serving uses.
@@ -92,8 +89,6 @@ def _bench_preset(name: str, reps: int) -> dict:
     replayer = ScheduleReplayer(g, subtasks, mapping, sched)
     runners = {be: dep.runner(backend=be)
                for be in ("numpy", "jax", "pallas")}
-    runners["pallas_perop"] = dep.with_backend(
-        "pallas", options=repro.BackendOptions(megakernel=False)).runner()
     jfn_b = dep.runner(batched=True, backend="jax")
 
     # correctness first: every timed path is bit-exact vs the oracle
@@ -118,8 +113,6 @@ def _bench_preset(name: str, reps: int) -> dict:
         "compiled_jax_b1": _time(lambda: jfn_b({"input": x1}), reps),
         "compiled_pallas": _time(
             lambda: runners["pallas"]({"input": x}), reps),
-        "compiled_pallas_perop": _time(
-            lambda: runners["pallas_perop"]({"input": x}), reps),
     }
     times["compiled_jax_b8_per_sample"] = _time(
         lambda: jfn_b({"input": xbb}), reps) / BATCH
@@ -132,8 +125,6 @@ def _bench_preset(name: str, reps: int) -> dict:
                                    / times["compiled_jax_b8_per_sample"]),
         "speedup_pallas_vs_seed": (times["interp_seed"]
                                    / times["compiled_pallas"]),
-        "speedup_mega_vs_perop": (times["compiled_pallas_perop"]
-                                  / times["compiled_pallas"]),
     }
 
 

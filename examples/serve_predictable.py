@@ -1,8 +1,8 @@
-"""Predictable LM serving: batched prefill+decode with a WCET bound per
-decode step computed by the paper's compiler pipeline, the full WCET
-report for the production-scale config on the TPU-v5e machine model, and
-the continuous-batching decode loop (`Server.register_decode`) serving
-mixed-length traffic with per-request deadline verdicts.
+"""Predictable LM serving: per-token WCET bounds computed by the paper's
+compiler pipeline for production-scale configs on the TPU-v5e machine
+model, and the continuous-batching decode loop (`Server.register_decode`)
+serving mixed-length traffic, every decode step checked against its
+bound and every request given a deadline verdict.
 
     PYTHONPATH=src python examples/serve_predictable.py
 """
@@ -11,11 +11,10 @@ import jax
 import numpy as np
 
 from repro.configs import get_config
-from repro.hw import PAPER_RISCV, TPU_V5E, scaled_paper_machine
+from repro.hw import TPU_V5E, scaled_paper_machine
 from repro.models import init_params
 from repro.serve import Server
-from repro.serve.engine import Request
-from repro.serve.predictable import PredictableEngine, analyze_decode
+from repro.serve.predictable import analyze_decode
 
 
 def main():
@@ -31,32 +30,11 @@ def main():
 
     print()
     print("=" * 72)
-    print("Live serving with deadline enforcement (reduced config, CPU)")
+    print("Continuous batching: requests enter/leave the batch mid-decode")
     print("=" * 72)
     cfg = get_config("smollm-135m", reduced=True)
     params = init_params(cfg, jax.random.PRNGKey(0))
-    eng = PredictableEngine(cfg, params, batch_size=4, max_len=96,
-                            hw=PAPER_RISCV)
-    print(eng.report.summary())
     rng = np.random.default_rng(0)
-    reqs = [Request(rid=i,
-                    prompt=list(rng.integers(1, cfg.vocab_size, 8)),
-                    max_new_tokens=12) for i in range(8)]
-    done = []
-    for i in range(0, len(reqs), 4):
-        done += eng.generate(reqs[i:i + 4])
-    for r in done[:3]:
-        print(f"  req {r.rid}: -> {r.out}")
-    print(f"engine metrics: {eng.metrics}")
-    # every decode step is individually timed and checked by the shared
-    # DeadlineMonitor (checks AND misses count per step)
-    print(f"deadline misses: {eng.deadline_misses}/{eng.deadline_checks}")
-    print(eng.monitor.summary())
-
-    print()
-    print("=" * 72)
-    print("Continuous batching: requests enter/leave the batch mid-decode")
-    print("=" * 72)
     srv = Server(scaled_paper_machine(4), speed_ratio=1e6)
     verdict = srv.register_decode(
         "lm", cfg, period_s=1 / 50, params=params, slots=4, prompt_len=8,

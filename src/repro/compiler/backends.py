@@ -9,8 +9,8 @@ and return a runner with the uniform serving contract:
     runner({input_name: np.ndarray, ...}) -> {output_name: np.ndarray, ...}
 
 numpy in, numpy out, graph outputs only, blocking until the result is
-ready. `Deployment.run` / `BatchedInferenceEngine` / the executor benchmark
-all go through this table, so a third-party backend (a new kernel library,
+ready. `Deployment.run` / `Server` / the executor benchmark all go
+through this table, so a third-party backend (a new kernel library,
 a remote accelerator client) plugs in with one `register_backend` call and
 is immediately selectable as `repro.compile(..., backend="mine")`.
 
@@ -31,21 +31,13 @@ Built-in backends (see repro/core/compiled.py for their numerics):
   * ``pallas`` — the fused per-core megakernel over the Pallas kernels
     (`repro.core.megakernel`): scratchpad-sized segments, one
     `pallas_call` each and `num_cores` of them when the scratchpad allows,
-    requant fused in epilogues. Real Mosaic
-    lowering on TPU, interpret mode elsewhere. ``megakernel=False`` in the
-    options falls back to the per-op kernel path.
-
-Deprecation: `register_backend` factories used to take just the program
-(``factory(prog)``). Those still work — they are wrapped with a shim that
-drops the options argument and emits a `DeprecationWarning` at
-registration — but new backends should accept ``(prog, options)``.
+    requant fused in epilogues. Real Mosaic lowering on TPU, interpret
+    mode elsewhere.
 """
 
 from __future__ import annotations
 
 import dataclasses
-import inspect
-import warnings
 from typing import Callable
 
 import numpy as np
@@ -73,8 +65,6 @@ class BackendOptions:
       interpret          — Pallas interpret mode. None: auto (real Mosaic
                            lowering on TPU, interpret elsewhere); False
                            requires the backend's `requires_device`.
-      megakernel         — fused per-core megakernel on/off (None: on for
-                           the pallas backend).
       scratchpad_budget  — bytes; packs the megakernel segments against
                            less than the machine's scratchpad capacity
                            (a larger value is clamped to the capacity).
@@ -84,7 +74,6 @@ class BackendOptions:
     """
 
     interpret: bool | None = None
-    megakernel: bool | None = None
     scratchpad_budget: int | None = None
     max_kernels: int | None = None
 
@@ -187,33 +176,6 @@ class Backend:
 _REGISTRY: dict[str, Backend] = {}
 
 
-def _adapt_factory(factory, name: str, which: str):
-    """Accept both factory signatures: (prog, options) and legacy (prog).
-
-    Legacy single-argument factories are wrapped to drop the options and
-    warned about once, at registration."""
-    try:
-        sig = inspect.signature(factory)
-    except (TypeError, ValueError):
-        return factory                       # builtins etc.: assume new
-    params = list(sig.parameters.values())
-    if any(p.kind == p.VAR_POSITIONAL for p in params):
-        return factory
-    positional = [p for p in params
-                  if p.kind in (p.POSITIONAL_ONLY, p.POSITIONAL_OR_KEYWORD)]
-    if len(positional) >= 2:
-        return factory
-    warnings.warn(
-        f"backend {name!r} {which} factory takes only (prog); factories "
-        "should accept (prog, options: BackendOptions). The legacy "
-        "signature is wrapped for now and will stop working in a future "
-        "release.", DeprecationWarning, stacklevel=3)
-
-    def adapted(prog, options):
-        return factory(prog)
-    return adapted
-
-
 def register_backend(name: str, *,
                      single: Callable,
                      batched: Callable | None = None,
@@ -223,17 +185,13 @@ def register_backend(name: str, *,
 
     `batched` defaults to a per-sample loop over `single` — correct for any
     backend, so plugins only need the single-sample runner. Factories take
-    ``(prog, options)``; the legacy ``(prog)`` signature still works via a
-    deprecation shim."""
+    ``(prog, options)``."""
     if name in _REGISTRY and not overwrite:
         raise BackendError(
             f"backend {name!r} already registered; pass overwrite=True")
-    single = _adapt_factory(single, name, "single")
     has_native_batched = batched is not None
     if batched is None:
         batched = _loop_batched(single)
-    else:
-        batched = _adapt_factory(batched, name, "batched")
     caps = capabilities or BackendCapabilities(
         supports_batched_native=has_native_batched)
     be = Backend(name=name, single=single, batched=batched,
@@ -261,9 +219,8 @@ def list_backends() -> list[str]:
 
 def _loop_batched(single_factory):
     """Default batched factory: run `single` per sample and stack."""
-    def factory(prog: _C.CompiledProgram,
-                options: BackendOptions | None = None) -> Runner:
-        single = single_factory(prog, options or BackendOptions())
+    def factory(prog: _C.CompiledProgram, options: BackendOptions) -> Runner:
+        single = single_factory(prog, options)
 
         def run(batch: dict) -> dict:
             B = next(iter(batch.values())).shape[0]
@@ -276,12 +233,9 @@ def _loop_batched(single_factory):
 
 
 # -- built-in backends --------------------------------------------------------
-# Builtin factories default `options` so the legacy direct-invocation form
-# (`get_backend("numpy").single(prog)`, used by wrapping third-party
-# backends) keeps working alongside the registry's (prog, options) calls.
 
 def _numpy_single(prog: _C.CompiledProgram,
-                  options: BackendOptions | None = None) -> Runner:
+                  options: BackendOptions) -> Runner:
     def run(inputs: dict) -> dict:
         vals = _C.run_numpy(prog, inputs)      # exposes every buffer
         return {t: vals[t] for t in prog.graph.outputs}
@@ -289,14 +243,14 @@ def _numpy_single(prog: _C.CompiledProgram,
 
 
 def _jax_single(prog: _C.CompiledProgram,
-                options: BackendOptions | None = None) -> Runner:
+                options: BackendOptions) -> Runner:
     import functools
     _C.jit_single(prog)                        # trace once at build time
     return functools.partial(_C.run_jax, prog, batched=False)
 
 
 def _jax_batched(prog: _C.CompiledProgram,
-                 options: BackendOptions | None = None) -> Runner:
+                 options: BackendOptions) -> Runner:
     import functools
     _C.jit_batched(prog)
     return functools.partial(_C.run_jax, prog, batched=True)
@@ -342,13 +296,8 @@ def _numpy_io(fn) -> Runner:
 
 def _pallas_fn(prog: _C.CompiledProgram, options: BackendOptions,
                batched: bool):
-    """The traced pallas program for (options, batched): megakernel by
-    default, per-op kernels when megakernel=False."""
+    """The traced megakernel program for (options, batched)."""
     interpret = _C.resolve_interpret(options.interpret)
-    if options.megakernel is False:
-        if batched:
-            return _C.pallas_batched(prog, interpret)
-        return _C.jit_pallas_single(prog, interpret)
     make = _MK.megakernel_batched if batched else _MK.jit_megakernel_single
     return make(prog, interpret=interpret,
                 budget=options.scratchpad_budget,
@@ -356,25 +305,23 @@ def _pallas_fn(prog: _C.CompiledProgram, options: BackendOptions,
 
 
 def _pallas_single(prog: _C.CompiledProgram,
-                   options: BackendOptions | None = None) -> Runner:
-    return _numpy_io(_pallas_fn(prog, options or BackendOptions(),
-                                batched=False))
+                   options: BackendOptions) -> Runner:
+    return _numpy_io(_pallas_fn(prog, options, batched=False))
 
 
 def _pallas_batched(prog: _C.CompiledProgram,
-                    options: BackendOptions | None = None) -> Runner:
-    return _numpy_io(_pallas_fn(prog, options or BackendOptions(),
-                                batched=True))
+                    options: BackendOptions) -> Runner:
+    return _numpy_io(_pallas_fn(prog, options, batched=True))
 
 
 def _mesh_single(prog: _C.CompiledProgram,
-                 options: BackendOptions | None = None) -> Runner:
+                 options: BackendOptions) -> Runner:
     from ..cluster.mesh import mesh_single_runner
     return mesh_single_runner(prog)
 
 
 def _mesh_batched(prog: _C.CompiledProgram,
-                  options: BackendOptions | None = None) -> Runner:
+                  options: BackendOptions) -> Runner:
     from ..cluster.mesh import mesh_batched_runner
     return mesh_batched_runner(prog)
 
@@ -389,7 +336,7 @@ register_backend("pallas", single=_pallas_single, batched=_pallas_batched,
                      supports_batched_native=True,
                      requires_device="tpu",
                      supported_options=frozenset(
-                         {"interpret", "megakernel", "scratchpad_budget",
+                         {"interpret", "scratchpad_budget",
                           "max_kernels"})))
 register_backend("mesh", single=_mesh_single, batched=_mesh_batched,
                  capabilities=BackendCapabilities(

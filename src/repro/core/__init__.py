@@ -12,7 +12,7 @@ The preferred front door for the whole pipeline is ``repro.compile()``
 returns a serializable `Deployment` with backend-registry execution.
 The loose entry points below remain supported as the building blocks the
 pipeline itself is made of — but new code should not re-chain
-``analyze -> compile_graph -> run_numpy/run_jax/run_pallas`` by hand;
+``analyze -> compile_graph -> run_numpy/run_jax/run_megakernel`` by hand;
 the per-backend ``run_*`` helpers in particular are retained as thin
 compatibility shims over the backend registry's runners.
 """
@@ -31,8 +31,7 @@ from .executor import (reference_forward, execute_schedule, init_params,
                        ScheduleReplayer, im2col, im2col_reference)
 from .compiled import (CompiledProgram, CompileError, clear_program_cache,
                        compile_graph, graph_signature, jit_batched,
-                       lower_program, pallas_batched, run_numpy, run_jax,
-                       run_pallas, supports_graph)
+                       lower_program, run_numpy, run_jax, supports_graph)
 from .megakernel import (count_pallas_calls, plan_segments, run_megakernel)
 from . import cnn, quantize
 
@@ -48,8 +47,7 @@ __all__ = [
     "ScheduleReplayer", "im2col", "im2col_reference",
     "CompiledProgram", "CompileError", "clear_program_cache",
     "compile_graph", "graph_signature", "jit_batched", "lower_program",
-    "pallas_batched", "run_numpy", "run_jax", "run_pallas",
-    "supports_graph",
+    "run_numpy", "run_jax", "supports_graph",
     "count_pallas_calls", "plan_segments", "run_megakernel",
     "cnn", "quantize",
 ]

@@ -27,17 +27,20 @@ Backends over the lowered program:
     float32 round-half-even as `quantize.requantize`, and avgpool/gap use
     integer-exact round-half-even division (`kernels.ref.round_half_even_div`)
     so no x64 is needed.
-  * ``run_pallas``  — gemm/conv tile batches lowered onto the package's
-    Pallas kernels (`kernels.gemm_int8`, `kernels.conv2d_im2col`; a
-    pointwise conv is a GEMM and takes the GEMM kernel), with a
-    gemm/conv -> requant chain fused into the kernel epilogue whenever the
-    int32 accumulator has no other consumer. BlockSpec tiling is derived
-    from the program's hardware model scratchpad capacity
-    (`hw.derive_gemm_blocks` / `hw.derive_conv_blocks`) so the kernel grid
-    mirrors the SPM streaming the schedule models. Op kinds the kernels
-    don't cover fall back per-op to the JAX backend's lowering. On
-    non-TPU backends the kernels run in Pallas interpret mode
-    (bit-exact, CPU CI); on TPU they are the real Mosaic lowering.
+  * the Pallas plan (``_pallas_plan``) — how each gemm/conv tile batch
+    lowers onto the package's Pallas kernels (`kernels.gemm_int8`,
+    `kernels.conv2d_im2col`; a pointwise conv is a GEMM and takes the GEMM
+    kernel), with a gemm/conv -> requant chain fused into the kernel
+    epilogue whenever the int32 accumulator has no other consumer.
+    BlockSpec tiling is derived from the program's hardware model
+    scratchpad capacity (`hw.derive_gemm_blocks` /
+    `hw.derive_conv_blocks`) so the kernel grid mirrors the SPM streaming
+    the schedule models. Op kinds the kernels don't cover fall back to
+    the JAX backend's lowering (`_jax_op`). `repro.core.megakernel` packs
+    the plan into scratchpad-sized segments and emits it; tiled steps run
+    through ``run_kernel_step``. On non-TPU backends the kernels run in
+    Pallas interpret mode (bit-exact, CPU CI); on TPU they are the real
+    Mosaic lowering.
 
 Programs are cached per graph *signature* (structural hash) so serving
 engines compile each distinct network once per process.
@@ -530,7 +533,7 @@ def resolve_interpret(interpret: bool | None = None) -> bool:
     ``None`` means auto: real Mosaic lowering on TPU, Pallas interpret mode
     everywhere else (Pallas cannot lower to the CPU XLA backend). The one
     place this decision is made — the backend registry's `BackendOptions`
-    and every pallas entry point below route through it.
+    and every megakernel entry point route through it.
     """
     if interpret is None:
         return jax.default_backend() != "tpu"
@@ -673,59 +676,3 @@ def run_kernel_step(prog: CompiledProgram, step: _PallasStep, vals: list,
             x, weights[b.w_idx], mult, kh=a["kh"], kw=a["kw"],
             stride=a["stride"], padding=a["padding"], rows_t=rows_t, bn=bn,
             interpret=interpret)
-
-
-def pallas_single(prog: CompiledProgram, interpret: bool = False):
-    """Single-sample traced function over the Pallas kernels (cached per
-    interpret flag). Same calling convention as `jax_single`; bit-exact
-    against it (and therefore against the interpreter oracle)."""
-    key = ("single", bool(interpret))
-    if key not in prog._pallas_cache:
-        plan = _pallas_plan(prog)
-        weights = {i: jnp.asarray(w) for i, w in prog.weights.items()}
-
-        def single(inputs: dict):
-            vals: list = [None] * len(prog.buffers)
-            for name, i in prog.input_idx.items():
-                vals[i] = inputs[name]
-            for step in plan:
-                if step.mode == "skip":
-                    continue                 # fused into the previous kernel
-                if step.mode == "jax":
-                    vals[step.out_idx] = _jax_op(step.batch, vals, prog,
-                                                 weights)
-                else:
-                    run_kernel_step(prog, step, vals, weights, interpret)
-            return {name: vals[i] for name, i in prog.output_idx.items()}
-
-        prog._pallas_cache[key] = single
-    return prog._pallas_cache[key]
-
-
-def jit_pallas_single(prog: CompiledProgram, interpret: bool = False):
-    key = ("jit_single", bool(interpret))
-    if key not in prog._pallas_cache:
-        prog._pallas_cache[key] = jax.jit(pallas_single(prog, interpret))
-    return prog._pallas_cache[key]
-
-
-def pallas_batched(prog: CompiledProgram, interpret: bool | None = None):
-    """The whole pallas-backend program jitted and vmapped over a leading
-    batch axis — the serving step of `BatchedInferenceEngine(backend=
-    "pallas")`. `interpret=None` auto-selects via `resolve_interpret`."""
-    interpret = resolve_interpret(interpret)
-    key = ("batched", bool(interpret))
-    if key not in prog._pallas_cache:
-        prog._pallas_cache[key] = jax.jit(
-            jax.vmap(pallas_single(prog, interpret)))
-    return prog._pallas_cache[key]
-
-
-def run_pallas(prog: CompiledProgram, inputs: dict[str, np.ndarray],
-               interpret: bool | None = None) -> dict[str, np.ndarray]:
-    """Convenience wrapper: one unbatched sample through the jitted pallas
-    program; numpy in, numpy out. Returns the graph outputs (like
-    `run_jax`, unlike `run_numpy` which exposes every buffer)."""
-    fn = jit_pallas_single(prog, resolve_interpret(interpret))
-    out = fn({k: jnp.asarray(v) for k, v in inputs.items()})
-    return {k: np.asarray(v) for k, v in out.items()}

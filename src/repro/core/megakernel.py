@@ -1,12 +1,12 @@
-"""Fused per-core megakernel pass over the Pallas program plan.
+"""Fused per-core megakernel: the `pallas` backend's program.
 
-The per-op pallas backend (`compiled.pallas_single`) issues one
-`pallas_call` per gemm/conv batch — dozens of kernel launches per
-inference, each re-streaming its operands. The paper's machine does the
-opposite: every core executes its whole statically scheduled instruction
-stream out of local scratchpad, with the DMA engine prefetching the next
-tile while the core computes the current one. This pass mirrors that
-structure on the compiled program:
+The paper's machine runs every core's whole statically scheduled
+instruction stream out of local scratchpad, with the DMA engine
+prefetching the next tile while the core computes the current one. This
+module emits the compiled program's Pallas plan (`compiled._pallas_plan`:
+kernel vs fallback per op, fused requant epilogues, SPM-derived blocks)
+in that structure, so a program runs as a few kernels rather than one
+per op:
 
   1. **Segmentation** (`plan_segments`): walk `_pallas_plan`'s steps in
      program order and greedily pack them into contiguous *segments* whose
@@ -24,7 +24,7 @@ structure on the compiled program:
      TPU, exactness-preserving chunked-f32 dots under interpret mode),
      convs and pools as strided tap windows over a padded int32 VMEM copy
      (`kernels.conv2d_im2col.conv_accumulate`), requantization fused into
-     the epilogues exactly as the per-op plan decided (`_PallasStep.mult`).
+     the epilogues exactly as the plan decided (`_PallasStep.mult`).
      In-kernel values stay int32 (Mosaic lays out and windows 32-bit
      values; int8 ones it refuses to reshape or compare) and narrow to int8
      only for the MXU and at the output store. Weights and requant
@@ -38,8 +38,7 @@ structure on the compiled program:
      reaches the MXU in int8 blocks, without the windowed kernel's
      padding, band copies, int32 widening and tap loads. (A fused body
      still windows it.) Fallback-only steps that fit in no segment run at
-     the XLA level between kernels (zero extra launches, same as the
-     per-op backend).
+     the XLA level between kernels (zero extra launches).
   3. **Kernel-count target**: the paper's shape is one program per core, so
      the planner aims at `num_cores` kernels (`max_kernels` override in
      `BackendOptions`): when a pack at a reduced budget emits more, the
@@ -55,7 +54,7 @@ chip's tiled layout (`kernels.vmem`).
 Bit-exactness: every emission path reuses the repo's single requant
 definition (`requant_epilogue`) and exact int8 contractions, so the
 megakernel is bit-identical to `run_numpy` / `reference_forward` on every
-supported graph — the same acceptance bar as the per-op backend.
+supported graph.
 """
 
 from __future__ import annotations
@@ -516,7 +515,7 @@ def megakernel_single(prog: C.CompiledProgram, *, interpret: bool = False,
                       max_kernels: int | None = None):
     """Single-sample traced function ``single(inputs)`` over the segment
     plan, weights bound. Same calling convention as
-    `compiled.pallas_single`; bit-exact against it."""
+    `compiled.jax_single`; bit-exact against `compiled.run_numpy`."""
     fn = megakernel_fn(prog, interpret=interpret, budget=budget,
                        max_kernels=max_kernels)
     return functools.partial(fn, device_weights(prog))

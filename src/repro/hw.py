@@ -146,7 +146,7 @@ PAPER_RISCV = HardwareModel(
 #
 # The paper's partitioner sizes GEMM tiles so that x-tile + w-tile + int32
 # accumulator fit in one core's scratchpad; the Pallas backend of the
-# compiled executor (repro.core.compiled.run_pallas) derives its BlockSpec
+# compiled executor (repro.core.compiled._pallas_plan) derives its BlockSpec
 # shapes from the same constraint so the kernel grid mirrors the SPM
 # streaming the schedule models. Streamed tiles (activations + weights)
 # are double-buffered on a dual-ported scratchpad — they count twice —

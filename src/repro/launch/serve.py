@@ -1,10 +1,12 @@
-"""Serving launcher (predictable mode by default).
+"""Serving launcher: LM decode through `Server`, with its WCET report.
 
     PYTHONPATH=src python -m repro.launch.serve --arch smollm-135m \
         --reduced --requests 8 --max-new 16
 
-Builds the model, runs batched prefill+decode over synthetic prompts, and
-prints the paper-pipeline WCET report for the decode step.
+Builds the model, prints the paper-pipeline WCET report for the decode
+step, serves synthetic prompts through `Server.register_decode` (every
+decode step checked against its bound), and prints the deadline checks
+and misses. `--analyze-only` prints the report without running.
 """
 
 from __future__ import annotations
@@ -16,8 +18,8 @@ import numpy as np
 
 from ..configs import ARCH_IDS, get_config
 from ..models import init_params
-from ..serve.engine import Request
-from ..serve.predictable import PredictableEngine, analyze_decode
+from ..serve import Server
+from ..serve.predictable import analyze_decode
 from ..hw import TPU_V5E, PAPER_RISCV
 
 
@@ -37,28 +39,34 @@ def main(argv=None):
     cfg = get_config(args.arch, reduced=args.reduced)
     hw = TPU_V5E if args.hw == "tpu" else PAPER_RISCV
 
+    rep = analyze_decode(cfg, args.batch, args.max_len, hw)
+    print(rep.summary())
     if args.analyze_only:
-        rep = analyze_decode(cfg, args.batch, args.max_len, hw)
-        print(rep.summary())
         return
 
     params = init_params(cfg, jax.random.PRNGKey(0))
-    eng = PredictableEngine(cfg, params, batch_size=args.batch,
-                            max_len=args.max_len, hw=hw)
-    print(eng.report.summary())
     rng = np.random.default_rng(0)
-    reqs = [Request(rid=i,
-                    prompt=list(rng.integers(1, cfg.vocab_size,
-                                             rng.integers(4, 12))),
-                    max_new_tokens=args.max_new)
-            for i in range(args.requests)]
-    done = []
-    for i in range(0, len(reqs), args.batch):
-        done += eng.generate(reqs[i:i + args.batch])
-    for r in done[:4]:
-        print(f"req {r.rid}: {len(r.out)} tokens -> {r.out[:8]}...")
-    print(f"metrics: {eng.metrics}; deadline misses "
-          f"{eng.deadline_misses}/{eng.deadline_checks}")
+    prompts = [list(rng.integers(1, cfg.vocab_size, rng.integers(4, 12)))
+               for _ in range(args.requests)]
+    srv = Server(hw, queue_capacity=max(1, args.requests))
+    # one decode step per period, with the per-token bound's headroom
+    srv.register_decode("lm", cfg, period_s=2 * rep.per_token_wcet_s,
+                        params=params, slots=args.batch,
+                        prompt_len=max(map(len, prompts), default=1),
+                        max_new_tokens=args.max_new, max_len=args.max_len)
+    tickets = [srv.submit("lm", {"prompt": p,
+                                 "max_new_tokens": args.max_new})
+               for p in prompts]
+    while not all(t.terminal for t in tickets):
+        srv.step()
+    for t in tickets[:4]:
+        out = t.result().output
+        print(f"req {t.tid}: {len(out)} tokens -> {out[:8]}...")
+    cont = srv.telemetry()["continuous"]["lm"]
+    mon = srv.monitor
+    print(f"metrics: {cont['decode_steps']} decode steps, "
+          f"{cont['tokens']} tokens; deadline misses "
+          f"{mon.misses.get('lm', 0)}/{mon.checks.get('lm', 0)}")
 
 
 if __name__ == "__main__":

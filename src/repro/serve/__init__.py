@@ -6,14 +6,13 @@
     srv.run(hyperperiods=3)
     ticket.result()        # output + latency + WCET bound + deadline verdict
 
-`BatchedInferenceEngine` / `ServeEngine` / `PredictableEngine` /
-`MultiModelEngine` remain as thin wrappers (batched CNN inference, LM
-prefill/decode, per-step WCET enforcement, the historical taskset
-adapter) — all deadline accounting lives in `DeadlineMonitor`, all
-multi-network execution in `Server`. LM decode traffic is served
-*continuously* (`repro.serve.continuous`): `Server.register_decode`
-installs a slot-indexed `ContinuousEngine` where requests enter and
-leave the batch mid-stream. See docs/serving.md.
+All deadline accounting lives in `DeadlineMonitor`, all multi-network
+execution in `Server`. LM decode traffic is served *continuously*
+(`repro.serve.continuous`): `Server.register_decode` installs a
+slot-indexed `ContinuousEngine` where requests enter and leave the batch
+mid-stream, each decode step checked against its WCET bound
+(`analyze_decode`). `ServeEngine` runs LM batches to completion; it is
+the oracle the continuous loop is tested against. See docs/serving.md.
 
 Degraded operation is first-class (docs/serving.md, "Failure modes &
 degraded operation"): mixed-criticality overload shedding
@@ -26,17 +25,16 @@ injection with bounded retries and per-network circuit breaking
 from .continuous import (ContinuousEngine, ContinuousRequest, DecodeState,
                          LMBackend, ResultTokens, SlotError, StepInfo,
                          ToyBackend)
-from .engine import BatchedInferenceEngine, Request, ServeEngine
+from .engine import Request, ServeEngine
 from .faults import (BreakerPolicy, CircuitBreaker, FaultInjector,
                      FaultPlan, InjectedFailure, InjectedTimeout,
                      RetryPolicy, StragglerWatchdog)
 from .modes import Mode, ModeChangeError, ModeNetwork
 from .monitor import DeadlineMonitor, DeadlineVerdict
-from .predictable import (AdmissionError, MultiModelEngine,
-                          PredictableEngine, PredictableServeReport,
-                          analyze_decode)
-from .runtime import (BackpressureError, OverloadPolicy, RequestQueue,
-                      ServeError, Server, Ticket, TicketResult)
+from .predictable import PredictableServeReport, analyze_decode
+from .runtime import (AdmissionError, BackpressureError, OverloadPolicy,
+                      RequestQueue, ServeError, Server, Ticket,
+                      TicketResult)
 
 __all__ = ["Server", "Ticket", "TicketResult", "RequestQueue",
            "ServeError", "AdmissionError", "BackpressureError",
@@ -45,9 +43,8 @@ __all__ = ["Server", "Ticket", "TicketResult", "RequestQueue",
            "FaultPlan", "FaultInjector", "InjectedFailure",
            "InjectedTimeout", "RetryPolicy", "BreakerPolicy",
            "CircuitBreaker", "StragglerWatchdog",
-           "BatchedInferenceEngine", "Request", "ServeEngine",
-           "PredictableEngine", "PredictableServeReport", "analyze_decode",
-           "MultiModelEngine",
+           "Request", "ServeEngine", "PredictableServeReport",
+           "analyze_decode",
            "ContinuousEngine", "ContinuousRequest", "DecodeState",
            "LMBackend", "ResultTokens", "SlotError", "StepInfo",
            "ToyBackend"]

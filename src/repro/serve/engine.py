@@ -1,28 +1,25 @@
-"""Batched serving engine: continuous prefill/decode over a request queue.
+"""Batch-to-completion LM serving: the oracle for continuous decode.
 
-The engine runs two compiled programs (the same ones the dry-run lowers):
+`ServeEngine` runs two compiled programs (the same ones the dry-run
+lowers):
   prefill_step — fills the KV/state cache for a batch of prompts;
   decode_step  — one token for the whole batch per call.
 
-Batching model: static batch slots (fixed shapes -> fixed dataflow -> the
-paper's WCET machinery applies per step; `repro.serve.predictable` wraps
-this engine with the static DMA schedule + WCET bound per decode step).
-Requests shorter than the batch are padded; finished rows are masked and
-refilled on the next prefill flush (simple continuous batching).
+Each batch of requests runs to completion in static batch slots; short
+batches are padded with dummy rows. `ServeEngine.serve` is the
+arrival-order-independent ground truth that the continuous-batching loop
+(`repro.serve.continuous`, served through `Server.register_decode`) is
+tested against.
 """
 
 from __future__ import annotations
 
 import dataclasses
-import time
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..compiler import compile as compile_deployment
-from ..core.graph import Graph
-from ..hw import HardwareModel, TPU_V5E
 from ..models.config import ModelConfig
 from ..models import prefill_step, decode_step, init_cache
 
@@ -34,78 +31,6 @@ class Request:
     max_new_tokens: int = 16
     out: list[int] = dataclasses.field(default_factory=list)
     done: bool = False
-
-
-class BatchedInferenceEngine:
-    """Batched CNN inference over a compiled `repro.compiler.Deployment`.
-
-    The network is compiled once through `repro.compile` (deployment cache
-    keyed on graph signature + machine fingerprint + backend) and every
-    batch replays the deployment's batched runner from the backend
-    registry: ``"jax"`` (the whole program as one jitted function vmapped
-    over the batch axis — the paper's static schedule turned into a real
-    batched serving step), ``"numpy"`` (vectorized per-sample replay; no
-    JAX tracing), ``"pallas"`` (the Pallas kernel lowering: real Mosaic
-    kernels on TPU, interpret mode elsewhere), or any third-party backend
-    registered via `repro.compiler.register_backend`. All built-ins are
-    bit-exact vs ``reference_forward``.
-
-    An engine can also be built straight from a saved artifact:
-    ``BatchedInferenceEngine.from_deployment(Deployment.load(path))``.
-    """
-
-    def __init__(self, graph: Graph, params: dict,
-                 hw: HardwareModel = TPU_V5E,
-                 num_cores: int | None = None, backend: str = "jax",
-                 backend_options=None,
-                 deployment=None,
-                 fault_hook=None):
-        self.graph = graph
-        self.params = params
-        self.backend = backend
-        if deployment is None:
-            deployment = compile_deployment(graph, hw, backend=backend,
-                                            params=params,
-                                            num_cores=num_cores,
-                                            backend_options=backend_options)
-        elif backend_options is not None:
-            # precompiled artifact: re-key with the requested options
-            # (validated against the backend's capabilities at swap time)
-            deployment = deployment.with_backend(backend,
-                                                 options=backend_options)
-        self.deployment = deployment
-        self.options = deployment.options
-        self.program = deployment.program
-        self._fn = deployment.runner(batched=True, backend=backend)
-        # chaos-run injection point for standalone engines (inside a
-        # Server the resilience layer injects at the job level instead):
-        # called before the runner, so a raising hook costs no state
-        self.fault_hook = fault_hook
-        self.metrics = {"batches": 0, "samples": 0}
-
-    @classmethod
-    def from_deployment(cls, deployment, backend: str | None = None,
-                        backend_options=None) -> "BatchedInferenceEngine":
-        """Serve a precompiled (e.g. `Deployment.load`-ed) artifact."""
-        return cls(deployment.graph, None,
-                   backend=backend or deployment.backend,
-                   backend_options=backend_options,
-                   deployment=deployment)
-
-    def infer(self, batch: dict[str, np.ndarray] | np.ndarray
-              ) -> dict[str, np.ndarray]:
-        """batch: {input_name: (B, ...)} (or a bare array for single-input
-        graphs) -> {output_name: (B, ...)}."""
-        if not isinstance(batch, dict):
-            (name,) = self.graph.inputs
-            batch = {name: batch}
-        B = next(iter(batch.values())).shape[0]
-        if self.fault_hook is not None:
-            self.fault_hook()
-        res = self._fn(batch)
-        self.metrics["batches"] += 1
-        self.metrics["samples"] += B
-        return res
 
 
 class ServeEngine:
@@ -148,12 +73,10 @@ class ServeEngine:
             if r.rid >= 0 and r.max_new_tokens > 0:
                 r.out.append(int(t))
         for step in range(1, max_new):
-            t0 = time.perf_counter()
             logits, cache = self._decode(self.params, cache, tok[:, None])
             self.metrics["decode_steps"] += 1
             tok = jnp.argmax(logits[:, -1, :], axis=-1).astype(jnp.int32)
-            tok_host = np.asarray(tok)           # sync: result materialized
-            self._record_decode_step(time.perf_counter() - t0)
+            tok_host = np.asarray(tok)
             for r, t in zip(requests, tok_host):
                 if r.rid >= 0 and len(r.out) < r.max_new_tokens:
                     r.out.append(int(t))
@@ -161,11 +84,6 @@ class ServeEngine:
         for r in requests:
             r.done = True
         return [r for r in requests if r.rid >= 0]
-
-    def _record_decode_step(self, dt_s: float) -> None:
-        """Per-decode-step timing hook (each step individually, measured at
-        its sync point). `PredictableEngine` overrides this to feed the
-        `DeadlineMonitor`; the base engine keeps no deadline state."""
 
     def serve(self, requests: list[Request],
               prompt_len: int | None = None) -> list[Request]:

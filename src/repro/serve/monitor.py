@@ -1,11 +1,9 @@
 """Deadline telemetry: the ONE place run-time deadline accounting lives.
 
-Every serving surface in this repo enforces the same scheme — a WCET bound
+Every network `Server` serves is held to the same scheme — a WCET bound
 from the compiler pipeline, scaled into wall-clock time by a measured (or
-pinned) machine-speed ratio, with a slack factor for host jitter — but it
-used to be re-implemented inline by `PredictableEngine.generate` and
-`MultiModelEngine.run_hyperperiod`, each with its own calibration and its
-own counters.  `DeadlineMonitor` extracts that logic once:
+pinned) machine-speed ratio, with a slack factor for host jitter.
+`DeadlineMonitor` holds that logic once:
 
   * **calibration** — the ratio between host wall time and modeled machine
     time is measured on the first real execution (latency / bound) unless

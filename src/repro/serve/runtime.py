@@ -29,8 +29,8 @@ The pieces, mirroring the paper's deployment story:
   * **release-order execution** — `step()` executes the next job of the
     compiled hyperperiod program; `run()` continues across hyperperiod
     boundaries (the job cursor wraps, releases accumulate absolute time),
-    generalizing `MultiModelEngine.run_hyperperiod` to sustained operation
-    the way JetStream's orchestrator drives its batched slots.
+    sustained operation the way JetStream's orchestrator drives its
+    batched slots.
   * **deadline telemetry** — one shared `DeadlineMonitor` calibrates the
     machine-speed ratio and accounts per-network checks/misses/histograms;
     every `Ticket` carries its own `DeadlineVerdict`.
@@ -290,11 +290,9 @@ class _Network:
     spec: NetworkSpec
     slots: int = 1
     step_fn: Callable | None = None
-    autorun: bool = False                # MultiModelEngine mode: jobs free-run
     params: dict | None = None
     deployment: object = None            # compiler Deployment (executable nets)
     runner: Callable | None = None       # batched runner at the slot count
-    engine: object = None                # BatchedInferenceEngine (attach mode)
     queue: RequestQueue | None = None
     cengine: object = None               # ContinuousEngine (decode networks)
     sustained: object = None             # SustainedServeVerdict (if declared)
@@ -332,7 +330,8 @@ class Server:
                      Deployment + batched runner on it;
       backend_options
                      a `repro.BackendOptions` with typed execution knobs
-                     (interpret mode, megakernel on/off, tile overrides),
+                     (interpret mode, the megakernel's scratchpad
+                     budget and kernel-count target),
                      validated against the backend's capabilities up front
                      and persisted through `save`/`load`;
       queue_capacity / queue_policy
@@ -404,28 +403,22 @@ class Server:
 
     @property
     def executors(self) -> dict[str, object]:
-        """Per-network executors: the `BatchedInferenceEngine` where one
-        was attached (`attach_executors`), else the compiled Deployment."""
-        return {n: (st.engine or st.deployment)
-                for n, st in self._nets.items()
-                if st.engine is not None or st.deployment is not None}
+        """Per-network executors: the compiled `Deployment` of every
+        network that has one."""
+        return {n: st.deployment for n, st in self._nets.items()
+                if st.deployment is not None}
 
     def add(self, name: str, net, period_s: float,
             deadline_s: float | None = None, *,
             criticality: int = 0,
             step_fn: Callable | None = None, slots: int = 1,
-            autorun: bool = False, params: dict | None = None,
+            params: dict | None = None,
             batch: int = 1, cache_len: int = 256,
             max_layers: int | None = 4) -> None:
         """Register WITHOUT admission control or executor building — the
-        analysis is invalidated and re-run lazily. This is the unchecked
-        path `MultiModelEngine.add_graph/add_model` ride on; new code
-        should prefer `register`.
-
-        autorun=True marks a free-running network (MultiModelEngine mode):
-        its `step_fn` takes NO arguments and is invoked once per job;
-        autorun networks refuse `submit` (queued serving uses the one-arg
-        ``step_fn(payload)`` convention of `register`)."""
+        analysis is invalidated and re-run lazily. `register`,
+        `register_decode` and `load` build on it; callers should prefer
+        `register`."""
         if name in self._nets:
             raise ServeError(f"network {name!r} already registered")
         if slots < 1:
@@ -435,7 +428,7 @@ class Server:
         self._nets[name] = _Network(
             spec=NetworkSpec(name, graph, period_s, deadline_s,
                              criticality=criticality),
-            slots=slots, step_fn=step_fn, autorun=autorun, params=params,
+            slots=slots, step_fn=step_fn, params=params,
             queue=RequestQueue(name, self.queue_capacity, self.queue_policy))
         if self.resilience is not None:
             self._arm_networks()
@@ -642,12 +635,7 @@ class Server:
         degraded verdict — degraded operation is a per-network property,
         not a blanket `BackpressureError` for everyone."""
         st = self._net(name)
-        if st.autorun:
-            raise ServeError(
-                f"network {name!r} free-runs a no-arg step_fn every job "
-                f"(MultiModelEngine mode) and does not take submissions")
-        if st.runner is None and st.step_fn is None and \
-                st.deployment is None and st.cengine is None:
+        if st.runner is None and st.step_fn is None and st.cengine is None:
             raise ServeError(
                 f"network {name!r} has no executor: it was added without "
                 f"admission (or is analysis-only) — register it through "
@@ -750,7 +738,7 @@ class Server:
         bound = self.report.bound(job.network)
         seq = self.metrics["jobs"]           # the job's sequence number
         self.metrics["jobs"] += 1
-        if st.breaker is not None and not st.autorun:
+        if st.breaker is not None:
             action = st.breaker.on_release()
             if action == "skip":
                 # open breaker: the network operates degraded — this
@@ -763,14 +751,7 @@ class Server:
                     self._resolve_terminal(t, "degraded")
                 self.metrics["idle_jobs"] += 1
                 return
-        if st.autorun and st.step_fn is not None:
-            # MultiModelEngine mode: every job free-runs its no-arg fn
-            # (autorun networks never hold tickets — submit refuses them)
-            out, dt = self._serve_call(st, [], st.step_fn, seq)
-            if out is _GIVE_UP:
-                return
-            self.monitor.check(job.network, dt, bound)
-        elif st.runner is not None and len(st.queue) > 0:
+        if st.runner is not None and len(st.queue) > 0:
             with tracing.span("repro.server.batch", seq) as sp:
                 tickets = st.queue.pop_upto(st.slots)
                 batch = self._take_staged(st, tickets)
@@ -1346,50 +1327,6 @@ class Server:
                 f"restores={m['restores']} "
                 f"mode_switches={m['mode_switches']}")
         return "\n".join(lines)
-
-    # -- MultiModelEngine-compat executor attachment -------------------------
-    def attach_executors(self, params_by_net: dict | None = None,
-                         inputs_by_net: dict | None = None,
-                         backend: str | None = None,
-                         seed: int = 0) -> dict[str, object]:
-        """Install compiled-deployment engines as free-running step_fns for
-        every executable network that has none (the
-        `MultiModelEngine.attach_compiled_executors` path): each job
-        instance replays the network's Deployment on a fixed input. Returns
-        the per-network `BatchedInferenceEngine`s."""
-        from ..compiler import compile as compile_deployment
-        from ..core.compiled import supports_graph
-        from ..core.executor import init_params
-        from .engine import BatchedInferenceEngine
-        backend = backend or self.backend
-        params_by_net = params_by_net or {}
-        inputs_by_net = inputs_by_net or {}
-        engines: dict[str, object] = {}
-        rng = np.random.default_rng(seed)
-        for name, st in self._nets.items():
-            if st.step_fn is not None or not supports_graph(st.spec.graph):
-                continue
-            graph = st.spec.graph
-            params = (params_by_net.get(name) or st.params
-                      or init_params(graph))
-            inp = inputs_by_net.get(name)
-            if inp is None:
-                inp = {t: rng.integers(
-                           -64, 64, size=(1,) + graph.tensors[t].shape
-                       ).astype(np.int8)
-                       for t in graph.inputs}
-            dep = compile_deployment(graph, self.machine, backend=backend,
-                                     params=params,
-                                     num_cores=self.num_cores,
-                                     arbitration=self.arbitration,
-                                     backend_options=self.backend_options)
-            eng = BatchedInferenceEngine.from_deployment(dep)
-            st.step_fn = (lambda e=eng, x=inp: e.infer(x))
-            st.autorun = True
-            st.deployment = dep          # the artifact (bundles save this)
-            st.engine = eng
-            engines[name] = eng
-        return engines
 
     # -- static analysis -----------------------------------------------------
     def verify(self, *, suppress: tuple = ()):

@@ -13,8 +13,9 @@ import pytest
 
 from repro.core import (analyze, cnn, compile_graph, execute_schedule,
                         init_params, lower_program, reference_forward,
-                        run_jax, run_numpy, run_pallas)
+                        run_jax, run_numpy, run_megakernel)
 from repro.core import compiled as C
+from repro.core import megakernel as MK
 from repro.core.schedule import compute_schedule, validate_schedule
 from repro.core.taskset import NetworkSpec, compile_taskset
 from repro.hw import scaled_paper_machine
@@ -44,8 +45,8 @@ BACKENDS = {
     "numpy": lambda prog, x: run_numpy(prog, {"input": x}),
     "jax": lambda prog, x: {t: v[0] for t, v in
                             run_jax(prog, {"input": x[None]}).items()},
-    "pallas": lambda prog, x: run_pallas(prog, {"input": x},
-                                         interpret=True),
+    "pallas": lambda prog, x: run_megakernel(prog, {"input": x},
+                                             interpret=True),
 }
 
 
@@ -340,7 +341,7 @@ def test_pallas_no_fusion_when_acc_is_graph_output():
     x = np.random.default_rng(8).integers(-64, 64,
                                           size=(12, 12, 3)).astype(np.int8)
     ref = reference_forward(g, params, {"input": x})
-    out = run_pallas(prog, {"input": x}, interpret=True)
+    out = run_megakernel(prog, {"input": x}, interpret=True)
     for t in g.outputs:
         assert np.array_equal(ref[t], out[t])
 
@@ -361,18 +362,19 @@ def test_pallas_per_channel_requant_fused():
     x = np.random.default_rng(10).integers(
         -64, 64, size=(32, 32, 3)).astype(np.int8)
     ref = reference_forward(g, params, {"input": x})
-    out = run_pallas(prog, {"input": x}, interpret=True)
+    out = run_megakernel(prog, {"input": x}, interpret=True)
     for t in g.outputs:
         assert np.array_equal(ref[t], out[t])
 
 
 @pytest.mark.parametrize("batch", [1, 3])
 def test_pallas_batched_vmap(batch):
-    """pallas_batched vmaps the kernel program over a leading batch axis."""
+    """megakernel_batched vmaps the kernel program over a leading batch
+    axis."""
     g, shape, params, prog, _ = _compiled("small_cnn")
     xb = np.random.default_rng(11).integers(
         -64, 64, size=(batch,) + shape).astype(np.int8)
-    fn = C.pallas_batched(prog, interpret=True)
+    fn = MK.megakernel_batched(prog, interpret=True)
     out = {k: np.asarray(v) for k, v in fn({"input": xb}).items()}
     for b in range(batch):
         ref = reference_forward(g, params, {"input": xb[b]})
@@ -381,17 +383,23 @@ def test_pallas_batched_vmap(batch):
 
 
 def test_engine_pallas_backend():
-    """BatchedInferenceEngine(backend="pallas") serves bit-exact batches."""
-    from repro.serve.engine import BatchedInferenceEngine
+    """Server(backend="pallas") in interpret mode serves a bit-exact batch
+    of 2 in one runner call."""
+    from repro.compiler import BackendOptions
+    from repro.serve import Server
     g = cnn.small_cnn()
     params = init_params(g, seed=12)
-    eng = BatchedInferenceEngine(g, params, scaled_paper_machine(4), 4,
-                                 backend="pallas")
+    srv = Server(scaled_paper_machine(4), backend="pallas", num_cores=4,
+                 backend_options=BackendOptions(interpret=True))
+    srv.register("cnn", g, period_s=1 / 50, slots=2, params=params)
     xb = np.random.default_rng(13).integers(
         -64, 64, size=(2, 32, 32, 3)).astype(np.int8)
-    out = eng.infer(xb)
-    for b in range(2):
+    tickets = [srv.submit("cnn", x) for x in xb]
+    srv.step()
+    for b, tk in enumerate(tickets):
         ref = reference_forward(g, params, {"input": xb[b]})
+        out = tk.result().output
         for t in g.outputs:
-            assert np.array_equal(ref[t], out[t][b])
-    assert eng.metrics == {"batches": 1, "samples": 2}
+            assert np.array_equal(ref[t], out[t])
+    assert srv.metrics["runner_calls"] == 1
+    assert srv.metrics["slots_filled"] == 2

@@ -179,8 +179,8 @@ def test_third_party_backend_pluggable():
     default batched factory loops the single runner."""
     calls = {"n": 0}
 
-    def make_single(prog):
-        inner = get_backend("numpy").single(prog)
+    def make_single(prog, options):
+        inner = get_backend("numpy").single(prog, options)
 
         def run(inputs):
             calls["n"] += 1
@@ -332,36 +332,57 @@ def test_compile_taskset_deployment():
 # -- serving integration -----------------------------------------------------
 
 def test_multi_model_engine_attaches_deployments():
-    """attach_compiled_executors compiles each admitted CNN into a cached
-    Deployment and hyperperiod jobs replay it with deadline accounting."""
-    from repro.serve.predictable import MultiModelEngine
-    eng = MultiModelEngine(hw=HW, num_cores=4)
-    eng.add_graph("a", cnn.small_cnn(), period_s=1 / 50)
-    eng.add_graph("b", cnn.small_cnn(h=24, w=24), period_s=1 / 100)
-    assert eng.compile().schedulable
-    executors = eng.attach_compiled_executors(backend="numpy")
+    """`Server.register` compiles each admitted CNN once into a cached
+    Deployment (a second server admitting the same network gets the same
+    artifact) and serves a submitted frame through it bit-exact, with
+    deadline accounting."""
+    from repro.serve import Server
+    clear_deployment_cache()
+    graphs = {"a": cnn.small_cnn(), "b": cnn.small_cnn(h=24, w=24)}
+    params = {n: init_params(g, seed=i) for i, (n, g) in
+              enumerate(graphs.items())}
+    periods = {"a": 1 / 50, "b": 1 / 100}
+
+    def server():
+        srv = Server(HW, backend="numpy", num_cores=4, speed_ratio=1e12)
+        for n, g in graphs.items():
+            srv.register(n, g, period_s=periods[n], params=params[n])
+        return srv
+
+    srv = server()
+    assert srv.report.schedulable
+    executors = srv.executors
     assert set(executors) == {"a", "b"}
-    for ex in executors.values():
-        assert ex.deployment.backend == "numpy"
-        assert ex.deployment.wcet_bound_s > 0
-    stats = eng.run_hyperperiod(speed_ratio=1e12)      # generous budget
-    assert stats["checks"]["a"] >= 1 and stats["checks"]["b"] >= 2
-    assert executors["b"].metrics["batches"] >= 2
+    for dep in executors.values():
+        assert dep.backend == "numpy" and dep.wcet_bound_s > 0
+    again = server().executors
+    assert all(again[n] is executors[n] for n in graphs)
+    frames = {n: np.random.default_rng(i).integers(
+        -64, 64, size=g.tensors["input"].shape).astype(np.int8)
+        for i, (n, g) in enumerate(graphs.items())}
+    tickets = {n: srv.submit(n, x) for n, x in frames.items()}
+    srv.run(hyperperiods=1)
+    for n, g in graphs.items():
+        ref = reference_forward(g, params[n], {"input": frames[n]})
+        out = tickets[n].result().output
+        for t in g.outputs:
+            assert np.array_equal(ref[t], out[t])
+    assert srv.monitor.checks == {"a": 1, "b": 1}
+    assert srv.metrics["runner_calls"] == 2
 
 
 def test_engine_exposes_deployment_and_loads_artifacts(tmp_path):
-    from repro.serve.engine import BatchedInferenceEngine
+    """`Deployment.save` -> `Deployment.load` -> batched runner serves
+    bit-exact."""
     g, x = _graph_and_input()
     params = init_params(g, seed=9)
-    eng = BatchedInferenceEngine(g, params, HW, 4, backend="numpy")
-    assert eng.deployment.backend == "numpy"
+    dep = repro.compile(g, HW, backend="numpy", params=params, num_cores=4)
     path = str(tmp_path / "net.rtdep")
-    eng.deployment.save(path)
+    dep.save(path)
 
-    eng2 = BatchedInferenceEngine.from_deployment(
-        Deployment.load(path, machine=HW))
-    out = eng2.infer(x[None])
+    loaded = Deployment.load(path, machine=HW)
+    assert loaded.backend == "numpy"
+    out = loaded.runner(batched=True)({"input": x[None]})
     ref = reference_forward(g, params, {"input": x})
     for t in g.outputs:
         assert np.array_equal(ref[t], out[t][0])
-    assert eng2.metrics == {"batches": 1, "samples": 1}

@@ -5,24 +5,24 @@ Megakernel contract (repro/core/megakernel.py): walking the pallas plan,
 packing steps into segments that never exceed the scratchpad, and emitting
 `num_cores` fused `pallas_call`s per program where the scratchpad allows,
 must stay bit-exact against `reference_forward` on every CNN preset —
-single sample and vmapped batch — while the per-op path (megakernel=False)
-keeps working.
+single sample and vmapped batch.
 
 Backend API contract (repro/compiler/backends.py): `BackendOptions` are
 validated against `BackendCapabilities` at compile/swap time (not on first
-run), persisted through `Deployment.save`/`load`, and legacy
-single-argument `register_backend` factories keep working via the
-deprecation shim.
+run) and persisted through `Deployment.save`/`load`; a saved artifact
+whose options carry a key this version no longer has still loads.
 """
 
-import warnings
+import hashlib
+import json
+import pickle
+import zipfile
 
 import numpy as np
 import pytest
 
 import repro
-from repro.compiler import (BackendError, BackendOptions, get_backend,
-                            register_backend, unregister_backend)
+from repro.compiler import BackendError, BackendOptions, get_backend
 from repro.core import (analyze, cnn, init_params, lower_program,
                         reference_forward)
 from repro.core import megakernel as MK
@@ -79,15 +79,16 @@ def test_megakernel_call_count_invariant(preset):
 
 
 def test_megakernel_fuses_below_per_op():
-    """The whole point: far fewer kernel launches than one-call-per-op."""
+    """The whole point: far fewer kernel launches than the plan has kernel
+    steps (one gemm or conv2d kernel per op)."""
     g, shape, params, prog = _compiled("resnet50")
     import jax.numpy as jnp
     from repro.core import compiled as C
     x = jnp.zeros(shape, jnp.int8)
     n_mega = MK.count_pallas_calls(
         MK.megakernel_single(prog, interpret=True), {"input": x})
-    n_perop = MK.count_pallas_calls(
-        C.pallas_single(prog, interpret=True), {"input": x})
+    counts = C.plan_counts(prog)
+    n_perop = counts.get("gemm", 0) + counts.get("conv2d", 0)
     assert n_mega <= prog.num_cores < n_perop
 
 
@@ -183,16 +184,17 @@ def _pointwise_program(hw_size, stride, c_in, c_out, requant):
     return g, lower_program(g, params, subtasks, mapping, sched, hw=hw)
 
 
-@pytest.mark.parametrize("megakernel", [False, True])
+@pytest.mark.parametrize("budget", [None, 1024])
 @pytest.mark.parametrize("requant", [True, False])
 @pytest.mark.parametrize("hw_size,stride,c_in,c_out", [
     (55, 1, 64, 256), (55, 2, 64, 64), (7, 1, 256, 64), (7, 2, 128, 256)])
 def test_pointwise_conv_runs_on_gemm_kernel_bit_exact(
-        hw_size, stride, c_in, c_out, requant, megakernel):
-    """A 1×1 conv runs on the GEMM kernel, per-op and as the megakernel's
-    tiled segment (a budget below its working set), bit for bit equal to
-    `run_numpy`: exact int32 contraction, same requant epilogue, stride by
-    subsampling."""
+        hw_size, stride, c_in, c_out, requant, budget):
+    """A 1×1 conv is planned as a GEMM and runs bit for bit equal to
+    `run_numpy` at the megakernel's default plan and as its tiled segment
+    (a budget below its working set); wherever it lands in a tiled
+    segment it runs on the GEMM kernel: exact int32 contraction, same
+    requant epilogue, stride by subsampling."""
     import jax.numpy as jnp
     from repro.core import compiled as C
     from repro.kernels.gemm_int8 import gemm_kernel_name
@@ -204,14 +206,13 @@ def test_pointwise_conv_runs_on_gemm_kernel_bit_exact(
     assert (step.mult is not None) == requant
     x = np.random.default_rng(hw_size + stride).integers(
         -128, 128, size=(hw_size, hw_size, c_in)).astype(np.int8)
-    if megakernel:
-        (seg,) = MK.plan_segments(prog, budget=1024)
+    (seg,) = MK.plan_segments(prog, budget=budget)
+    if budget is not None:
         assert seg.kind == "tiled"
-        fn = MK.megakernel_single(prog, interpret=True, budget=1024)
-    else:
-        fn = C.pallas_single(prog, interpret=True)
-    assert MK.pallas_call_names(fn, {"input": jnp.asarray(x)}) == [
-        gemm_kernel_name(oh * oh, c_in, c_out)]
+    fn = MK.megakernel_single(prog, interpret=True, budget=budget)
+    if seg.kind == "tiled":
+        assert MK.pallas_call_names(fn, {"input": jnp.asarray(x)}) == [
+            gemm_kernel_name(oh * oh, c_in, c_out)]
     ref = C.run_numpy(prog, {"input": x})
     out = fn({"input": jnp.asarray(x)})
     (t,) = g.outputs
@@ -239,7 +240,7 @@ def _deploy(preset="small_cnn", backend="pallas", **kw):
 def test_backend_options_validated_at_compile_time():
     with pytest.raises(BackendError, match="does not support"):
         _deploy(backend="jax",
-                backend_options=BackendOptions(megakernel=True))
+                backend_options=BackendOptions(max_kernels=2))
 
 
 def test_interpret_false_requires_tpu():
@@ -272,13 +273,29 @@ def test_with_backend_validates_at_swap_time():
             assert np.array_equal(ref[t], out[t])
 
 
-def test_pallas_megakernel_off_restores_per_op_path():
+def test_pallas_megakernel_off_restores_per_op_path(tmp_path):
+    """An artifact saved when the pallas backend still had a per-op path
+    carries ``"megakernel": false`` in its options. It loads, the retired
+    key is ignored, and it runs the megakernel bit-exact."""
     g, shape, params, dep = _deploy(
-        backend="pallas",
-        backend_options=BackendOptions(interpret=True, megakernel=False))
+        backend="pallas", backend_options=BackendOptions(interpret=True))
+    p = str(tmp_path / "old.rtdep")
+    dep.save(p)
+    with zipfile.ZipFile(p) as z:
+        manifest = json.loads(z.read("manifest.json"))
+        payload = pickle.loads(z.read("payload.pkl"))
+    old_options = {"interpret": True, "megakernel": False}
+    payload["backend_options"] = manifest["backend_options"] = old_options
+    blob = pickle.dumps(payload)
+    manifest["payload_sha256"] = hashlib.sha256(blob).hexdigest()
+    with zipfile.ZipFile(p, "w") as z:
+        z.writestr("manifest.json", json.dumps(manifest))
+        z.writestr("payload.pkl", blob)
+    old = repro.Deployment.load(p, machine=dep.machine)
+    assert old.options == BackendOptions(interpret=True)
     x = np.random.default_rng(2).integers(-64, 64, size=shape).astype(np.int8)
     ref = reference_forward(g, params, {"input": x})
-    out = dep.run({"input": x})
+    out = old.run({"input": x})
     for t in g.outputs:
         assert np.array_equal(ref[t], out[t])
 
@@ -315,47 +332,26 @@ def test_capabilities_of_builtins():
     assert get_backend("numpy").capabilities.supported_options == frozenset()
 
 
-def test_legacy_factory_deprecation_shim():
-    """Old-style `register_backend(name, single=lambda prog: ...)` still
-    works, with a DeprecationWarning at registration."""
-    def legacy(prog):
-        def run(inputs):
-            from repro.core import run_numpy
-            vals = run_numpy(prog, inputs)
-            return {t: vals[t] for t in prog.graph.outputs}
-        return run
-
-    with warnings.catch_warnings(record=True) as w:
-        warnings.simplefilter("always")
-        register_backend("legacy-test", single=legacy)
-    try:
-        assert any(issubclass(x.category, DeprecationWarning) for x in w)
-        g, shape, params, dep = _deploy(backend="legacy-test")
-        x = np.random.default_rng(2).integers(
-            -64, 64, size=shape).astype(np.int8)
-        ref = reference_forward(g, params, {"input": x})
-        out = dep.run({"input": x})
-        for t in g.outputs:
-            assert np.array_equal(ref[t], out[t])
-    finally:
-        unregister_backend("legacy-test")
-
-
 def test_engine_accepts_backend_options():
-    from repro.serve.engine import BatchedInferenceEngine
+    """`Server(backend_options=...)` carries the options into the
+    Deployment it compiles, and serves through it bit-exact."""
+    from repro.serve import Server
     g, shape = PRESETS["small_cnn"][0](), PRESETS["small_cnn"][1]
     params = init_params(g, seed=1)
-    eng = BatchedInferenceEngine(
-        g, params, hw=scaled_paper_machine(4), backend="pallas",
-        backend_options=BackendOptions(interpret=True))
-    assert eng.options.interpret is True
+    opts = BackendOptions(interpret=True, max_kernels=2)
+    srv = Server(scaled_paper_machine(4), backend="pallas", num_cores=4,
+                 backend_options=opts)
+    srv.register("cnn", g, period_s=1 / 50, slots=2, params=params)
+    assert srv.executors["cnn"].options == opts
     xb = np.random.default_rng(7).integers(
         -64, 64, size=(2,) + shape).astype(np.int8)
-    out = eng.infer(xb)
-    for b in range(2):
+    tickets = [srv.submit("cnn", x) for x in xb]
+    srv.step()
+    for b, tk in enumerate(tickets):
         ref = reference_forward(g, params, {"input": xb[b]})
+        out = tk.result().output
         for t in g.outputs:
-            assert np.array_equal(ref[t], out[t][b])
+            assert np.array_equal(ref[t], out[t])
 
 
 def test_server_persists_backend_options(tmp_path):

@@ -1,5 +1,5 @@
-"""Serving: engine generation, predictable-mode WCET integration, quantized
-LM decode graph pipeline."""
+"""Serving: engine generation, decode WCET analysis and deadline checks
+through `Server`, quantized LM decode graph pipeline."""
 
 import jax
 import pytest
@@ -9,8 +9,9 @@ from repro.core.lmgraph import lm_decode_graph
 from repro.core.wcet import analyze
 from repro.hw import PAPER_RISCV, TPU_V5E, scaled_paper_machine
 from repro.models import init_params
+from repro.serve import Server
 from repro.serve.engine import Request, ServeEngine
-from repro.serve.predictable import PredictableEngine, analyze_decode
+from repro.serve.predictable import analyze_decode
 
 
 def test_engine_generates():
@@ -61,12 +62,21 @@ def test_analyze_decode_scales_layers():
 
 
 def test_predictable_engine_runs_with_deadlines():
+    """Decode through `Server.register_decode` with the machine-speed
+    ratio calibrated on the first step: tokens come out, every decode step
+    is checked, and the request gets a deadline verdict."""
     cfg = get_config("smollm-135m", reduced=True)
     params = init_params(cfg, jax.random.PRNGKey(0))
-    eng = PredictableEngine(cfg, params, batch_size=2, max_len=64,
-                            hw=scaled_paper_machine(4))
-    done = eng.generate([Request(rid=0, prompt=[1, 2], max_new_tokens=4)])
-    assert done[0].out and eng.deadline_checks > 0
+    srv = Server(scaled_paper_machine(4))
+    srv.register_decode("lm", cfg, period_s=1 / 50, params=params, slots=2,
+                        prompt_len=2, max_new_tokens=4, max_len=64)
+    t = srv.submit("lm", [1, 2])
+    while not t.done:
+        srv.step()
+    r = t.result()
+    assert r.output and r.verdict is not None
+    assert srv.monitor.speed_ratio is not None
+    assert srv.monitor.checks["lm"] > 0
 
 
 def test_wcet_scales_down_with_cores():

@@ -9,9 +9,9 @@
   * release-order execution is correct across multiple hyperperiods;
   * `Server.save`/`Server.load` round-trips a whole serving configuration
     and serves bit-exact results;
-  * the historical engines are thin wrappers: `PredictableEngine` counts
-    per-step checks AND misses, `MultiModelEngine.admit_model` admits LM
-    architectures through the same atomic path.
+  * every decode step of a `register_decode` network counts as a check
+    (and a miss) on the server's monitor, and `register` admits LM
+    architectures through the same atomic path as graphs.
 """
 
 import numpy as np
@@ -21,8 +21,7 @@ from repro.core import cnn
 from repro.hw import scaled_paper_machine
 from repro.models.config import ModelConfig
 from repro.serve import (AdmissionError, BackpressureError, DeadlineMonitor,
-                         MultiModelEngine, RequestQueue, ServeError, Server,
-                         Ticket)
+                         RequestQueue, ServeError, Server, Ticket)
 
 HW = scaled_paper_machine(4)
 
@@ -215,13 +214,6 @@ def test_failed_job_marks_popped_tickets_failed():
     assert t.done
 
 
-def test_autorun_network_refuses_submissions():
-    eng = MultiModelEngine(hw=HW, num_cores=4)
-    eng.add_graph("a", cnn.small_cnn(), period_s=1 / 50, step_fn=lambda: 1)
-    with pytest.raises(ServeError, match="free-runs"):
-        eng.server.submit("a", _frame())
-
-
 def test_pending_ticket_has_no_result():
     srv = _mixed_server()
     t = srv.submit("cnn", _frame())
@@ -400,37 +392,43 @@ def test_monitor_calibrates_once():
     assert mon.speed_ratio is None
 
 
-# -- wrappers ------------------------------------------------------------------
+# -- LM decode and LM admission ------------------------------------------------
 
 def test_predictable_engine_counts_misses_per_step():
     jax = pytest.importorskip("jax")
     from repro.configs import get_config
     from repro.models import init_params
-    from repro.serve import PredictableEngine, Request
     cfg = get_config("smollm-135m", reduced=True)
     params = init_params(cfg, jax.random.PRNGKey(0))
-    eng = PredictableEngine(cfg, params, batch_size=2, max_len=64,
-                            hw=scaled_paper_machine(4), speed_ratio=1e-12)
-    done = eng.generate([Request(rid=0, prompt=[1, 2], max_new_tokens=6)])
-    assert done[0].out
-    # the old aggregate accounting capped misses at 1 per generate() call;
-    # with a hopeless pinned ratio every individual step must miss
-    assert eng.deadline_checks == 5
-    assert eng.deadline_misses == eng.deadline_checks
+    srv = Server(HW, num_cores=4, speed_ratio=1e-12)
+    srv.register_decode("lm", cfg, period_s=1 / 50, params=params, slots=2,
+                        prompt_len=2, max_new_tokens=6, max_len=64)
+    t = srv.submit("lm", {"prompt": [1, 2], "max_new_tokens": 6})
+    while not t.done:
+        srv.step()
+    assert len(t.result().output) == 6
+    # a prefill and 5 decode steps: every decode step is checked on its
+    # own, and with a hopeless pinned ratio every one of them misses
+    assert srv.monitor.checks["lm"] == 5
+    assert srv.monitor.misses["lm"] == srv.monitor.checks["lm"]
 
 
 def test_multi_model_engine_admit_model():
-    eng = MultiModelEngine(hw=HW, num_cores=4)
-    assert eng.admit_graph("det", cnn.small_cnn(), period_s=1 / 50)
-    assert eng.admit_model("lm", _lm_cfg(), period_s=1 / 25, cache_len=64)
-    assert {s.name for s in eng.specs} == {"det", "lm"}
-    assert eng.report.schedulable
+    srv = Server(HW, backend="numpy", num_cores=4)
+    srv.register("det", cnn.small_cnn(), period_s=1 / 50)
+    srv.register("lm", _lm_cfg(), period_s=1 / 25, cache_len=64)
+    assert set(srv.networks) == {"det", "lm"}
+    assert srv.report.schedulable
     # an LM model with an impossible deadline is rejected atomically
-    assert not eng.admit_model("lm2", _lm_cfg(), period_s=1 / 25,
-                               cache_len=64, deadline_s=1e-9)
-    assert {s.name for s in eng.specs} == {"det", "lm"}
-    assert eng.report.schedulable
-    stats = eng.run_hyperperiod(speed_ratio=1e12)
-    assert stats["speed_ratio"] == 1e12
-    # "det" has no step_fn yet: executed for ordering, never checked
-    assert "det" not in stats["checks"]
+    with pytest.raises(AdmissionError) as e:
+        srv.register("lm2", _lm_cfg(), period_s=1 / 25, cache_len=64,
+                     deadline_s=1e-9)
+    assert e.value.report is not None and not e.value.report.schedulable
+    assert set(srv.networks) == {"det", "lm"}
+    assert srv.report.schedulable
+    srv.monitor.pin(1e12)
+    srv.run(hyperperiods=1)
+    assert srv.hyperperiods_completed == 1
+    # nothing was submitted: every job ran for ordering, none was checked
+    assert srv.metrics["idle_jobs"] == srv.metrics["jobs"] > 0
+    assert srv.monitor.checks == {}

@@ -256,12 +256,20 @@ def _jax_batched(prog: _C.CompiledProgram,
     return functools.partial(_C.run_jax, prog, batched=True)
 
 
-def _numpy_io(fn) -> Runner:
+def _numpy_io(fn, ship: Callable[[dict], dict]) -> Runner:
     """numpy in, numpy out around a jitted program, in three spans: the
     copy of the inputs to the device (`repro.runner.h2d`), the call, which
     returns once the program is enqueued (`repro.runner.launch`), and the
     copy of the outputs back, which waits for the device to finish
     (`repro.runner.fetch`).
+
+    `ship` puts the host inputs in the layout the program takes
+    (`megakernel.ship_inputs`): an input that the network's stem reads
+    crosses to the device as (N, H / s, s·W·C) row groups, a free reshape
+    of the C-contiguous (N, H, W, C) batch `Server` stacks, lane-dense on
+    the device. In its (N, H, W, C) shape the copy would pad 3 channels
+    out to 128 lanes (4.76 against 1.57 ms for 4 frames of 640×640×3 on a
+    TPU v5e).
 
     Two more entry points let a caller overlap the input copy of one call
     with the program of another. `run.stage(inputs)` issues the copy alone
@@ -274,7 +282,7 @@ def _numpy_io(fn) -> Runner:
 
     def stage(inputs: dict) -> dict:
         with tracing.span("repro.runner.h2d"):
-            return {k: jnp.asarray(v) for k, v in inputs.items()}
+            return {k: jnp.asarray(v) for k, v in ship(inputs).items()}
 
     def launch(inputs: dict) -> Callable[[], dict]:
         if not all(isinstance(v, jax.Array) for v in inputs.values()):
@@ -306,12 +314,16 @@ def _pallas_fn(prog: _C.CompiledProgram, options: BackendOptions,
 
 def _pallas_single(prog: _C.CompiledProgram,
                    options: BackendOptions) -> Runner:
-    return _numpy_io(_pallas_fn(prog, options, batched=False))
+    import functools
+    return _numpy_io(_pallas_fn(prog, options, batched=False),
+                     functools.partial(_MK.ship_inputs, prog))
 
 
 def _pallas_batched(prog: _C.CompiledProgram,
                     options: BackendOptions) -> Runner:
-    return _numpy_io(_pallas_fn(prog, options, batched=True))
+    import functools
+    return _numpy_io(_pallas_fn(prog, options, batched=True),
+                     functools.partial(_MK.ship_inputs, prog))
 
 
 def _mesh_single(prog: _C.CompiledProgram,

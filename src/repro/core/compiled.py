@@ -67,6 +67,7 @@ from ..hw import HardwareModel, derive_conv_blocks, derive_gemm_blocks
 from ..kernels import ref as kref
 from ..kernels.conv2d_im2col import conv2d_int8_pallas
 from ..kernels.gemm_int8 import gemm_int8_pallas
+from ..kernels.stem_conv import fits_stem, stem_conv_pallas, stem_geometry
 
 _JNP_DT = {"int8": jnp.int8, "uint8": jnp.uint8, "int16": jnp.int16,
            "int32": jnp.int32, "f32": jnp.float32, "bf16": jnp.float32}
@@ -554,9 +555,11 @@ class GemmShape(NamedTuple):
 class _PallasStep:
     """One op of the pallas-backend program plan.
 
-    mode: "gemm" / "conv2d" (Pallas kernel), "jax" (fallback), or "skip"
-    (a requant batch fused into the preceding kernel's epilogue). A "gemm"
-    step's batch is a gemm or a pointwise conv; `gemm` gives its shape.
+    mode: "gemm" / "conv2d" / "stem" (Pallas kernel), "jax" (fallback),
+    or "skip" (a requant batch fused into the preceding kernel's epilogue).
+    A "gemm" step's batch is a gemm or a pointwise conv; `gemm` gives its
+    shape. A "stem" step is a conv that reads a graph input as row groups
+    (`_is_stem`).
     """
 
     mode: str
@@ -601,6 +604,28 @@ def _gemm_shape(b: OpBatch) -> GemmShape | None:
     return None
 
 
+def _is_stem(prog: CompiledProgram, b: OpBatch) -> bool:
+    """Whether a conv batch reads a graph input that nothing else reads,
+    through a weight nothing else uses, with few enough input channels
+    that a window row (`C_in · kw`) fits one 128-lane row and whole row
+    groups of `stride` frame rows (`kernels.stem_conv.fits_stem`)."""
+    if b.kind != "conv2d":
+        return False
+    a = b.attrs
+    name = prog.buffers[b.in_idx[0]][0]
+    return (name in prog.graph.inputs
+            and len(prog.graph.consumers_of(name)) == 1
+            and sum(o.w_idx == b.w_idx for o in prog.batches) == 1
+            and fits_stem(a["H"], a["C_in"], a["kw"], a["stride"]))
+
+
+def stem_geometry_of(b: OpBatch):
+    """The stem kernel's geometry for a "stem" step's conv batch."""
+    a = b.attrs
+    return stem_geometry(a["H"], a["W"], a["C_in"], a["C_out"], kh=a["kh"],
+                         kw=a["kw"], stride=a["stride"], padding=a["padding"])
+
+
 def _pallas_plan(prog: CompiledProgram) -> list[_PallasStep]:
     """Decide, once per program, how each fused tile batch lowers onto the
     Pallas kernels: kernel vs fallback, epilogue fusion, and SPM-derived
@@ -613,6 +638,12 @@ def _pallas_plan(prog: CompiledProgram) -> list[_PallasStep]:
     GEMM kernel, which feeds int8 blocks straight to the MXU, instead of
     the windowed conv kernel's halo-less pad, band copies, int32 widening
     and tap loads. Same exact int32 contraction, same requant epilogue.
+
+    A windowed conv that reads a narrow graph input alone (`_is_stem`:
+    the networks' 3-channel stems) is a "stem" step: the runner ships that
+    input as row groups (`megakernel.input_layout`) and the stem kernel
+    (`kernels.stem_conv`) contracts them against banded weights, so the
+    frame is never padded out to 128 lanes, on the host or on the device.
     Every other conv keeps the windowed kernel."""
     plan: list[_PallasStep] = []
     skipped: set[int] = set()
@@ -629,37 +660,48 @@ def _pallas_plan(prog: CompiledProgram) -> list[_PallasStep]:
         out_bytes = 1 if rq is not None else 4
         gemm = _gemm_shape(b)
         if gemm is not None:
+            mode = "gemm"
             blocks = (derive_gemm_blocks(prog.hw, gemm.M, gemm.K, gemm.N,
                                          out_bytes)
                       if prog.hw is not None else (128, 128, 128))
+        elif _is_stem(prog, b):
+            mode, blocks = "stem", ()
         else:
+            mode = "conv2d"
             blocks = (derive_conv_blocks(prog.hw, b.attrs, out_bytes)
                       if prog.hw is not None else (8, 128))
         if rq is not None:
             skipped.add(rq.op_idx)
-        plan.append(_PallasStep("gemm" if gemm is not None else "conv2d",
-                                b, out_idx, mult, blocks, gemm))
+        plan.append(_PallasStep(mode, b, out_idx, mult, blocks, gemm))
     return plan
 
 
 def plan_counts(prog: CompiledProgram) -> dict[str, int]:
     """Steps of the pallas plan by mode, plus `pointwise_gemm`: the convs
-    planned as GEMMs."""
+    planned as GEMMs, and `dense_stem`: the convs that read a graph input
+    as row groups (the "stem" steps)."""
     plan = _pallas_plan(prog)
     counts = dict(Counter(s.mode for s in plan))
     counts["pointwise_gemm"] = sum(
         s.mode == "gemm" and s.batch.kind == "conv2d" for s in plan)
+    counts["dense_stem"] = counts.get("stem", 0)
     return counts
 
 
 def run_kernel_step(prog: CompiledProgram, step: _PallasStep, vals: list,
                     weights: dict, interpret: bool) -> None:
-    """Run one "gemm" or "conv2d" plan step on its tiled Pallas kernel
-    (grid-streamed, double-buffered) and store its result in `vals`."""
+    """Run one "gemm", "conv2d" or "stem" plan step on its tiled Pallas
+    kernel (grid-streamed, double-buffered) and store its result in
+    `vals`. A stem's input is in row groups and its weight is the band
+    expansion (`megakernel.device_weights`)."""
     b = step.batch
     mult = None if step.mult is None else jnp.asarray(step.mult)
     x = vals[b.in_idx[0]]
-    if step.mode == "gemm":
+    if step.mode == "stem":
+        vals[step.out_idx] = stem_conv_pallas(
+            x, weights[b.w_idx], mult, geom=stem_geometry_of(b),
+            interpret=interpret)
+    elif step.mode == "gemm":
         M, K, _, s = step.gemm
         bm, bn, bk = step.blocks
         if s > 1:

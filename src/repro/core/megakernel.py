@@ -37,8 +37,15 @@ per op:
      its input, subsampled by the stride and flattened to (oh·ow, C_in),
      reaches the MXU in int8 blocks, without the windowed kernel's
      padding, band copies, int32 widening and tap loads. (A fused body
-     still windows it.) Fallback-only steps that fit in no segment run at
-     the XLA level between kernels (zero extra launches).
+     still windows it.) A stem step (`compiled._is_stem`: a windowed conv
+     that alone reads a narrow graph input) is always a tiled segment of
+     its own, on the stem kernel (`kernels.stem_conv`): the program takes
+     that input as row groups, (H / s, s·W·C) a frame (`input_layout`),
+     which the pallas runner ships from the host as a free reshape of the
+     C-contiguous batch (`ship_inputs`), and the kernel contracts them
+     against the banded weights `host_weights` builds once. Fallback-only
+     steps that fit in no segment run at the XLA level between kernels
+     (zero extra launches).
   3. **Kernel-count target**: the paper's shape is one program per core, so
      the planner aims at `num_cores` kernels (`max_kernels` override in
      `BackendOptions`): when a pack at a reduced budget emits more, the
@@ -65,6 +72,7 @@ import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 
 from . import compiled as C
@@ -75,6 +83,8 @@ from ..kernels.conv2d_im2col import (conv2d_vmem_bytes, conv_accumulate,
 from ..kernels.gemm_int8 import (dot_i32_exact, gemm_vmem_bytes,
                                  requant_epilogue)
 from ..kernels.ref import _as_channel_mult, round_half_even_div
+from ..kernels.stem_conv import (stem_bands, stem_input_shape,
+                                 stem_vmem_bytes)
 
 _ITEM_BYTES = {"int8": 1, "uint8": 1, "int16": 2, "int32": 4,
                "f32": 4, "bf16": 2}
@@ -182,7 +192,8 @@ def _pack(prog: C.CompiledProgram, plan, budget: int, dual: bool
                 flush()
                 segments.append(Segment("outside", (step,)))
             continue
-        if sb > budget:              # oversized gemm/conv: tiled kernel
+        if sb > budget or step.mode == "stem":
+            # an oversized gemm/conv, or a stem, runs on its own kernel
             flush()
             segments.append(Segment("tiled", (step,)))
             continue
@@ -407,6 +418,8 @@ def segment_vmem_bytes(prog: C.CompiledProgram, seg: Segment) -> int:
         bm, bn, bk = step.blocks
         M, K, N, _ = step.gemm
         return gemm_vmem_bytes(M, K, N, bm=bm, bn=bn, bk=bk, requant=requant)
+    if step.mode == "stem":
+        return stem_vmem_bytes(C.stem_geometry_of(step.batch), requant)
     rows_t, bn = step.blocks
     return conv2d_vmem_bytes(a["H"], a["W"], a["C_in"], a["C_out"],
                              kh=a["kh"], kw=a["kw"], stride=a["stride"],
@@ -420,8 +433,9 @@ def segment_name(index: int, seg: Segment) -> str:
     fused segment's kernel carries it as its `pallas_call` name into the
     compiled program and the profiler's trace. A tiled segment's kernel is
     named after its shape instead (`conv2d_kernel_name`,
-    `gemm_kernel_name`): segments of one shape share one traced and
-    lowered kernel, which a name per segment would multiply."""
+    `gemm_kernel_name`, `stem_kernel_name`): segments of one shape share
+    one traced and lowered kernel, which a name per segment would
+    multiply."""
     op = re.sub(r"[^0-9A-Za-z_]", "_", seg.steps[0].batch.name)
     return f"seg{index:03d}_{op}"
 
@@ -468,6 +482,40 @@ def _run_fused(prog: C.CompiledProgram, seg: Segment, vals: list,
         vals[i] = r
 
 
+def _stem_steps(prog: C.CompiledProgram) -> list:
+    return [s for s in C._pallas_plan(prog) if s.mode == "stem"]
+
+
+def input_layout(prog: C.CompiledProgram) -> dict:
+    """The per-frame shape the program takes each graph input in. An input
+    a stem step reads comes as row groups (`stem_input_shape`: (H / s,
+    s·W·C), a reshape of the C-contiguous (H, W, C) frame, which the host
+    copies to the device without padding its channels out to 128 lanes);
+    every other input keeps its graph shape. Cached on the program."""
+    if "input_layout" not in prog._pallas_cache:
+        layout = {name: tuple(prog.buffers[i][1])
+                  for name, i in prog.input_idx.items()}
+        for step in _stem_steps(prog):
+            a = step.batch.attrs
+            layout[prog.buffers[step.batch.in_idx[0]][0]] = \
+                stem_input_shape(a["H"], a["W"], a["C_in"], a["stride"])
+        prog._pallas_cache["input_layout"] = layout
+    return prog._pallas_cache["input_layout"]
+
+
+def ship_inputs(prog: C.CompiledProgram, inputs: dict) -> dict:
+    """Host arrays, with or without a leading batch axis, reshaped to the
+    layout the program takes (`input_layout`): free for C-contiguous
+    arrays."""
+    layout = input_layout(prog)
+    out = {}
+    for name, v in inputs.items():
+        v = np.asarray(v)
+        lead = v.ndim - len(prog.buffers[prog.input_idx[name]][1])
+        out[name] = v.reshape(v.shape[:lead] + layout[name])
+    return out
+
+
 def megakernel_fn(prog: C.CompiledProgram, *, interpret: bool = False,
                   budget: int | None = None,
                   max_kernels: int | None = None):
@@ -475,16 +523,20 @@ def megakernel_fn(prog: C.CompiledProgram, *, interpret: bool = False,
     the segment plan (cached per (interpret, budget, max_kernels) on the
     program). `weights` maps weight buffer idx -> array
     (`device_weights`); taking them as arguments keeps them out of the
-    executable instead of baking them in as constants."""
+    executable instead of baking them in as constants. Each input is
+    taken in its `input_layout` shape; one in its graph shape is reshaped
+    to it inside the program (correct, but a copy on the device that a
+    caller shipping the layout avoids)."""
     key = ("mega_fn", bool(interpret), budget, max_kernels)
     if key not in prog._pallas_cache:
         segments = plan_segments(prog, budget=budget,
                                  max_kernels=max_kernels)
+        layout = input_layout(prog)
 
         def fn(weights: dict, inputs: dict):
             vals: list = [None] * len(prog.buffers)
             for name, i in prog.input_idx.items():
-                vals[i] = inputs[name]
+                vals[i] = inputs[name].reshape(layout[name])
             for index, seg in enumerate(segments):
                 name = segment_name(index, seg)
                 with jax.named_scope(name):
@@ -502,11 +554,24 @@ def megakernel_fn(prog: C.CompiledProgram, *, interpret: bool = False,
     return prog._pallas_cache[key]
 
 
+def host_weights(prog: C.CompiledProgram) -> dict:
+    """The weight operands of the program's kernels, by weight buffer idx:
+    each weight as lowered, but a stem's as its band expansion
+    (`kernels.stem_conv.stem_bands`), built here once."""
+    weights = dict(prog.weights)
+    for step in _stem_steps(prog):
+        b = step.batch
+        weights[b.w_idx] = stem_bands(weights[b.w_idx],
+                                      C.stem_geometry_of(b))
+    return weights
+
+
 def device_weights(prog: C.CompiledProgram) -> dict:
-    """The program's weights as device arrays, put once per program."""
+    """The program's weight operands (`host_weights`) as device arrays,
+    put once per program."""
     if "weights" not in prog._pallas_cache:
         prog._pallas_cache["weights"] = {
-            i: jnp.asarray(w) for i, w in prog.weights.items()}
+            i: jnp.asarray(w) for i, w in host_weights(prog).items()}
     return prog._pallas_cache["weights"]
 
 
@@ -554,10 +619,11 @@ def megakernel_batched(prog: C.CompiledProgram, *,
 
 def run_megakernel(prog: C.CompiledProgram, inputs: dict,
                    interpret: bool | None = None) -> dict:
-    """Convenience wrapper: one unbatched sample; numpy in, numpy out."""
-    import numpy as np
+    """Convenience wrapper: one unbatched sample; numpy in (shipped in the
+    program's `input_layout`), numpy out."""
     fn = jit_megakernel_single(prog, interpret=interpret)
-    out = fn({k: jnp.asarray(v) for k, v in inputs.items()})
+    out = fn({k: jnp.asarray(v) for k, v in
+              ship_inputs(prog, inputs).items()})
     return {k: np.asarray(v) for k, v in out.items()}
 
 

@@ -20,6 +20,11 @@ values; each window narrows to int8 right before its MXU dot. The megakernel's f
 segments window their scratchpad-resident values through the same
 helpers (`conv_accumulate`), so both paths share one tap order and one
 exact int8 contraction.
+
+A network's stem, a conv that alone reads a frame of few channels, does
+not come here: with 3 channels every tap would be a dot with K = 3 over a
+frame padded to 128 lanes. The plan gives it the stem kernel
+(`kernels.stem_conv`), which reads the frame as lane-dense row groups.
 """
 
 from __future__ import annotations

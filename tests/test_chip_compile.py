@@ -27,6 +27,8 @@ from repro.core import megakernel as MK
 from repro.hw import derive_conv_blocks, scaled_paper_machine
 from repro.kernels.conv2d_im2col import conv2d_int8_pallas
 from repro.kernels.gemm_int8 import gemm_int8_pallas
+from repro.kernels.stem_conv import (stem_conv_pallas, stem_geometry,
+                                     stem_input_shape, stem_kernel_name)
 
 HW = scaled_paper_machine(16)
 
@@ -54,19 +56,32 @@ def _n_kernels(compiled) -> int:
     return compiled.as_text().count("custom_call_target=\"tpu_custom_call\"")
 
 
+def _operands(prog, sharding, batch=()):
+    """Specs of the program's weight operands (a stem's band expansion
+    among them) and of its inputs in the layout the program declares,
+    with a leading `batch` shape."""
+    weights = {i: _spec(w.shape, w.dtype, sharding)
+               for i, w in MK.host_weights(prog).items()}
+    inputs = {name: _spec(batch + shape, jnp.int8, sharding)
+              for name, shape in MK.input_layout(prog).items()}
+    return weights, inputs
+
+
 def _compile_served(prog, frame_shape, n_kernels, one_chip):
     """The served program batched over 4 frames, as the Server runs it,
-    compiled for v5e; no fused segment holds more than the scratchpad,
-    and each kernel-emitting segment is one Mosaic kernel (`n_kernels`)."""
+    compiled for v5e: it takes the frame (`frame_shape`) in the layout the
+    program declares, row groups for the stem; no fused segment holds
+    more than the scratchpad, and each kernel-emitting segment is one
+    Mosaic kernel (`n_kernels`)."""
     segments = MK.plan_segments(prog)
     cap = prog.hw.scratchpad_bytes
     assert all(MK.segment_footprint(prog, s) <= cap
                for s in segments if s.kind == "fused")
+    H, W, C = frame_shape
+    assert MK.input_layout(prog) == {"input": (H // 2, 2 * W * C)}
     fn = jax.vmap(MK.megakernel_fn(prog, interpret=False),
                   in_axes=(None, 0))
-    weights = {i: _spec(w.shape, w.dtype, one_chip)
-               for i, w in prog.weights.items()}
-    x = {"input": _spec((4,) + frame_shape, jnp.int8, one_chip)}
+    weights, x = _operands(prog, one_chip, (4,))
     compiled = jax.jit(fn).lower(weights, x).compile()
     assert _n_kernels(compiled) == sum(s.emits_call for s in segments) \
         == n_kernels
@@ -161,9 +176,8 @@ def test_resnet50_pointwise_conv_gemm_route_compiles_for_v5e(name, one_chip):
     M = ((shape[0] - 1) // stride + 1) ** 2
     assert seg.steps[0].gemm == (M, shape[2], c_out, stride)
     fn = jax.vmap(MK.megakernel_fn(prog, interpret=False), in_axes=(None, 0))
-    weights = {i: _spec(w.shape, w.dtype, one_chip)
-               for i, w in prog.weights.items()}
-    x = {"input": _spec((4,) + shape, jnp.int8, one_chip)}
+    weights, x = _operands(prog, one_chip, (4,))
+    assert x["input"].shape == (4,) + shape
     compiled = jax.jit(fn).lower(weights, x).compile()
     assert _n_kernels(compiled) == 1
     assert gemm_kernel_name(M, shape[2], c_out) in compiled.as_text()
@@ -198,8 +212,28 @@ def test_fused_segments_compile_for_v5e(preset, one_chip):
                          sched, hw=hw)
     segments = MK.plan_segments(prog)
     assert any(s.kind == "fused" for s in segments)
-    weights = {i: _spec(w.shape, w.dtype, one_chip)
-               for i, w in prog.weights.items()}
+    weights, x = _operands(prog, one_chip)
     compiled = jax.jit(MK.megakernel_fn(prog, interpret=False)).lower(
-        weights, {"input": _spec(shape, jnp.int8, one_chip)}).compile()
+        weights, x).compile()
     assert _n_kernels(compiled) == sum(s.emits_call for s in segments)
+
+
+@pytest.mark.parametrize("frame,k,p,n", [((640, 640, 3), 6, 2, 32),
+                                         ((224, 224, 3), 7, 3, 64)])
+def test_stem_kernel_compiles_for_v5e(frame, k, p, n, one_chip):
+    """The stem kernel at YOLOv5s's 6×6/2 (640×640×3 → 32) and
+    ResNet-50's 7×7/2 (224×224×3 → 64), with its fused requant, batched
+    over 4 frames as the served program runs it: row groups in (a minor
+    dim of 3840 or 1344 lanes, the latter a partial 128-lane block), the
+    banded weights, one Mosaic kernel under the stem's name."""
+    H, W, C = frame
+    geom = stem_geometry(H, W, C, n, kh=k, kw=k, stride=2, padding=p)
+    fn = jax.vmap(functools.partial(stem_conv_pallas, geom=geom),
+                  in_axes=(0, None, None))
+    compiled = jax.jit(fn).lower(
+        _spec((4,) + stem_input_shape(H, W, C, 2), jnp.int8, one_chip),
+        _spec((geom.k_total, len(geom.qs) * geom.n_blk), jnp.int8,
+              one_chip),
+        _spec((n,), jnp.float32, one_chip)).compile()
+    assert _n_kernels(compiled) == 1
+    assert stem_kernel_name(geom) in compiled.as_text()

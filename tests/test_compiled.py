@@ -228,33 +228,36 @@ def test_supports_graph():
 def test_pallas_plan_fuses_requant_chains():
     """Every conv -> requant chain in the CNN presets fuses into the kernel
     epilogue; fused requant batches become skip steps; fallback kinds go to
-    the JAX lowering; blocks come from the program's scratchpad model."""
+    the JAX lowering; blocks come from the program's scratchpad model. The
+    conv that reads the 3-channel input is the stem step."""
     g, shape, params, prog, _ = _compiled("small_cnn")
     plan = C._pallas_plan(prog)
     modes = {s.batch.name: s.mode for s in plan}
-    assert modes["conv1"] == "conv2d" and modes["conv1.rq"] == "skip"
+    assert modes["conv1"] == "stem" and modes["conv1.rq"] == "skip"
     assert modes["conv2"] == "conv2d" and modes["conv2.rq"] == "skip"
     assert modes["pool1"] == "jax" and modes["gap"] == "jax"
     assert modes["fc"] == "gemm"
     for s in plan:
-        if s.mode == "conv2d":
+        if s.mode in ("conv2d", "stem"):
             assert s.mult is not None          # fused epilogue multiplier
-            assert len(s.blocks) == 2
+            assert len(s.blocks) == (2 if s.mode == "conv2d" else 0)
         if s.mode == "gemm":
             assert len(s.blocks) == 3
     # a pointwise conv is planned as a gemm step carrying the conv's batch,
-    # its requant fused the same way; every other conv stays windowed
+    # its requant fused the same way; the stem reads the input as row
+    # groups; every other conv stays windowed
     _, _, _, prog, _ = _compiled("resnet50")
     plan = C._pallas_plan(prog)
     modes = {s.batch.name: s.mode for s in plan}
     assert modes["s0.b0.c1"] == "gemm" and modes["s0.b0.c1.rq"] == "skip"
     assert modes["s1.b0.ds"] == "gemm" and modes["s1.b0.ds.rq"] == "skip"
-    assert modes["s0.b0.c2"] == "conv2d" and modes["stem"] == "conv2d"
+    assert modes["s0.b0.c2"] == "conv2d" and modes["stem"] == "stem"
     for s in plan:
         a = s.batch.attrs
         if s.batch.kind == "conv2d":
             pointwise = a["kh"] == a["kw"] == 1 and a["padding"] == 0
-            assert s.mode == ("gemm" if pointwise else "conv2d")
+            assert s.mode == ("gemm" if pointwise else
+                              "stem" if s.batch.name == "stem" else "conv2d")
             assert s.mult is not None
         if s.mode == "gemm" and s.batch.kind == "conv2d":
             oh, ow, n = prog.buffers[s.batch.out_idx][1]
@@ -269,9 +272,10 @@ def test_pallas_plan_fuses_requant_chains():
 def test_plan_counts_full_width_resnet50():
     """Full-width ResNet-50 (224², the benchmark's graph) on the 16-core
     paper machine: its 36 pointwise convs (c1 and c3 of 16 bottlenecks,
-    4 projections) are planned as GEMMs, the 17 3×3 and 7×7 convs stay
-    windowed, and the segmentation is untouched: 54 tiled (53 convs and
-    the fc), 67 at the XLA level."""
+    4 projections) are planned as GEMMs, the 7×7 stem reads the frame as
+    row groups (`dense_stem`), the 16 3×3 convs stay windowed, and the
+    segmentation is untouched: 54 tiled (53 convs and the fc), 67 at the
+    XLA level."""
     from repro.core import megakernel as MK
     g = cnn.resnet50()
     hw = scaled_paper_machine(16)
@@ -280,10 +284,11 @@ def test_plan_counts_full_width_resnet50():
                          sched, hw=hw)
     counts = C.plan_counts(prog)
     assert counts["pointwise_gemm"] == 36
-    assert counts["gemm"] == 37 and counts["conv2d"] == 17
+    assert counts["gemm"] == 37 and counts["conv2d"] == 16
+    assert counts["stem"] == counts["dense_stem"] == 1
     windowed = [s.batch.attrs for s in C._pallas_plan(prog)
                 if s.mode == "conv2d"]
-    assert sorted({a["kh"] for a in windowed}) == [3, 7]
+    assert sorted({a["kh"] for a in windowed}) == [3]
     segments = MK.plan_segments(prog)
     kinds = [s.kind for s in segments]
     assert kinds.count("tiled") == 54 and kinds.count("outside") == 67
@@ -296,9 +301,9 @@ def test_plan_counts_full_width_yolov5s():
     """Full-width YOLOv5s backbone + SPPF as published (640², the
     benchmark's graph) on the 16-core paper machine: the 21 pointwise
     convs (C3 branches and fuses, SPPF's 512→256 and 1024→512) are planned
-    as GEMMs, the 6×6 stem and the 11 3×3 convs stay windowed; concats,
-    SPPF's pools, shortcut adds and ReLUs are 48 XLA-level steps; 33 tiled
-    segments, none fused."""
+    as GEMMs, the 6×6 stem reads the frame as row groups (`dense_stem`),
+    the 11 3×3 convs stay windowed; concats, SPPF's pools, shortcut adds
+    and ReLUs are 48 XLA-level steps; 33 tiled segments, none fused."""
     from repro.core import megakernel as MK
     g = cnn.yolov5s()
     hw = scaled_paper_machine(16)
@@ -306,11 +311,12 @@ def test_plan_counts_full_width_yolov5s():
     prog = lower_program(g, init_params(g, seed=0), subtasks, mapping,
                          sched, hw=hw)
     counts = C.plan_counts(prog)
-    assert counts["conv2d"] == 12 and counts["pointwise_gemm"] == 21
+    assert counts["conv2d"] == 11 and counts["pointwise_gemm"] == 21
     assert counts["gemm"] == 21 and counts["jax"] == 48
+    assert counts["stem"] == counts["dense_stem"] == 1
     windowed = [s.batch.attrs for s in C._pallas_plan(prog)
                 if s.mode == "conv2d"]
-    assert sorted({a["kh"] for a in windowed}) == [3, 6]
+    assert sorted({a["kh"] for a in windowed}) == [3]
     gemms = sorted({s.gemm for s in C._pallas_plan(prog) if s.mode == "gemm"})
     assert {m for m, *_ in gemms} == {25600, 6400, 1600, 400}
     assert max(k for _, k, *_ in gemms) == 1024
@@ -321,7 +327,8 @@ def test_plan_counts_full_width_yolov5s():
 
 def test_pallas_no_fusion_when_acc_is_graph_output():
     """An int32 accumulator that is itself a graph output must NOT be
-    requant-fused away — and the backend stays bit-exact."""
+    requant-fused away — and the backend stays bit-exact (the conv reads
+    the 3-channel input, so it is the stem, with an int32 output)."""
     from repro.core.graph import Graph, conv2d, requant
     g = Graph("acc_out")
     g.add_tensor("input", (12, 12, 3), "int8", is_input=True)
@@ -336,7 +343,7 @@ def test_pallas_no_fusion_when_acc_is_graph_output():
     prog = lower_program(g, params, subtasks, mapping, sched, hw=hw)
     plan = C._pallas_plan(prog)
     modes = {s.batch.name: s.mode for s in plan}
-    assert modes["c1"] == "conv2d" and modes["c1.rq"] == "jax"
+    assert modes["c1"] == "stem" and modes["c1.rq"] == "jax"
     assert all(s.mult is None for s in plan)
     x = np.random.default_rng(8).integers(-64, 64,
                                           size=(12, 12, 3)).astype(np.int8)

@@ -103,6 +103,44 @@ def test_conv2d_fused_requant(rng, per_channel):
     assert np.array_equal(np.asarray(out), np.asarray(expect))
 
 
+@pytest.mark.parametrize("batch", [1, 4])
+@pytest.mark.parametrize("requant", [True, False])
+@pytest.mark.parametrize("H,W,N,k,stride,pad", [
+    (16, 20, 32, 6, 2, 2),    # YOLOv5s's 6×6/2, one column block
+    (24, 48, 32, 6, 2, 2),    # two column blocks, the second partial
+    (14, 14, 64, 7, 2, 3),    # ResNet-50's 7×7/2, rows of 84 lanes
+    (12, 44, 64, 7, 2, 3),    # rows of 264 lanes: a partial 128-lane row
+    (10, 12, 16, 3, 1, 1),    # stride 1: row groups of one frame row
+])
+def test_stem_kernel_bit_exact(rng, H, W, N, k, stride, pad, requant, batch):
+    """The stem kernel over row groups of 3-channel frames == the
+    reference conv (and its requant) bit for bit, with a fused requant and
+    with an int32 output, for one frame and vmapped over 4."""
+    import jax
+    import jax.numpy as jnp
+    from repro.kernels import stem_conv as S
+    C = 3
+    x = rng.integers(-128, 128, (batch, H, W, C)).astype(np.int8)
+    w = rng.integers(-128, 128, (k * k * C, N)).astype(np.int8)
+    mult = (rng.random(N) * 0.002 + 1e-5).astype(np.float32) \
+        if requant else None
+    geom = S.stem_geometry(H, W, C, N, kh=k, kw=k, stride=stride,
+                           padding=pad)
+    assert geom.lanes == stride * W * C
+    xs = x.reshape((batch,) + S.stem_input_shape(H, W, C, stride))
+    fn = jax.vmap(lambda v: S.stem_conv_pallas(
+        v, jnp.asarray(S.stem_bands(w, geom)),
+        None if mult is None else jnp.asarray(mult), geom=geom,
+        interpret=True))
+    out = np.asarray(fn(jnp.asarray(xs)))
+    assert out.dtype == (np.int8 if requant else np.int32)
+    for b in range(batch):
+        expect = ref.conv2d_int8(x[b], w, stride=stride, padding=pad,
+                                 requant_mult=mult)
+        assert out[b].shape == expect.shape
+        assert np.array_equal(out[b], np.asarray(expect))
+
+
 def test_spm_derived_blocks_fit_scratchpad():
     """hw.derive_*_blocks always return shapes whose working set (with
     double buffering on dual-ported machines) fits the scratchpad."""

@@ -221,6 +221,116 @@ def test_pointwise_conv_runs_on_gemm_kernel_bit_exact(
     assert np.array_equal(ref[t], np.asarray(out[t]))
 
 
+def _stem_program(frame, k, stride, pad, c_out, requant=True,
+                  second_consumer=False):
+    """One conv over the graph input, its accumulator requantized (fused
+    into the kernel) or itself the graph output; with `second_consumer`
+    a max pool reads the input too."""
+    from repro.core.graph import Graph, conv2d, pool2d, requant as rq
+    g = Graph("stem")
+    g.add_tensor("input", frame, "int8", is_input=True)
+    y = conv2d(g, "c", "input", c_out, k, stride=stride, padding=pad)
+    g.mark_output(rq(g, "c.rq", y) if requant else y)
+    if second_consumer:
+        g.mark_output(pool2d(g, "pool", "maxpool", "input", 2, 2))
+    g.validate()
+    hw = scaled_paper_machine(2)
+    rep, sched, subtasks, mapping = analyze(g, hw, num_cores=2)
+    params = init_params(g, seed=13)
+    return g, lower_program(g, params, subtasks, mapping, sched, hw=hw)
+
+
+@pytest.mark.parametrize("requant", [True, False])
+@pytest.mark.parametrize("frame,k,stride,pad,c_out", [
+    ((16, 20, 3), 6, 2, 2, 32),   # YOLOv5s's stem geometry
+    ((12, 44, 3), 7, 2, 3, 64),   # ResNet-50's; rows of 264 lanes
+])
+def test_stem_conv_bit_exact_per_step_and_megakernel(frame, k, stride, pad,
+                                                     c_out, requant):
+    """A conv that alone reads a 3-channel graph input is planned as the
+    stem: the program takes the input as row groups, the kernel carries
+    the stem's name, and the per-step path (`run_kernel_step`), the
+    megakernel for one frame and for 4, given the row groups or the
+    (H, W, C) frames, all equal `run_numpy` bit for bit."""
+    import jax.numpy as jnp
+    from repro.core import compiled as C
+    from repro.kernels.stem_conv import stem_input_shape, stem_kernel_name
+    g, prog = _stem_program(frame, k, stride, pad, c_out, requant)
+    (step,) = [s for s in C._pallas_plan(prog) if s.mode != "skip"]
+    assert step.mode == "stem" and (step.mult is not None) == requant
+    assert C.plan_counts(prog)["dense_stem"] == 1
+    H, W, Cn = frame
+    rows = stem_input_shape(H, W, Cn, stride)
+    assert MK.input_layout(prog) == {"input": rows}
+    (t,) = g.outputs
+    xs = np.random.default_rng(sum(frame) + k).integers(
+        -128, 128, size=(4,) + frame).astype(np.int8)
+    want = [C.run_numpy(prog, {"input": x})[t] for x in xs]
+
+    vals = [None] * len(prog.buffers)
+    vals[prog.input_idx["input"]] = jnp.asarray(xs[0].reshape(rows))
+    C.run_kernel_step(prog, step, vals, MK.device_weights(prog),
+                      interpret=True)
+    got = np.asarray(vals[step.out_idx])
+    assert got.dtype == want[0].dtype and np.array_equal(got, want[0])
+
+    fn = MK.megakernel_single(prog, interpret=True)
+    assert MK.pallas_call_names(fn, {"input": jnp.asarray(xs[0])}) == [
+        stem_kernel_name(C.stem_geometry_of(step.batch))]
+    assert np.array_equal(MK.run_megakernel(prog, {"input": xs[0]},
+                                            interpret=True)[t], want[0])
+    batched = MK.megakernel_batched(prog, interpret=True)
+    for x in (xs, xs.reshape((4,) + rows)):
+        out = np.asarray(batched({"input": jnp.asarray(x)})[t])
+        for b in range(4):
+            assert np.array_equal(out[b], want[b])
+
+
+@pytest.mark.parametrize("case", ["two_consumers", "wide_input"])
+def test_a_shared_or_wide_input_keeps_the_windowed_kernel(case):
+    """The stem path needs an input that only the conv reads, narrow enough
+    that a window row fits 128 lanes: an input that a pool reads too, or
+    one of 64 channels (64·3 lanes), stays on the windowed kernel in its
+    (H, W, C) shape, bit-exact."""
+    import jax.numpy as jnp
+    from repro.core import compiled as C
+    frame = (16, 16, 64) if case == "wide_input" else (16, 16, 3)
+    g, prog = _stem_program(frame, 3, 2, 1, 32,
+                            second_consumer=case == "two_consumers")
+    conv = [s for s in C._pallas_plan(prog) if s.batch.name == "c"]
+    assert conv[0].mode == "conv2d"
+    assert C.plan_counts(prog)["dense_stem"] == 0
+    assert MK.input_layout(prog) == {"input": frame}
+    x = np.random.default_rng(4).integers(-128, 128,
+                                          size=frame).astype(np.int8)
+    want = C.run_numpy(prog, {"input": x})
+    out = MK.megakernel_single(prog, interpret=True)({"input": jnp.asarray(x)})
+    for t in g.outputs:
+        assert np.array_equal(np.asarray(out[t]), want[t])
+
+
+@pytest.mark.parametrize("preset", ["resnet50", "yolov5s", "yolov5s_v6"])
+def test_reduced_networks_have_one_dense_stem(preset):
+    """Each reduced CNN's first conv reads the 3-channel frame alone: one
+    stem step, the frame shipped as row groups of the stem's stride."""
+    from repro.core import compiled as C
+    if preset == "yolov5s_v6":
+        g = cnn.yolov5s(h=64, w=64, width=0.25)
+        hw = scaled_paper_machine(4)
+        rep, sched, subtasks, mapping = analyze(g, hw, num_cores=4)
+        prog = lower_program(g, init_params(g, seed=1), subtasks, mapping,
+                             sched, hw=hw)
+    else:
+        g, _, _, prog = _compiled(preset)
+    assert C.plan_counts(prog)["dense_stem"] == 1
+    (stem,) = [s for s in C._pallas_plan(prog) if s.mode == "stem"]
+    a = stem.batch.attrs
+    H, W, Cn = g.tensors["input"].shape
+    assert stem.batch.in_idx[0] == prog.input_idx["input"]
+    assert MK.input_layout(prog) == {
+        "input": (H // a["stride"], a["stride"] * W * Cn)}
+
+
 def test_segment_cores_round_robin():
     segments = [s for s in MK.plan_segments(_compiled("resnet50")[3])
                 if s.emits_call]

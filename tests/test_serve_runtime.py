@@ -432,3 +432,35 @@ def test_multi_model_engine_admit_model():
     # nothing was submitted: every job ran for ordering, none was checked
     assert srv.metrics["idle_jobs"] == srv.metrics["jobs"] > 0
     assert srv.monitor.checks == {}
+
+
+@pytest.mark.parametrize("path", ["staged", "whole", "short"])
+def test_pallas_server_serves_the_stem_from_row_groups(path):
+    """A reduced ResNet-50 (a 7×7/2 stem over 32×32×3 frames, shipped as
+    (16, 192) row groups) served on the pallas backend equals the numpy
+    backend bit for bit: with the next batch staged during each call, with
+    the runner called whole (wrapped without `stage`, as a tracing wrapper
+    calls it), and for a short batch padded to the slots."""
+    n = {"staged": 6, "whole": 4, "short": 3}[path]
+    frames = [_frame(k) for k in range(n)]
+    outs = {}
+    for backend in ("pallas", "numpy"):
+        srv = Server(HW, backend=backend, num_cores=4)
+        srv.register("r", cnn.resnet50(h=32, w=32, width=0.25,
+                                       blocks=(1, 1, 1, 1), num_classes=16),
+                     period_s=0.2, slots=2 if path == "staged" else 4)
+        st = srv._nets["r"]
+        if path == "whole":
+            inner = st.runner
+            st.runner = lambda batch: inner(batch)
+        tickets = [srv.submit("r", f) for f in frames]
+        while not all(t.terminal for t in tickets):
+            srv.step()
+        outs[backend] = [t.result().output for t in tickets]
+        if backend == "pallas":
+            m = srv.metrics
+            assert m["staged_calls"] == (2 if path == "staged" else 0)
+            assert m["slots_padded"] == (1 if path == "short" else 0)
+    for got, want in zip(outs["pallas"], outs["numpy"]):
+        for k in want:
+            np.testing.assert_array_equal(got[k], want[k])

@@ -323,3 +323,28 @@ def test_the_pallas_runner_stages_and_serves_bit_exact():
     for got, want in zip(outs["pallas"], outs["numpy"]):
         for k in want:
             np.testing.assert_array_equal(got[k], want[k])
+
+
+def test_the_pallas_runner_stages_the_stems_row_groups():
+    """The pallas runner's `stage` puts the frames on the device in the
+    layout the program declares: small_cnn's first conv (3×3, stride 1)
+    reads the 3-channel input alone, so 4 frames of 32×32×3 cross as
+    (4, 32, 96) row groups. A launch of the staged arrays and a call of
+    the runner with the numpy batch give the numpy backend's outputs."""
+    import jax
+    frames = [_frame(k) for k in range(4)]
+    batch = {"input": np.stack(frames)}
+    outs = {}
+    for backend in ("pallas", "numpy"):
+        srv = Server(HW, backend=backend, num_cores=4)
+        srv.register("a", cnn.small_cnn(), period_s=1 / 50, slots=4)
+        outs[backend] = srv._nets["a"].runner
+    runner = outs["pallas"]
+    staged = runner.stage(batch)
+    assert isinstance(staged["input"], jax.Array)
+    assert staged["input"].shape == (4, 32, 96)
+    assert staged["input"].dtype == np.int8
+    want = outs["numpy"](batch)
+    for got in (runner.launch(staged)(), runner(batch)):
+        for k in want:
+            np.testing.assert_array_equal(got[k], want[k])

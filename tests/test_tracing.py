@@ -249,12 +249,14 @@ def test_a_collection_inside_a_step_is_a_child_of_the_step(rec):
 def test_every_megakernel_pallas_call_has_a_stable_name():
     """A fused kernel carries its segment's name (unique in the program); a
     tiled kernel its shape, shared by the segments of one shape: a GEMM's
-    (M, K, N), pointwise convs included, or a windowed conv's geometry. A
-    64 KiB scratchpad splits the reduced ResNet-50 into fused, tiled and
-    XLA-level segments."""
+    (M, K, N), pointwise convs included, a windowed conv's geometry, or the
+    stem's conv and row-group shape. A 64 KiB scratchpad splits the
+    reduced ResNet-50 into fused, tiled and XLA-level segments."""
     from repro.core import analyze, init_params, lower_program
+    from repro.core import compiled as C
     from repro.kernels.conv2d_im2col import conv2d_kernel_name
     from repro.kernels.gemm_int8 import gemm_kernel_name
+    from repro.kernels.stem_conv import stem_kernel_name
 
     def expected(prog, index, seg):
         if seg.kind == "fused":
@@ -264,8 +266,10 @@ def test_every_megakernel_pallas_call_has_a_stable_name():
         a = b.attrs
         if step.mode == "gemm":
             return gemm_kernel_name(step.gemm.M, step.gemm.K, step.gemm.N)
-        H, W, C = prog.buffers[b.in_idx[0]][1]
-        return conv2d_kernel_name(H, W, C, prog.buffers[b.out_idx][1][-1],
+        if step.mode == "stem":
+            return stem_kernel_name(C.stem_geometry_of(b))
+        H, W, Cn = prog.buffers[b.in_idx[0]][1]
+        return conv2d_kernel_name(H, W, Cn, prog.buffers[b.out_idx][1][-1],
                                   kh=a["kh"], kw=a["kw"],
                                   stride=a["stride"], padding=a["padding"])
 
@@ -286,7 +290,8 @@ def test_every_megakernel_pallas_call_has_a_stable_name():
                  if s.kind == "fused"]
         assert len(set(fused)) == len(fused)
     assert {s.kind for s in segments} == {"fused", "tiled", "outside"}
-    assert names[0] == "seg000_stem"
+    assert names[0] == "stem7x7s2p3_16x192_16"
+    assert not [n for n in names if n.startswith("conv7x7")]
     assert "conv3x3s2p1_4x4x64_64" in names
     assert "gemm_4x128x256" in names           # the stride-2 projection
     assert not [n for n in names if n.startswith("conv1x1")]

@@ -5,8 +5,9 @@
 
 For each seed: the cell's weights drawn on the chip as a run draws them,
 its frames, and a window's worth of frame indices (`--frames`); then the
-run's comparison with the plain reference put in the program's place and
-computed one precision step down (int4 for the configurations' int8).
+run's comparison, over every output of each sampled frame, with the plain
+reference put in the program's place and computed one precision step
+down (int4 for the configurations' int8).
 Prints one JSON line per seed with the numbers the comparison reads; the
 control must fail it on every seed. Refuses any platform but "tpu".
 """

@@ -2,17 +2,18 @@
 
 `frame_ops` counts the matrix-unit work: 2 operations (a multiply and an
 add) per multiply-accumulate of every conv and fully-connected layer.
-Requant, ReLU, add, pooling and concat run on the vector unit and are
-left out, so a share of the int8 peak built on this count never credits
-them as matrix work.
+Requant, ReLU, SiLU, add, pooling, upsample and concat run on the vector
+unit or only move data; they count 0, so a share of the int8 peak built
+on this count never credits them as matrix work.
 
 `call_floor_s` is the least time one call of the program can take on a
 chip, whatever implements it: the larger of its matrix work over the int8
 peak and of the bytes it cannot avoid moving over the HBM bandwidth. The
 bytes it cannot avoid are the weights, read once per call whatever the
-batch, and each frame's input and output. Intermediate activations are
-not counted: a program that keeps them on chip moves none of them, so a
-count that included them could put a sound program above its roofline.
+batch, and each frame's input and every one of its outputs. Intermediate
+activations are not counted: a program that keeps them on chip moves
+none of them, so a count that included them could put a sound program
+above its roofline.
 """
 
 from __future__ import annotations
@@ -44,10 +45,10 @@ def weight_bytes(net: Net) -> int:
 
 
 def frame_io_bytes(net: Net) -> int:
-    """One frame's input and output as the program reads and writes them."""
-    return (math.prod(net.shapes["input"])
-            + math.prod(net.shapes[net.output])
-            * DTYPE_BYTES[net.dtypes[net.output]])
+    """One frame's input and outputs as the program reads and writes them."""
+    return math.prod(net.shapes["input"]) + sum(
+        math.prod(net.shapes[t]) * DTYPE_BYTES[net.dtypes[t]]
+        for t in net.outputs)
 
 
 def call_floor_s(net: Net, frames: int, peaks: dict) -> float:

@@ -163,8 +163,11 @@ def _import(path: str):
 def build_graph(config: dict, net: reference.Net):
     """The program's graph of the configuration, checked against the
     reference: the same weight names and shapes, the same multipliers,
-    and every tensor the reference computes (`<layer>.out`) present in
-    the graph with the same shape, so a program whose layers change shape
+    as many outputs as the reference has, the graph's i-th output of the
+    shape of the reference's i-th (`Net.outputs`: the graph may name an
+    output after an activation the reference folds into its layer), and
+    every tensor the reference computes (`<layer>.out`) present in the
+    graph with the same shape, so a program whose layers change shape
     (a pool's padding, a stride) is refused rather than measured."""
     kwargs = {k: tuple(v) if isinstance(v, list) else v
               for k, v in config["kwargs"].items()}
@@ -174,9 +177,10 @@ def build_graph(config: dict, net: reference.Net):
     if want_w != net.weights or want_m != set(net.mults):
         raise BenchError(f"{config['name']}: the program's graph and the "
                          f"reference name different parameters")
-    if len(g.outputs) != 1 or tuple(g.tensors[g.outputs[0]].shape) \
-            != net.shapes[net.output]:
-        raise BenchError(f"{config['name']}: output shapes differ")
+    if [tuple(g.tensors[t].shape) for t in g.outputs] \
+            != [net.shapes[t] for t in net.outputs]:
+        raise BenchError(f"{config['name']}: the program's outputs and the "
+                         f"reference's differ in number or shape")
     differ = sorted(t for t, shape in net.shapes.items()
                     if t not in g.tensors or tuple(g.tensors[t].shape) != shape)
     if differ:
@@ -252,7 +256,8 @@ def _spans(on: bool):
 
 class Executive:
     """Submits frames and steps the Server, recording every frame and step.
-    The outputs of served frames are kept (by frame index) for the check."""
+    Every output of each served frame is kept, by frame index, as a list
+    in the order of the graph's outputs, for the check."""
 
     def __init__(self, srv, name: str, frames: traffic.Frames, span,
                  k0: int = 0):
@@ -263,8 +268,8 @@ class Executive:
         self.pending: collections.deque = collections.deque()
         self.records: list[Frame] = []
         self.steps: list[Step] = []
-        self.outputs: dict[int, np.ndarray] = {}
-        self.out_name = srv.specs[0].graph.outputs[0]
+        self.outputs: dict[int, list[np.ndarray]] = {}
+        self.out_names = list(srv.specs[0].graph.outputs)
         self.in_window = True
 
     @property
@@ -297,7 +302,7 @@ class Executive:
                 rec.done = e
                 res = t.result()
                 runner_s = res.latency_s
-                self.outputs[rec.k] = res.output[self.out_name]
+                self.outputs[rec.k] = [res.output[t] for t in self.out_names]
                 n += 1
         self.pending = waiting
         self.steps.append(Step(s, e, n, runner_s, self.in_window))
@@ -383,9 +388,12 @@ def compare(net: reference.Net, params: dict, frames: traffic.Frames,
             arith: str = "int8") -> dict:
     """Every frame of the window must come back ("lost_frames"); a sample
     of the served ones, drawn from the seed with the first and the last
-    served frame in it, must equal the reference bit for bit
-    ("mismatched_frames", "max_abs_diff"). Returns ({name: (value, limit)},
-    number of frames compared).
+    served frame in it, must equal the reference bit for bit in every
+    output: a frame counts once in "mismatched_frames" if any of its
+    outputs differs, or if it has another number of outputs, and
+    "max_abs_diff" is the largest gap over all outputs. `outputs` holds
+    each served frame's outputs as a list in the order of `net.outputs`.
+    Returns ({name: (value, limit)}, number of frames compared).
     `arith` computes the reference itself in another precision (the
     control reads its gap from the int8 reference instead of the program).
     """
@@ -397,13 +405,16 @@ def compare(net: reference.Net, params: dict, frames: traffic.Frames,
     picks |= {served[0], served[-1]} if served else set()
     mism, gap = 0, 0
     for k in sorted(picks):
-        want = reference.forward(net, params, frames(k))
-        got = outputs[k] if arith == "int8" else \
-            reference.forward(net, params, frames(k), arith)
-        diff = np.abs(np.asarray(got, np.int64).reshape(want.shape)
-                      - want.astype(np.int64))
-        mism += int(diff.any())
-        gap = max(gap, int(diff.max()))
+        want = list(reference.forward(net, params, frames(k)).values())
+        got = list(outputs[k]) if arith == "int8" else list(
+            reference.forward(net, params, frames(k), arith).values())
+        bad = len(got) != len(want)
+        for g, w in zip(got, want):
+            diff = np.abs(np.asarray(g, np.int64).reshape(w.shape)
+                          - w.astype(np.int64))
+            bad |= bool(diff.any())
+            gap = max(gap, int(diff.max()))
+        mism += int(bad)
     return {"lost_frames": (lost, 0), "mismatched_frames": (mism, 0),
             "max_abs_diff": (gap, 0)}, len(picks)
 

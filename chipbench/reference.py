@@ -2,9 +2,11 @@
 
 A configuration's network is written out once as a `Net`: a flat list of
 layers with their shapes, built by the reference module beside the
-configuration's file (`configs/resnet.py`, `configs/yolov5.py`). `forward`
-evaluates it on one frame in numpy, op by op, with no partition, schedule,
-kernel or batching:
+configuration's file (`configs/resnet.py`, `configs/yolov5s_v6.py`).
+`forward` evaluates it on one frame in numpy, op by op, with no partition,
+schedule, kernel or batching, and returns every output of the network by
+name: the tensors marked with `Net.mark_output`, in the order marked, or
+the last layer's output where none is marked.
 
 * conv and fully-connected layers are im2col matrix products in float64.
   Every product of two int8 values and every sum of up to 2**38 of them is
@@ -14,11 +16,19 @@ kernel or batching:
   float32 multiplier, rounded half to even and clamped to int8.
 * add saturates to int8; max-pool pads with -128; global average pool
   rounds the float64 mean half to even.
+* upsample repeats each pixel `factor` times along both spatial axes
+  (nearest neighbour, `nn.Upsample(scale_factor=f, mode="nearest")`).
+* silu maps each int8 value q through one 256-entry table that keeps the
+  input's scale s (a layer attribute):
+  t[q] = clip(rint(q * sigmoid(q * s)), -128, 127), computed in float64
+  with sigmoid(z) = 1 / (1 + exp(-z)) and rint rounding half to even.
+  A program reproduces this table bit for bit.
 
 `arith="int4"` is the control: the same network with every conv and
 fully-connected input and weight first rounded to int4 (a step of 16,
 clamped to [-8, 7]) and the product scaled back by 256, the precision one
-step below the configuration's int8.
+step below the configuration's int8. Every other op, silu's table
+included, is the same in both.
 
 The parameter names (`<conv>.w`, `<conv>.rq.mult`, `<fc>.w`) and the
 weight layout (rows ordered kernel-row, kernel-column, input channel) are
@@ -35,7 +45,7 @@ import numpy as np
 
 @dataclasses.dataclass(frozen=True)
 class Layer:
-    op: str                       # conv | fc | add | relu | maxpool | gap | concat
+    op: str     # conv | fc | add | relu | maxpool | gap | concat | upsample | silu
     name: str
     inputs: tuple[str, ...]
     output: str
@@ -52,7 +62,21 @@ class Net:
         self.dtypes: dict[str, str] = {"input": "int8"}
         self.weights: dict[str, tuple[int, int]] = {}   # name -> shape
         self.mults: dict[str, int] = {}                 # name -> fan-in
-        self.output = "input"
+        self.output = "input"             # the last layer's output
+        self._marked: list[str] = []
+
+    @property
+    def outputs(self) -> list[str]:
+        """The network's outputs: those marked, or else the last layer's."""
+        return list(self._marked) or [self.output]
+
+    def mark_output(self, t: str) -> str:
+        """Make tensor `t` one of the network's outputs."""
+        if t not in self.shapes:
+            raise ValueError(f"no tensor {t!r} in {self.name}")
+        if t not in self._marked:
+            self._marked.append(t)
+        return t
 
     def _add(self, op, name, inputs, shape, dtype="int8", **attrs) -> str:
         out = f"{name}.out"
@@ -100,6 +124,18 @@ class Net:
         shape = self.shapes[xs[0]][:-1] + (sum(self.shapes[t][-1] for t in xs),)
         return self._add("concat", name, xs, shape)
 
+    def upsample(self, name: str, x: str, factor: int) -> str:
+        """Nearest-neighbour upsampling by an integer factor."""
+        h, w, c = self.shapes[x]
+        return self._add("upsample", name, [x], (h * factor, w * factor, c),
+                         factor=factor)
+
+    def silu(self, name: str, x: str, scale: float) -> str:
+        """int8 SiLU by table at the input's scale (module docstring)."""
+        if self.dtypes[x] != "int8":
+            raise ValueError(f"silu {name!r} reads {x!r}, which is not int8")
+        return self._add("silu", name, [x], self.shapes[x], scale=scale)
+
     def mult_values(self, gain: float) -> dict[str, np.float32]:
         """Requant multipliers gain / sqrt(fan-in): with a gain fitted to
         the network, random int8 activations neither die out nor saturate
@@ -131,6 +167,14 @@ def _requant(acc: np.ndarray, mult: np.float32) -> np.ndarray:
     return np.clip(y, -128, 127).astype(np.int8)
 
 
+def silu_table(scale: float) -> np.ndarray:
+    """t[q + 128] for q in -128..127: the int8 SiLU at input scale `scale`."""
+    q = np.arange(-128, 128, dtype=np.float64)
+    sigmoid = 1.0 / (1.0 + np.exp(-q * np.float64(scale)))
+    y = np.rint(q * sigmoid)
+    return np.clip(y, -128, 127).astype(np.int8)
+
+
 def _maxpool(x: np.ndarray, k: int, stride: int, pad: int) -> np.ndarray:
     xp = np.pad(x, ((pad, pad), (pad, pad), (0, 0)), constant_values=-128)
     h, w, _ = xp.shape
@@ -144,9 +188,10 @@ def _maxpool(x: np.ndarray, k: int, stride: int, pad: int) -> np.ndarray:
 
 
 def forward(net: Net, params: dict, frame: np.ndarray,
-            arith: str = "int8") -> np.ndarray:
-    """The network's output for one (H, W, C) int8 frame; `params` holds
-    the weights and the requant multipliers by name."""
+            arith: str = "int8") -> dict[str, np.ndarray]:
+    """The network's outputs for one (H, W, C) int8 frame, by name in the
+    order of `net.outputs`; `params` holds the weights and the requant
+    multipliers by name."""
     if arith not in ("int8", "int4"):
         raise ValueError(f"arith must be int8 or int4, not {arith!r}")
     vals = {"input": np.asarray(frame, np.int8)}
@@ -174,6 +219,12 @@ def forward(net: Net, params: dict, frame: np.ndarray,
             vals[ly.output] = np.clip(m, -128, 127).astype(np.int8)[None]
         elif ly.op == "concat":
             vals[ly.output] = np.concatenate(xs, axis=-1)
+        elif ly.op == "upsample":
+            f = a["factor"]
+            vals[ly.output] = np.repeat(np.repeat(xs[0], f, axis=0), f, axis=1)
+        elif ly.op == "silu":
+            table = silu_table(a["scale"])
+            vals[ly.output] = table[xs[0].astype(np.int16) + 128]
         else:
             raise ValueError(f"unknown layer op {ly.op!r}")
-    return vals[net.output]
+    return {t: vals[t] for t in net.outputs}

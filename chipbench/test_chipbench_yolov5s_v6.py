@@ -33,7 +33,7 @@ def test_reference_matches_the_program_oracle(width):
     assert net.weights["sppf.cv2.w"] == (2 * c, c)
     for k in range(2):
         want = reference_forward(graph, params, {"input": frames(k)})
-        got = reference.forward(net, params, frames(k))
+        (got,) = reference.forward(net, params, frames(k)).values()
         np.testing.assert_array_equal(got, want[graph.outputs[0]])
         assert np.mean(got != 0) > 0.2
 
@@ -42,7 +42,8 @@ def test_reference_matches_the_program_oracle(width):
 def test_int4_control_fails_the_comparison(seed):
     _, net, params, frames = setup(seed)
     records = [harness.Frame(k, 0.0, 0.0, 0.0, "done") for k in range(6)]
-    sound = {k: reference.forward(net, params, frames(k)) for k in range(6)}
+    sound = {k: list(reference.forward(net, params, frames(k)).values())
+             for k in range(6)}
     checks, n = harness.compare(net, params, frames, sound, records, seed, 4)
     assert n >= 4 and all(v <= lim for v, lim in checks.values())
     control, _ = harness.compare(net, params, frames, sound, records, seed,

@@ -261,7 +261,9 @@ def _numpy_io(fn, ship: Callable[[dict], dict]) -> Runner:
     copy of the inputs to the device (`repro.runner.h2d`), the call, which
     returns once the program is enqueued (`repro.runner.launch`), and the
     copy of the outputs back, which waits for the device to finish
-    (`repro.runner.fetch`).
+    (`repro.runner.fetch`). A network of several outputs (YOLOv5s's three
+    Detect maps) has every output's copy started before any is waited on
+    (`jax.device_get`).
 
     `ship` puts the host inputs in the layout the program takes
     (`megakernel.ship_inputs`): an input that the network's stem reads
@@ -292,7 +294,7 @@ def _numpy_io(fn, ship: Callable[[dict], dict]) -> Runner:
 
         def fetch() -> dict:
             with tracing.span("repro.runner.fetch"):
-                return {k: np.asarray(v) for k, v in out.items()}
+                return jax.device_get(out)
         return fetch
 
     def run(inputs: dict) -> dict:

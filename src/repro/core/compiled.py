@@ -31,7 +31,8 @@ Backends over the lowered program:
     lowers onto the package's Pallas kernels (`kernels.gemm_int8`,
     `kernels.conv2d_im2col`; a pointwise conv is a GEMM and takes the GEMM
     kernel), with a gemm/conv -> requant chain fused into the kernel
-    epilogue whenever the int32 accumulator has no other consumer.
+    epilogue whenever the int32 accumulator has no other consumer, and a
+    SiLU that alone reads the requant's output fused after it.
     BlockSpec tiling is derived from the program's hardware model
     scratchpad capacity (`hw.derive_gemm_blocks` /
     `hw.derive_conv_blocks`) so the kernel grid mirrors the SPM streaming
@@ -62,7 +63,8 @@ from .mapping import Mapping, map_reverse_affinity
 from .partition import Partitioner, Subtask
 from .schedule import StaticSchedule, compute_schedule
 from .executor import (_NP_DT, _avgpool, _maxpool, _requant_np, _sat_add,
-                       im2col)
+                       _silu_np, _upsample_np, im2col)
+from .quantize import silu_steps, silu_table
 from ..hw import HardwareModel, derive_conv_blocks, derive_gemm_blocks
 from ..kernels import ref as kref
 from ..kernels.conv2d_im2col import conv2d_int8_pallas
@@ -79,7 +81,8 @@ class CompileError(ValueError):
 
 # Op kinds both backends lower; matches the executor oracle's coverage.
 SUPPORTED_KINDS = frozenset({"gemm", "conv2d", "requant", "relu", "add",
-                             "maxpool", "avgpool", "gap", "concat"})
+                             "maxpool", "avgpool", "gap", "concat", "silu",
+                             "upsample"})
 
 
 def supports_graph(g: Graph) -> bool:
@@ -409,6 +412,10 @@ def run_numpy(prog: CompiledProgram,
             out = np.clip(m, -128, 127).astype(np.int8).reshape(1, -1)
         elif b.kind == "concat":
             out = np.concatenate([vals[i] for i in b.in_idx], axis=-1)
+        elif b.kind == "silu":
+            out = _silu_np(vals[b.in_idx[0]], a["scale"])
+        elif b.kind == "upsample":
+            out = _upsample_np(vals[b.in_idx[0]], a["factor"])
         else:
             raise CompileError(f"op kind {b.kind} not lowered")
         vals[b.out_idx] = out
@@ -475,6 +482,12 @@ def _jax_op(b: OpBatch, vals: list, prog: CompiledProgram,
         return jnp.clip(m, -128, 127).astype(jnp.int8).reshape(1, -1)
     if b.kind == "concat":
         return jnp.concatenate([vals[i] for i in b.in_idx], axis=-1)
+    if b.kind == "silu":
+        table = jnp.asarray(silu_table(a["scale"]))
+        return table[vals[b.in_idx[0]].astype(jnp.int32) + 128]
+    if b.kind == "upsample":
+        f = a["factor"]
+        return jnp.repeat(jnp.repeat(vals[b.in_idx[0]], f, axis=0), f, axis=1)
     raise CompileError(f"op kind {b.kind} not lowered")
 
 
@@ -556,40 +569,50 @@ class _PallasStep:
     """One op of the pallas-backend program plan.
 
     mode: "gemm" / "conv2d" / "stem" (Pallas kernel), "jax" (fallback),
-    or "skip" (a requant batch fused into the preceding kernel's epilogue).
-    A "gemm" step's batch is a gemm or a pointwise conv; `gemm` gives its
-    shape. A "stem" step is a conv that reads a graph input as row groups
-    (`_is_stem`).
+    or "skip" (a requant or SiLU batch fused into the preceding kernel's
+    epilogue). A "gemm" step's batch is a gemm or a pointwise conv; `gemm`
+    gives its shape. A "stem" step is a conv that reads a graph input as
+    row groups (`_is_stem`). `silu` is the fused SiLU's table as
+    `quantize.silu_steps` gives it, applied after the requant.
     """
 
     mode: str
     batch: OpBatch
-    out_idx: int                 # where the result lands (fused: requant out)
+    out_idx: int                 # where the result lands (fused: last op's)
     mult: np.ndarray | None      # fused requant multiplier, else None
     blocks: tuple                # (bm, bn, bk) gemm | (rows_t, bn) conv
     gemm: GemmShape | None = None  # the contraction of a "gemm" step
+    silu: tuple | None = None    # fused SiLU steps, else None
 
 
-def _fusable_requant(prog: CompiledProgram, b: OpBatch) -> OpBatch | None:
-    """The requant batch to fold into `b`'s kernel epilogue, if legal.
-
-    Legal iff b's int32 output feeds exactly one consumer, that consumer is
-    a requant op, and the accumulator is not itself a graph output — then
-    requantization in the epilogue is observationally identical to running
-    the requant batch afterwards (`requant_epilogue` shares the oracle's
-    round-half-even numerics).
-    """
+def _sole_consumer(prog: CompiledProgram, b: OpBatch,
+                   kind: str) -> OpBatch | None:
+    """The batch of `b`'s only consumer, if it is of `kind` and `b`'s
+    output is not itself a graph output; else None."""
     out_name = prog.buffers[b.out_idx][0]
     if out_name in prog.graph.outputs:
         return None
     consumers = prog.graph.consumers_of(out_name)
-    if len(consumers) != 1 or consumers[0].kind != "requant":
+    if len(consumers) != 1 or consumers[0].kind != kind:
         return None
-    (rq,) = consumers
-    for cand in prog.batches:
-        if cand.name == rq.name:
-            return cand
-    return None
+    return next(c for c in prog.batches if c.name == consumers[0].name)
+
+
+def _fusable_epilogue(prog: CompiledProgram, b: OpBatch
+                      ) -> tuple[OpBatch | None, OpBatch | None]:
+    """The requant batch, and after it the SiLU batch, to fold into `b`'s
+    kernel epilogue, where legal.
+
+    A requant is legal iff b's int32 output feeds exactly one consumer,
+    that consumer is a requant op, and the accumulator is not itself a
+    graph output — then requantization in the epilogue is observationally
+    identical to running the requant batch afterwards (`requant_epilogue`
+    shares the oracle's round-half-even numerics). A SiLU is legal on the
+    same terms for the requant's int8 output: its table applied in the
+    epilogue is the standalone op's, bit for bit (`silu_epilogue`).
+    """
+    rq = _sole_consumer(prog, b, "requant")
+    return rq, (None if rq is None else _sole_consumer(prog, rq, "silu"))
 
 
 def _gemm_shape(b: OpBatch) -> GemmShape | None:
@@ -644,7 +667,11 @@ def _pallas_plan(prog: CompiledProgram) -> list[_PallasStep]:
     input as row groups (`megakernel.input_layout`) and the stem kernel
     (`kernels.stem_conv`) contracts them against banded weights, so the
     frame is never padded out to 128 lanes, on the host or on the device.
-    Every other conv keeps the windowed kernel."""
+    Every other conv keeps the windowed kernel.
+
+    A SiLU whose input is a fused requant's output, and which nothing
+    else reads, applies its table in the same epilogue (`silu`), so the
+    conv's int8 output does not make a second pass for its activation."""
     plan: list[_PallasStep] = []
     skipped: set[int] = set()
     for b in prog.batches:
@@ -654,8 +681,8 @@ def _pallas_plan(prog: CompiledProgram) -> list[_PallasStep]:
         if b.kind not in PALLAS_KINDS:
             plan.append(_PallasStep("jax", b, b.out_idx, None, ()))
             continue
-        rq = _fusable_requant(prog, b)
-        out_idx = rq.out_idx if rq is not None else b.out_idx
+        rq, act = _fusable_epilogue(prog, b)
+        last = act or rq or b
         mult = rq.mult if rq is not None else None
         out_bytes = 1 if rq is not None else 4
         gemm = _gemm_shape(b)
@@ -670,21 +697,28 @@ def _pallas_plan(prog: CompiledProgram) -> list[_PallasStep]:
             mode = "conv2d"
             blocks = (derive_conv_blocks(prog.hw, b.attrs, out_bytes)
                       if prog.hw is not None else (8, 128))
-        if rq is not None:
-            skipped.add(rq.op_idx)
-        plan.append(_PallasStep(mode, b, out_idx, mult, blocks, gemm))
+        skipped.update(f.op_idx for f in (rq, act) if f is not None)
+        silu = silu_steps(act.attrs["scale"]) if act is not None else None
+        plan.append(_PallasStep(mode, b, last.out_idx, mult, blocks, gemm,
+                                silu))
     return plan
 
 
 def plan_counts(prog: CompiledProgram) -> dict[str, int]:
     """Steps of the pallas plan by mode, plus `pointwise_gemm`: the convs
-    planned as GEMMs, and `dense_stem`: the convs that read a graph input
-    as row groups (the "stem" steps)."""
+    planned as GEMMs; `dense_stem`: the convs that read a graph input as
+    row groups (the "stem" steps); `silu_epilogue`: the kernel steps whose
+    epilogue applies a SiLU; `silu_steps`: the SiLUs left as steps of
+    their own; `upsample`: the upsample steps."""
     plan = _pallas_plan(prog)
     counts = dict(Counter(s.mode for s in plan))
     counts["pointwise_gemm"] = sum(
         s.mode == "gemm" and s.batch.kind == "conv2d" for s in plan)
     counts["dense_stem"] = counts.get("stem", 0)
+    counts["silu_epilogue"] = sum(s.silu is not None for s in plan)
+    counts["silu_steps"] = sum(s.batch.kind == "silu" and s.mode != "skip"
+                               for s in plan)
+    counts["upsample"] = sum(s.batch.kind == "upsample" for s in plan)
     return counts
 
 
@@ -700,14 +734,15 @@ def run_kernel_step(prog: CompiledProgram, step: _PallasStep, vals: list,
     if step.mode == "stem":
         vals[step.out_idx] = stem_conv_pallas(
             x, weights[b.w_idx], mult, geom=stem_geometry_of(b),
-            interpret=interpret)
+            silu=step.silu, interpret=interpret)
     elif step.mode == "gemm":
         M, K, _, s = step.gemm
         bm, bn, bk = step.blocks
         if s > 1:
             x = x[::s, ::s, :]
         out = gemm_int8_pallas(x.reshape(M, K), weights[b.w_idx], mult,
-                               bm=bm, bn=bn, bk=bk, interpret=interpret)
+                               bm=bm, bn=bn, bk=bk, silu=step.silu,
+                               interpret=interpret)
         if mult is None:
             out = out.astype(_JNP_DT[prog.buffers[step.out_idx][2]])
         vals[step.out_idx] = out.reshape(prog.buffers[step.out_idx][1])
@@ -717,4 +752,4 @@ def run_kernel_step(prog: CompiledProgram, step: _PallasStep, vals: list,
         vals[step.out_idx] = conv2d_int8_pallas(
             x, weights[b.w_idx], mult, kh=a["kh"], kw=a["kw"],
             stride=a["stride"], padding=a["padding"], rows_t=rows_t, bn=bn,
-            interpret=interpret)
+            silu=step.silu, interpret=interpret)

@@ -25,6 +25,7 @@ import numpy as np
 from .graph import Graph, OpNode, conv_out_hw
 from .partition import Subtask
 from .mapping import Mapping
+from .quantize import silu_table
 from .schedule import StaticSchedule
 
 
@@ -100,6 +101,14 @@ def _sat_add(a: np.ndarray, b: np.ndarray, dtype) -> np.ndarray:
     if np.dtype(dtype) == np.int8:
         return np.clip(s, -128, 127).astype(np.int8)
     return s.astype(dtype)
+
+
+def _silu_np(x: np.ndarray, scale: float) -> np.ndarray:
+    return silu_table(scale)[x.astype(np.int16) + 128]
+
+
+def _upsample_np(x: np.ndarray, f: int) -> np.ndarray:
+    return np.repeat(np.repeat(x, f, axis=0), f, axis=1)
 
 
 def _maxpool(x: np.ndarray, k: int, s: int, p: int) -> np.ndarray:
@@ -192,6 +201,10 @@ def _eval_op(op: OpNode, g: Graph, params: dict,
         return out
     if k == "concat":
         return np.concatenate([vals[t] for t in op.inputs], axis=-1)
+    if k == "silu":
+        return _silu_np(vals[op.inputs[0]], op.attrs["scale"])
+    if k == "upsample":
+        return _upsample_np(vals[op.inputs[0]], op.attrs["factor"])
     raise NotImplementedError(f"op kind {k}")
 
 

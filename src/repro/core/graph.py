@@ -43,6 +43,9 @@ class OpNode:
 
     attrs for kind == "gemm":   M, K, N  (activation (M,K) @ weight (K,N))
     attrs for kind == "conv2d": H, W, C_in, C_out, kh, kw, stride, padding
+    attrs for kind == "silu":   scale (int8 in and out, by table:
+                                `quantize.silu_table`)
+    attrs for kind == "upsample": factor (nearest neighbour, both axes)
     elementwise/pool/norm ops carry their natural attrs.
     """
 
@@ -64,7 +67,8 @@ class OpNode:
         # elementwise-ish ops: ~a few ops per output element
         out = g.tensors[self.outputs[0]]
         per = {"relu": 1, "add": 1, "mul": 1, "maxpool": 4, "avgpool": 4,
-               "requant": 4, "norm": 8, "softmax": 10, "gap": 2}.get(self.kind, 2)
+               "requant": 4, "norm": 8, "softmax": 10, "gap": 2,
+               "silu": 2, "upsample": 1}.get(self.kind, 2)
         return float(per * out.size)
 
     def is_gemm_like(self) -> bool:
@@ -217,6 +221,27 @@ def requant(g: Graph, name: str, x: str, dtype: str = "int8") -> str:
     y = f"{name}.out"
     g.add_tensor(y, g.tensors[x].shape, dtype)
     g.add_op(OpNode(name, "requant", [x], [y]))
+    return y
+
+
+def silu(g: Graph, name: str, x: str, scale: float) -> str:
+    """int8 SiLU at the input's scale `scale`, by the 256-entry table
+    `quantize.silu_table` (the output keeps the input's scale)."""
+    if g.tensors[x].dtype != "int8":
+        raise GraphError(f"{name}: silu reads {x}, which is not int8")
+    y = f"{name}.out"
+    g.add_tensor(y, g.tensors[x].shape, "int8")
+    g.add_op(OpNode(name, "silu", [x], [y], attrs={"scale": float(scale)}))
+    return y
+
+
+def upsample(g: Graph, name: str, x: str, factor: int) -> str:
+    """Nearest-neighbour upsampling of an (H, W, C) tensor by an integer
+    factor along both spatial axes."""
+    H, W, C = g.tensors[x].shape
+    y = f"{name}.out"
+    g.add_tensor(y, (H * factor, W * factor, C), g.tensors[x].dtype)
+    g.add_op(OpNode(name, "upsample", [x], [y], attrs={"factor": factor}))
     return y
 
 

@@ -23,8 +23,9 @@ per op:
      int8 contraction (`kernels.gemm_int8.dot_i32_exact`: MXU int8 dots on
      TPU, exactness-preserving chunked-f32 dots under interpret mode),
      convs and pools as strided tap windows over a padded int32 VMEM copy
-     (`kernels.conv2d_im2col.conv_accumulate`), requantization fused into
-     the epilogues exactly as the plan decided (`_PallasStep.mult`).
+     (`kernels.conv2d_im2col.conv_accumulate`), requantization and an
+     int8 SiLU fused into the epilogues exactly as the plan decided
+     (`_PallasStep.mult`, `_PallasStep.silu`).
      In-kernel values stay int32 (Mosaic lays out and windows 32-bit
      values; int8 ones it refuses to reshape or compare) and narrow to int8
      only for the MXU and at the output store. Weights and requant
@@ -76,12 +77,13 @@ import numpy as np
 from jax.experimental import pallas as pl
 
 from . import compiled as C
+from .quantize import silu_steps
 from ..kernels import vmem
 from ..kernels.conv2d_im2col import (conv2d_vmem_bytes, conv_accumulate,
                                      fill_window, tap_windows,
                                      window_scratch)
 from ..kernels.gemm_int8 import (dot_i32_exact, gemm_vmem_bytes,
-                                 requant_epilogue)
+                                 requant_epilogue, silu_epilogue)
 from ..kernels.ref import _as_channel_mult, round_half_even_div
 from ..kernels.stem_conv import (stem_bands, stem_input_shape,
                                  stem_vmem_bytes)
@@ -302,7 +304,8 @@ def _emit_step(step, local: dict, w_refs: dict, m_refs: dict,
                                   via_f32=via_f32)
             acc = acc.reshape(oh, ow, a["C_out"])
         local[step.out_idx] = (_narrow(acc, out_dt) if mult is None else
-                               requant_epilogue(acc, mult, jnp.int32))
+                               requant_epilogue(acc, mult, jnp.int32,
+                                                silu=step.silu))
         return
     ins = [local[i] for i in b.in_idx]
     if b.kind == "requant":
@@ -335,6 +338,13 @@ def _emit_step(step, local: dict, w_refs: dict, m_refs: dict,
         out = jnp.clip(m, -128, 127)
     elif b.kind == "concat":
         out = jnp.concatenate(ins, axis=-1)
+    elif b.kind == "silu":
+        out = silu_epilogue(ins[0], silu_steps(a["scale"]))
+    elif b.kind == "upsample":
+        H, W, Cn = ins[0].shape
+        f = a["factor"]
+        out = jnp.broadcast_to(ins[0][:, None, :, None, :],
+                               (H, f, W, f, Cn)).reshape(H * f, W * f, Cn)
     else:
         raise C.CompileError(f"op kind {b.kind} not lowered")
     local[step.out_idx] = out
@@ -429,15 +439,17 @@ def segment_vmem_bytes(prog: C.CompiledProgram, seg: Segment) -> int:
 
 def segment_name(index: int, seg: Segment) -> str:
     """The stable name of segment `index` of a plan: its index and its first
-    graph op. Every segment runs under it as a `jax.named_scope`, and a
-    fused segment's kernel carries it as its `pallas_call` name into the
-    compiled program and the profiler's trace. A tiled segment's kernel is
-    named after its shape instead (`conv2d_kernel_name`,
-    `gemm_kernel_name`, `stem_kernel_name`): segments of one shape share
-    one traced and lowered kernel, which a name per segment would
-    multiply."""
+    graph op, and `_silu` where one of its steps applies a SiLU. Every
+    segment runs under it as a `jax.named_scope`, and a fused segment's
+    kernel carries it as its `pallas_call` name into the compiled program
+    and the profiler's trace. A tiled segment's kernel is named after its
+    shape instead (`conv2d_kernel_name`, `gemm_kernel_name`,
+    `stem_kernel_name`): segments of one shape share one traced and
+    lowered kernel, which a name per segment would multiply."""
     op = re.sub(r"[^0-9A-Za-z_]", "_", seg.steps[0].batch.name)
-    return f"seg{index:03d}_{op}"
+    silu = any(s.silu is not None or s.batch.kind == "silu"
+               for s in seg.steps)
+    return f"seg{index:03d}_{op}" + ("_silu" if silu else "")
 
 
 def _run_fused(prog: C.CompiledProgram, seg: Segment, vals: list,

@@ -272,6 +272,12 @@ class Partitioner:
                     i1 = min(t.shape[0], (r1 - 1) * st_ + k)
                     nb = max(1, int(t.nbytes * (i1 - i0) / t.shape[0]))
                     reg = ("rows", i0, i1)
+                elif op.kind == "upsample":
+                    # output row r repeats input row r // factor
+                    f = op.attrs["factor"]
+                    i0, i1 = r0 // f, (r1 - 1) // f + 1
+                    nb = t.nbytes * (i1 - i0) // t.shape[0]
+                    reg = ("rows", i0, i1)
                 loads.append(Transfer(t.name, "act", nb, nb, reg))
             st_bytes = max(1, int(y.nbytes * frac))
             store = Transfer(y.name, "out", st_bytes, st_bytes,

@@ -10,6 +10,7 @@ kernel's epilogue use, so tiled and whole-layer paths agree bit-exactly.
 from __future__ import annotations
 
 import dataclasses
+import functools
 
 import jax.numpy as jnp
 import numpy as np
@@ -73,6 +74,30 @@ def requantize(acc_i32: jnp.ndarray, mult: jnp.ndarray) -> jnp.ndarray:
     matching jnp.round; identical math used by kernel epilogue and ref)."""
     y = jnp.round(acc_i32.astype(jnp.float32) * mult)
     return jnp.clip(y, -128, 127).astype(jnp.int8)
+
+
+def silu_table(scale: float) -> np.ndarray:
+    """t[q + 128] for q in -128..127: the int8 SiLU at input scale `scale`,
+    t[q] = clip(rint(q * sigmoid(q * scale)), -128, 127), computed in
+    float64 on the host (rint rounds half to even). The output keeps the
+    input's scale. The table is the op's definition: every backend
+    reproduces it bit for bit, none evaluates the sigmoid on a device."""
+    q = np.arange(-128, 128, dtype=np.float64)
+    sigmoid = 1.0 / (1.0 + np.exp(-q * np.float64(scale)))
+    y = np.rint(q * sigmoid)
+    return np.clip(y, -128, 127).astype(np.int8)
+
+
+@functools.lru_cache(maxsize=None)
+def silu_steps(scale: float) -> tuple[int, tuple[tuple[int, int], ...]]:
+    """`silu_table(scale)` as (base, ((k, c), ...)) with
+    t[q] = max(q, 0) + base + sum(c for (k, c) if q >= k): the table less
+    a ReLU is a step function of few steps (16 at scale 1/16), so a kernel
+    epilogue applies it exactly with compares, selects and adds."""
+    q = np.arange(-128, 128)
+    d = silu_table(scale).astype(np.int64) - np.maximum(q, 0)
+    at = np.flatnonzero(np.diff(d)) + 1
+    return int(d[0]), tuple((int(q[i]), int(d[i] - d[i - 1])) for i in at)
 
 
 def sqnr_db(ref: np.ndarray, test: np.ndarray) -> float:

@@ -100,7 +100,7 @@ def conv_accumulate(src, w, c: int, kh: int, kw: int, stride: int, oh: int,
 
 
 def _make_kernel(kh: int, kw: int, stride: int, rows_t: int, ow: int,
-                 requant: bool, via_f32: bool):
+                 requant: bool, via_f32: bool, silu: tuple | None):
     def kernel(x_ref, w_ref, *refs):
         # x_ref: (1, in_rows, Wp, C) int8 raw band (halo included)
         # w_ref: (kh*kw, C, bn) int8
@@ -113,7 +113,7 @@ def _make_kernel(kh: int, kw: int, stride: int, rows_t: int, ow: int,
                               rows_t, ow, via_f32=via_f32)
         acc = acc.reshape(rows_t, ow, acc.shape[-1])
         if requant:
-            o_ref[...] = requant_epilogue(acc, refs[0][...])
+            o_ref[...] = requant_epilogue(acc, refs[0][...], silu=silu)
         else:
             o_ref[...] = acc
     return kernel
@@ -155,33 +155,39 @@ def conv2d_vmem_bytes(H: int, W: int, C: int, N: int, *, kh: int, kw: int,
 
 
 def conv2d_kernel_name(H: int, W: int, C: int, N: int, *, kh: int, kw: int,
-                       stride: int, padding: int) -> str:
+                       stride: int, padding: int, silu: bool = False) -> str:
     """The kernel's name in the compiled program and the profiler's trace:
     its geometry, so a trace ties the kernel's time to the layers of that
-    shape, and convolutions of one shape share one kernel."""
-    return f"conv{kh}x{kw}s{stride}p{padding}_{H}x{W}x{C}_{N}"
+    shape, and convolutions of one shape share one kernel; `_silu` where
+    its epilogue applies a SiLU."""
+    return (f"conv{kh}x{kw}s{stride}p{padding}_{H}x{W}x{C}_{N}"
+            + ("_silu" if silu else ""))
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "kh", "kw", "stride", "padding", "rows_t", "bn", "interpret"))
+    "kh", "kw", "stride", "padding", "rows_t", "bn", "silu", "interpret"))
 def conv2d_int8_pallas(x: jax.Array, w: jax.Array,
                        requant_mult: jax.Array | None = None,
                        *, kh: int, kw: int,
                        stride: int = 1, padding: int = 0,
                        rows_t: int = 8, bn: int = 128,
+                       silu: tuple | None = None,
                        interpret: bool = False) -> jax.Array:
     """x (H,W,C) int8, w (kh*kw*C, N) int8 -> (oh, ow, N) int32.
 
     With `requant_mult` (scalar or per-channel (N,)) the int32 accumulator
     is folded to int8 in the kernel epilogue (`requant_epilogue` — the same
     round-half-even contract as the GEMM kernel and `kernels.ref`), so the
-    int32 tensor never leaves VMEM. Block shapes (rows_t, bn) can be derived
-    from a scratchpad budget with `repro.hw.derive_conv_blocks`; `bn` is
-    rounded to whole 128-lane tiles (or the whole channel axis).
+    int32 tensor never leaves VMEM; with `silu` (a SiLU's steps) the int8
+    result then passes the SiLU table there too. Block shapes (rows_t, bn)
+    can be derived from a scratchpad budget with
+    `repro.hw.derive_conv_blocks`; `bn` is rounded to whole 128-lane tiles
+    (or the whole channel axis).
     """
     H, W, C = x.shape
     KKC, N = w.shape
     assert KKC == kh * kw * C
+    assert silu is None or requant_mult is not None, "SiLU reads int8"
     oh, ow, rows_t, oh_p, in_rows_t, wp_ = _conv_geometry(
         H, W, kh, kw, stride, padding, rows_t)
     bn_ = _block(bn, N, 128)
@@ -201,7 +207,7 @@ def conv2d_int8_pallas(x: jax.Array, w: jax.Array,
 
     fused = requant_mult is not None
     kernel = _make_kernel(kh, kw, stride, rows_t, ow, requant=fused,
-                          via_f32=interpret)
+                          via_f32=interpret, silu=silu)
     in_specs = [
         pl.BlockSpec((1, in_rows_t, xp.shape[1], C),
                      lambda i, j: (i, 0, 0, 0)),
@@ -225,6 +231,6 @@ def conv2d_int8_pallas(x: jax.Array, w: jax.Array,
             rows_t=rows_t, bn=bn, requant=fused)),
         interpret=interpret,
         name=conv2d_kernel_name(H, W, C, N, kh=kh, kw=kw, stride=stride,
-                                padding=padding),
+                                padding=padding, silu=silu is not None),
     )(*operands)
     return out[:oh, :, :N]

@@ -12,7 +12,8 @@ MXU (128x128 systolic, int8 path at 2x bf16 rate, VMEM scratchpad):
   * accumulation stays in an int32 VMEM scratch tile across the K grid
     dimension (paper: int32 accumulators in the vector registers).
   * the epilogue folds the int32 tile to int8 via the same fixed-point
-    requant math as `repro.core.quantize.requantize` (bit-exact).
+    requant math as `repro.core.quantize.requantize` (bit-exact), and may
+    apply an int8 SiLU after it (`silu_epilogue`).
 
 Block shapes default to MXU-aligned (128, 128, 128) and are rounded to
 what Mosaic tiles (`_block`); the call states its scoped-VMEM limit from
@@ -88,8 +89,24 @@ def dot_i32_exact(x: jax.Array, w: jax.Array, *,
     return acc
 
 
+def silu_epilogue(q: jax.Array, silu: tuple) -> jax.Array:
+    """The int8 SiLU table on int8-valued `q` (any dtype that holds them
+    exactly), from its steps `silu` = (base, ((k, c), ...))
+    (`core.quantize.silu_steps`): max(q, 0) + base + sum of c where
+    q >= k. Compares, selects and adds on the vector unit, exact for every
+    input: no sigmoid is evaluated on the device, whose `exp` is not the
+    host's."""
+    base, steps = silu
+    out = jnp.maximum(q, 0)
+    if base:
+        out = out + base
+    for k, c in steps:
+        out = out + jnp.where(q >= k, c, 0).astype(q.dtype)
+    return out
+
+
 def requant_epilogue(acc: jax.Array, mult: jax.Array,
-                     dtype=jnp.int8) -> jax.Array:
+                     dtype=jnp.int8, silu: tuple | None = None) -> jax.Array:
     """int32 accumulator tile -> int8, the repo's single requant definition.
 
     float32 multiply + round-half-even + saturate. `jnp.round` rounds halves
@@ -99,13 +116,16 @@ def requant_epilogue(acc: jax.Array, mult: jax.Array,
     semantics on exact-half quotients. Shared by the GEMM and conv kernels
     so the fused epilogue can never drift from the oracle. `dtype=int32`
     returns the same int8-ranged values widened, for kernel bodies that
-    keep their values in 32-bit layouts.
+    keep their values in 32-bit layouts. With `silu` (a SiLU's steps) the
+    int8 values then pass through the SiLU table (`silu_epilogue`).
     """
-    y = jnp.round(acc.astype(jnp.float32) * mult)
-    return jnp.clip(y, -128, 127).astype(dtype)
+    y = jnp.clip(jnp.round(acc.astype(jnp.float32) * mult), -128, 127)
+    if silu is not None:
+        y = silu_epilogue(y, silu)
+    return y.astype(dtype)
 
 
-def _gemm_requant_kernel(x_ref, w_ref, m_ref, o_ref, acc_ref):
+def _gemm_requant_kernel(x_ref, w_ref, m_ref, o_ref, acc_ref, *, silu):
     k = pl.program_id(2)
 
     @pl.when(k == 0)
@@ -118,7 +138,7 @@ def _gemm_requant_kernel(x_ref, w_ref, m_ref, o_ref, acc_ref):
 
     @pl.when(k == pl.num_programs(2) - 1)
     def _store():
-        o_ref[...] = requant_epilogue(acc_ref[...], m_ref[...])
+        o_ref[...] = requant_epilogue(acc_ref[...], m_ref[...], silu=silu)
 
 
 def _block(b: int, dim: int, align: int) -> int:
@@ -148,18 +168,22 @@ def gemm_vmem_bytes(M: int, K: int, N: int, *, bm: int, bn: int, bk: int,
     return 2 * blocks + 2 * vmem.tile_bytes((bm, bn), 4)
 
 
-def gemm_kernel_name(M: int, K: int, N: int) -> str:
+def gemm_kernel_name(M: int, K: int, N: int, silu: bool = False) -> str:
     """The kernel's name in the compiled program and the profiler's trace:
-    its shape, so GEMMs of one shape share one kernel and one name."""
-    return f"gemm_{M}x{K}x{N}"
+    its shape, so GEMMs of one shape share one kernel and one name, and
+    `_silu` where its epilogue applies a SiLU."""
+    return f"gemm_{M}x{K}x{N}" + ("_silu" if silu else "")
 
 
-@functools.partial(jax.jit, static_argnames=("bm", "bn", "bk", "interpret"))
+@functools.partial(jax.jit,
+                   static_argnames=("bm", "bn", "bk", "silu", "interpret"))
 def gemm_int8_pallas(x: jax.Array, w: jax.Array,
                      requant_mult: jax.Array | None = None,
                      *, bm: int = 128, bn: int = 128, bk: int = 128,
+                     silu: tuple | None = None,
                      interpret: bool = False) -> jax.Array:
-    """x (M,K) int8 @ w (K,N) int8 -> int32 (or int8 if requant_mult given).
+    """x (M,K) int8 @ w (K,N) int8 -> int32 (or int8 if requant_mult given;
+    with `silu`, a SiLU's steps, the int8 result passes its table).
 
     Shapes are padded to block multiples; padding contributes zeros to the
     accumulator so results are exact. `requant_mult` may be a scalar or a
@@ -172,6 +196,7 @@ def gemm_int8_pallas(x: jax.Array, w: jax.Array,
     M, K = x.shape
     K2, N = w.shape
     assert K == K2, (x.shape, w.shape)
+    assert silu is None or requant_mult is not None, "SiLU reads int8"
     if requant_mult is not None:
         requant_mult = _as_channel_mult(requant_mult, N)
     bm_, bn_, bk_ = gemm_blocks(M, K, N, bm, bn, bk)
@@ -197,7 +222,7 @@ def gemm_int8_pallas(x: jax.Array, w: jax.Array,
     else:
         mp = jnp.pad(requant_mult.astype(jnp.float32), (0, Np - N))
         out = pl.pallas_call(
-            _gemm_requant_kernel,
+            functools.partial(_gemm_requant_kernel, silu=silu),
             grid=grid,
             in_specs=[pl.BlockSpec((bm_, bk_), lambda i, j, k: (i, k)),
                       pl.BlockSpec((bk_, bn_), lambda i, j, k: (k, j)),
@@ -206,6 +231,7 @@ def gemm_int8_pallas(x: jax.Array, w: jax.Array,
             out_shape=jax.ShapeDtypeStruct((Mp, Np), jnp.int8),
             scratch_shapes=[pltpu.VMEM((bm_, bn_), jnp.int32)],
             compiler_params=params,
-            interpret=interpret, name=gemm_kernel_name(M, K, N),
+            interpret=interpret,
+            name=gemm_kernel_name(M, K, N, silu is not None),
         )(xp, wp, mp.reshape(1, Np))
     return out[:M, :N]

@@ -193,11 +193,12 @@ def stem_bands(w: np.ndarray, geom: StemGeometry) -> np.ndarray:
     return t.reshape(geom.k_total, -1).astype(np.int8)
 
 
-def stem_kernel_name(geom: StemGeometry) -> str:
+def stem_kernel_name(geom: StemGeometry, silu: bool = False) -> str:
     """The kernel's name in the compiled program and the profiler's trace:
-    the conv and the row-group shape it reads."""
+    the conv and the row-group shape it reads, and `_silu` where its
+    epilogue applies a SiLU."""
     return (f"stem{geom.kh}x{geom.kw}s{geom.stride}p{geom.padding}_"
-            f"{geom.rows}x{geom.lanes}_{geom.N}")
+            f"{geom.rows}x{geom.lanes}_{geom.N}" + ("_silu" if silu else ""))
 
 
 def stem_vmem_bytes(geom: StemGeometry, requant: bool) -> int:
@@ -218,7 +219,8 @@ def stem_vmem_bytes(geom: StemGeometry, requant: bool) -> int:
     return 2 * blocks + values
 
 
-def _make_kernel(geom: StemGeometry, requant: bool, via_f32: bool):
+def _make_kernel(geom: StemGeometry, requant: bool, via_f32: bool,
+                 silu: tuple | None):
     top, rows, oh, n_blk = geom.pad_top, geom.rows, geom.oh, geom.n_blk
 
     def kernel(x_ref, t_ref, *refs):
@@ -247,20 +249,23 @@ def _make_kernel(geom: StemGeometry, requant: bool, via_f32: bool):
                 part = p_ref[top + q:top + q + oh,
                              n * n_blk:(n + 1) * n_blk]
                 acc = part if acc is None else acc + part
-            out = requant_epilogue(acc, refs[0][...]) if requant else acc
+            out = (requant_epilogue(acc, refs[0][...], silu=silu)
+                   if requant else acc)
             o_ref[:, jb * n_blk:(jb + 1) * n_blk] = out
     return kernel
 
 
-@functools.partial(jax.jit, static_argnames=("geom", "interpret"))
+@functools.partial(jax.jit, static_argnames=("geom", "silu", "interpret"))
 def stem_conv_pallas(x: jax.Array, bands: jax.Array,
                      requant_mult: jax.Array | None = None, *,
-                     geom: StemGeometry, interpret: bool = False
-                     ) -> jax.Array:
+                     geom: StemGeometry, silu: tuple | None = None,
+                     interpret: bool = False) -> jax.Array:
     """x (H/s, s·W·C) int8 row groups, bands from `stem_bands` ->
     (oh, ow, N) int32, or int8 with `requant_mult` (scalar or per-channel
-    (N,)) folded in by the epilogue."""
+    (N,)) folded in by the epilogue, and then, with `silu` (a SiLU's
+    steps), the SiLU table."""
     assert x.shape == (geom.rows, geom.lanes), (x.shape, geom)
+    assert silu is None or requant_mult is not None, "SiLU reads int8"
     fused = requant_mult is not None
     operands = [x, bands]
     in_specs = [pl.BlockSpec((geom.rows, geom.lanes_p), lambda i: (0, 0)),
@@ -272,7 +277,7 @@ def stem_conv_pallas(x: jax.Array, bands: jax.Array,
         operands.append(mult.reshape(1, geom.n_blk))
         in_specs.append(pl.BlockSpec((1, geom.n_blk), lambda i: (0, 0)))
     out = pl.pallas_call(
-        _make_kernel(geom, fused, via_f32=interpret),
+        _make_kernel(geom, fused, via_f32=interpret, silu=silu),
         grid=(1,),       # a grid: the input's block may overhang its lanes
         in_specs=in_specs,
         out_specs=pl.BlockSpec((geom.oh, geom.out_lanes), lambda i: (0, 0)),
@@ -282,6 +287,6 @@ def stem_conv_pallas(x: jax.Array, bands: jax.Array,
             (geom.scratch_rows, len(geom.qs) * geom.n_blk), jnp.int32)],
         compiler_params=vmem.compiler_params(stem_vmem_bytes(geom, fused)),
         interpret=interpret,
-        name=stem_kernel_name(geom),
+        name=stem_kernel_name(geom, silu is not None),
     )(*operands)
     return out[:, :geom.ow * geom.N].reshape(geom.oh, geom.ow, geom.N)

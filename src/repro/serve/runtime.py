@@ -370,7 +370,8 @@ class Server:
                         "dropped": 0, "degraded": 0, "retries": 0,
                         "sheds": 0, "restores": 0, "mode_switches": 0,
                         "runner_calls": 0, "staged_calls": 0,
-                        "slots_filled": 0, "slots_padded": 0}
+                        "slots_filled": 0, "slots_padded": 0,
+                        "fetched_bytes": 0}
         self._nets: dict[str, _Network] = {}
         self.report: TasksetReport | None = None
         self.compiled = None                 # CompiledTaskset after analyze()
@@ -773,6 +774,8 @@ class Server:
                                        self._runner_call(st, batch, seq), seq)
             if out is _GIVE_UP:
                 return
+            self.metrics["fetched_bytes"] += sum(
+                np.asarray(v).nbytes for v in out.values())
             with tracing.span("repro.server.account", seq):
                 self.monitor.check(job.network, dt, bound)
                 for i, t in enumerate(tickets):
@@ -1317,7 +1320,8 @@ class Server:
             lines.append(f"  runner_calls={m['runner_calls']} "
                          f"(slots filled {m['slots_filled']}, "
                          f"padded {m['slots_padded']}), "
-                         f"staged_calls={m['staged_calls']}")
+                         f"staged_calls={m['staged_calls']}, "
+                         f"fetched_bytes={m['fetched_bytes']}")
         if any(m[k] for k in ("dropped", "degraded", "retries", "sheds",
                               "restores", "mode_switches")) or self.mode_name:
             lines.append(

@@ -1,5 +1,6 @@
 """Compiles of the main path's Pallas kernels for a TPU v5e, at ResNet-50
-224x224 and YOLOv5s 640x640 widths, without a chip.
+224x224 and YOLOv5s 640x640 widths (backbone, and the whole detector with
+its SiLU epilogues), without a chip.
 
 The TPU compiler is installed alongside JAX and compiles for a chip that is
 described (`topologies.get_topology_desc`) rather than attached, so Mosaic
@@ -125,6 +126,28 @@ def test_yolov5s_megakernel_program_compiles_for_v5e(one_chip):
     assert mem.temp_size_in_bytes < 1 << 30
 
 
+def test_yolov5s_det_megakernel_program_compiles_for_v5e(one_chip):
+    """The whole published YOLOv5s (640x640: backbone, SPPF, PANet neck,
+    Detect over P3/P4/P5) as the Server runs it, batched over 4 frames,
+    compiles for v5e: 60 conv kernels, 57 of them with the SiLU table in
+    their epilogue and named for it (Detect's three without), the two
+    upsamples, concats, pools and adds at the XLA level, and three int8
+    outputs of 255 channels."""
+    g = cnn.yolov5s_det()
+    prog = repro.compile(g, HW, backend="pallas",
+                         params=init_params(g, seed=0), num_cores=16).program
+    compiled = _compile_served(prog, (640, 640, 3), 60, one_chip)
+    text = compiled.as_text()
+    for name in ("stem6x6s2p2_320x3840_32_silu",
+                 "conv3x3s1p1_80x80x64_64_silu", "gemm_25600x64x32_silu",
+                 "gemm_6400x128x255", "gemm_400x512x255"):
+        assert f"/{name}/pallas_call" in text
+    assert "gemm_6400x128x255_silu" not in text
+    mem = compiled.memory_analysis()
+    assert mem.output_size_in_bytes >= 4 * 255 * (80 * 80 + 40 * 40 + 20 * 20)
+    assert mem.temp_size_in_bytes < 1 << 30
+
+
 def _conv_case(name):
     """(x shape, kernel size, stride, padding, C_out) of a ResNet-50 conv."""
     return {
@@ -194,17 +217,20 @@ def test_resnet50_fc_gemm_fused_requant_compiles_for_v5e(one_chip):
     assert _n_kernels(compiled) == 1
 
 
-@pytest.mark.parametrize("preset", ["resnet50_32_w025", "yolov5s_64_w025"])
+@pytest.mark.parametrize("preset", ["resnet50_32_w025", "yolov5s_64_w025",
+                                    "yolov5s_det_64_w025"])
 def test_fused_segments_compile_for_v5e(preset, one_chip):
     """Fused segments (the megakernel body: in-kernel convs, pools, gap,
-    concat, residual adds, requant epilogues) of reduced networks whose
-    whole program fits a few scratchpads."""
+    concat, residual adds, requant and SiLU epilogues, upsamples) of
+    reduced networks whose whole program fits a few scratchpads."""
     g, shape = {
         "resnet50_32_w025": (cnn.resnet50(h=32, w=32, width=0.25,
                                           blocks=(1, 1, 1, 1),
                                           num_classes=16), (32, 32, 3)),
         "yolov5s_64_w025": (cnn.yolov5s_backbone(h=64, w=64, width=0.25),
                             (64, 64, 3)),
+        "yolov5s_det_64_w025": (cnn.yolov5s_det(h=64, w=64, width=0.25),
+                                (64, 64, 3)),
     }[preset]
     hw = scaled_paper_machine(4)
     rep, sched, subtasks, mapping = analyze(g, hw, num_cores=4)

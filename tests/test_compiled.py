@@ -28,6 +28,8 @@ PRESETS = {
                  (32, 32, 3)),
     "yolov5s": (lambda: cnn.yolov5s_backbone(h=64, w=64, width=0.25),
                 (64, 64, 3)),
+    "yolov5s_det": (lambda: cnn.yolov5s_det(h=64, w=64, width=0.25),
+                    (64, 64, 3)),
 }
 
 
@@ -323,6 +325,37 @@ def test_plan_counts_full_width_yolov5s():
     kinds = [s.kind for s in MK.plan_segments(prog)]
     assert kinds.count("tiled") == 33 and kinds.count("outside") == 48
     assert kinds.count("fused") == 0
+
+
+def test_plan_counts_full_width_yolov5s_det():
+    """The whole published YOLOv5s (640², the benchmark's detector graph)
+    on the 16-core paper machine: every one of its 57 SiLUs is applied in
+    the epilogue of its conv's kernel (none is left as a step), the two
+    upsamples are steps, the stem reads the frame as row groups; the 60
+    convs are 60 tiled kernels and the 25 upsamples, concats, pools and
+    adds run at the XLA level. A ReLU network's plan has no SiLU and no
+    upsample."""
+    from repro.core import megakernel as MK
+    hw = scaled_paper_machine(16)
+    g = cnn.yolov5s_det()
+    rep, sched, subtasks, mapping = analyze(g, hw, num_cores=16)
+    prog = lower_program(g, init_params(g, seed=0), subtasks, mapping,
+                         sched, hw=hw)
+    counts = C.plan_counts(prog)
+    assert (counts["silu_epilogue"], counts["silu_steps"],
+            counts["upsample"], counts["dense_stem"]) == (57, 0, 2, 1)
+    assert counts["conv2d"] == 17 and counts["gemm"] == 42
+    assert counts["skip"] == 60 + 57 and counts["jax"] == 25
+    plan = C._pallas_plan(prog)
+    detect = [s for s in plan if s.mode != "skip"
+              and s.batch.name.startswith("detect.")]
+    assert [s.silu for s in detect] == [None] * 3
+    assert [prog.buffers[s.out_idx][0] for s in detect] == g.outputs
+    kinds = [s.kind for s in MK.plan_segments(prog)]
+    assert kinds.count("tiled") == 60 and kinds.count("outside") == 25
+    relu = C.plan_counts(_compiled("yolov5s")[3])
+    assert (relu["silu_epilogue"], relu["silu_steps"], relu["upsample"]) \
+        == (0, 0, 0)
 
 
 def test_pallas_no_fusion_when_acc_is_graph_output():

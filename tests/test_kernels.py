@@ -141,6 +141,100 @@ def test_stem_kernel_bit_exact(rng, H, W, N, k, stride, pad, requant, batch):
         assert np.array_equal(out[b], np.asarray(expect))
 
 
+# -- int8 SiLU in the epilogues -----------------------------------------------
+
+def _silu_through(kernel: str, q: np.ndarray, scale: float) -> np.ndarray:
+    """Every int8 value `q` (256 of them) through `kernel`'s requant and
+    SiLU epilogue: an identity contraction (one weight of 1, the rest 0)
+    and a multiplier of 1, so the epilogue reads q itself."""
+    import jax.numpy as jnp
+    from repro.core.quantize import silu_steps
+    from repro.kernels import stem_conv as S
+    from repro.kernels.conv2d_im2col import conv2d_int8_pallas
+    from repro.kernels.gemm_int8 import gemm_int8_pallas
+    silu, one = silu_steps(scale), jnp.float32(1.0)
+    if kernel == "gemm":                  # (256, 8) @ (8, 128)
+        x = np.zeros((256, 8), np.int8)
+        x[:, 0] = q
+        w = np.zeros((8, 128), np.int8)
+        w[0] = 1
+        out = gemm_int8_pallas(x, w, one, bm=64, bn=128, bk=128, silu=silu,
+                               interpret=True)
+        return np.asarray(out)[:, 0]
+    if kernel == "conv":                  # 3x3/1 over 16x16x1, centre tap
+        w = np.zeros((9, 8), np.int8)
+        w[4] = 1
+        out = conv2d_int8_pallas(q.reshape(16, 16, 1), w, one, kh=3, kw=3,
+                                 padding=1, rows_t=4, bn=8, silu=silu,
+                                 interpret=True)
+        return np.asarray(out)[..., 0].reshape(-1)
+    # stem: 6x6/2 over 32x32x3; out[i, j] = frame[2i, 2j, 0]
+    frame = np.zeros((32, 32, 3), np.int8)
+    frame[::2, ::2, 0] = q.reshape(16, 16)
+    w = np.zeros((6 * 6 * 3, 32), np.int8)
+    w[(2 * 6 + 2) * 3] = 1
+    geom = S.stem_geometry(32, 32, 3, 32, kh=6, kw=6, stride=2, padding=2)
+    out = S.stem_conv_pallas(
+        jnp.asarray(frame.reshape(S.stem_input_shape(32, 32, 3, 2))),
+        jnp.asarray(S.stem_bands(w, geom)), one, geom=geom, silu=silu,
+        interpret=True)
+    return np.asarray(out)[..., 0].reshape(-1)
+
+
+def _silu_fused_segment(q: np.ndarray, scale: float, fold: bool):
+    """A fused megakernel segment (a 3x3 conv over 64 channels, too wide
+    for the stem kernel, its requant and SiLU) over every int8 value, run
+    in interpret mode. With `fold` False the requant's output is a graph
+    output too, so the SiLU stays a step of its own inside the
+    segment."""
+    from repro.core import analyze, lower_program, run_megakernel
+    from repro.core import compiled as C
+    from repro.core import megakernel as MK
+    from repro.core.graph import Graph, conv2d, requant, silu
+    from repro.hw import scaled_paper_machine
+    g = Graph("silu_seg")
+    g.add_tensor("input", (16, 16, 64), "int8", is_input=True)
+    y = requant(g, "c.rq", conv2d(g, "c", "input", 8, 3))
+    if not fold:
+        g.mark_output(y)
+    g.mark_output(silu(g, "c.silu", y, scale))
+    g.validate()
+    hw = scaled_paper_machine(4)
+    _, sched, subtasks, mapping = analyze(g, hw, num_cores=4)
+    w = np.zeros((9 * 64, 8), np.int8)
+    w[4 * 64] = 1                        # centre tap, channel 0
+    prog = lower_program(g, {"c.w": w, "c.rq.mult": np.float32(1.0)},
+                         subtasks, mapping, sched, hw=hw)
+    (seg,) = MK.plan_segments(prog)
+    assert seg.kind == "fused"
+    counts = C.plan_counts(prog)
+    assert (counts["silu_epilogue"], counts["silu_steps"]) == \
+        ((1, 0) if fold else (0, 1))
+    x = np.zeros((16, 16, 64), np.int8)
+    x[..., 0] = q.reshape(16, 16)
+    out = run_megakernel(prog, {"input": x}, interpret=True)
+    return out["c.silu.out"][..., 0].reshape(-1)
+
+
+@pytest.mark.parametrize("scale", [1 / 8, 1 / 16, 1 / 32])
+@pytest.mark.parametrize("kernel", ["gemm", "conv", "stem", "fused",
+                                    "fused_step"])
+def test_silu_epilogue_reproduces_the_table(kernel, scale):
+    """Each kernel epilogue that applies the int8 SiLU (GEMM, windowed
+    conv, stem, a fused megakernel segment, and a SiLU left as a step of
+    its own in a fused segment) maps all 256 int8 inputs to the host's
+    float64 table (`quantize.silu_table`) bit for bit."""
+    from repro.core.quantize import silu_steps, silu_table
+    q = np.arange(-128, 128).astype(np.int8)
+    if kernel.startswith("fused"):
+        got = _silu_fused_segment(q, scale, fold=kernel == "fused")
+    else:
+        got = _silu_through(kernel, q, scale)
+    assert got.dtype == np.int8
+    np.testing.assert_array_equal(got, silu_table(scale)[q.astype(int) + 128])
+    assert len(silu_steps(scale)[1]) == {1 / 8: 8, 1 / 16: 16, 1 / 32: 32}[scale]
+
+
 def test_spm_derived_blocks_fit_scratchpad():
     """hw.derive_*_blocks always return shapes whose working set (with
     double buffering on dual-ported machines) fits the scratchpad."""

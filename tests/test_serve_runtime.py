@@ -315,6 +315,47 @@ def test_yolov5s_served_on_pallas_equals_the_oracle(monkeypatch):
     assert tracing.drain() == [] and tracing.dropped_spans() == 0
 
 
+def test_yolov5s_det_served_on_pallas_equals_the_oracle():
+    """The whole published YOLOv5s (64x64, width 0.25) served on the
+    pallas megakernel (interpret mode here) with 4 slots: 5 frames take
+    two calls. All three Detect outputs of every frame equal the numpy
+    oracle bit for bit, and `fetched_bytes` counts the three outputs of
+    every slot of both calls, padded ones included."""
+    from repro.core import init_params, reference_forward
+    g = cnn.yolov5s_det(h=64, w=64, width=0.25)
+    params = init_params(g, seed=6)
+    srv = Server(scaled_paper_machine(16), backend="pallas", num_cores=16)
+    srv.register("det", g, period_s=0.2, params=params, slots=4)
+    frames = [_frame(k, 64, 64) for k in range(5)]
+    tickets = [srv.submit("det", f) for f in frames]
+    while not all(t.terminal for t in tickets):
+        srv.step()
+    assert len(g.outputs) == 3
+    for f, t in zip(frames, tickets):
+        want = reference_forward(g, params, {"input": f})
+        got = t.result().output
+        for out in g.outputs:
+            assert got[out].dtype == np.int8
+            assert np.array_equal(got[out], want[out])
+    per_frame = 255 * (8 * 8 + 4 * 4 + 2 * 2)
+    assert srv.metrics["runner_calls"] == 2
+    assert srv.metrics["fetched_bytes"] == 2 * 4 * per_frame
+    assert srv.telemetry()["metrics"]["fetched_bytes"] == 2 * 4 * per_frame
+    assert f"fetched_bytes={2 * 4 * per_frame}" in srv.summary()
+
+
+def test_register_admits_full_yolov5s_det():
+    """`Server.register` admits the whole published YOLOv5s at 640x640 on
+    the 16-core paper machine at the benchmark's 0.2 s period: its WCET
+    bound is below the period."""
+    from repro.core import init_params
+    g = cnn.yolov5s_det()
+    srv = Server(scaled_paper_machine(16), backend="pallas", num_cores=16)
+    v = srv.register("det", g, period_s=0.2, params=init_params(g, seed=0),
+                     slots=4)
+    assert 0.1 < v.response_bound_s < 0.2
+
+
 # -- save / load ----------------------------------------------------------------
 
 @pytest.mark.parametrize("backend", ["numpy", "jax"])
